@@ -2,10 +2,11 @@
 has no FastAPI; reference servers are spray-can actors, SURVEY.md §2).
 
 The front end is a nonblocking event loop, not a thread per connection:
-BENCH_r05 measured the old ``socketserver.ThreadingTCPServer`` stack
-plateauing at ~426 qps (c8) and *falling* to ~369 qps at c32 while the
-serve tail itself cost 0.69 ms — 32 handler threads convoying on the
-GIL and the accept queue were the wall, not the model.  Here one
+the round-5 driver run (CPU) measured the old
+``socketserver.ThreadingTCPServer`` stack plateauing at ~426 qps (c8)
+and *falling* to ~369 qps at c32 while the serve tail itself cost
+0.69 ms — 32 handler threads convoying on the GIL and the accept queue
+were the wall, not the model.  Here one
 selectors-based loop per prefork worker owns every socket: it accepts,
 parses request line + headers + body with plain buffer splits (no
 email.parser, no per-line syscalls), and hands COMPLETE requests to a

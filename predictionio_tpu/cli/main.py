@@ -16,7 +16,7 @@ bin/pio (SURVEY.md §1-2).  Subcommand surface mirrors the reference:
   trace                                   browse a server's request flight recorder
   lineage                                 browse generation lineage (freshness waterfalls)
   top                                     sparkline view of a server's metrics history
-  status                                  storage + env sanity report
+  status                                  storage + jax backend sanity report
   version
 
 Where the reference shells out to spark-submit, this dispatches in-process to
@@ -53,14 +53,18 @@ def _cmd_status(args) -> int:
     except Exception as e:  # pragma: no cover - defensive
         print(f"  storage ERROR: {e}")
         return 1
-    try:
-        import jax
+    import jax
 
-        devs = jax.devices()
-        print(f"  jax devices: {len(devs)} ({devs[0].platform})")
-    except Exception as e:
-        print(f"  jax unavailable: {e}")
-    print("(sanity check: all storage repositories reachable)")
+    from predictionio_tpu.utils.device import device_info
+
+    try:
+        dev = device_info()
+    except RuntimeError as e:
+        print(f"  jax backend ERROR: {e}")
+        return 1
+    print(f"  jax devices: {dev['count']} ({dev['platform']}, {dev['kind']})")
+    print(f"  compile cache: {jax.config.jax_compilation_cache_dir}")
+    print("(sanity check: storage repositories reachable, jax backend up)")
     return 0
 
 
@@ -1117,10 +1121,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    from predictionio_tpu.utils import apply_platform_override
     from predictionio_tpu.utils.config import enable_compilation_cache
 
-    apply_platform_override()
     enable_compilation_cache()
     args = build_parser().parse_args(argv)
     return args.func(args)

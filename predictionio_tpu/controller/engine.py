@@ -172,10 +172,10 @@ class Engine(BaseEngine):
 
         The reference has no analogue (spray served queries one actor
         message at a time); on an accelerator one [B, …] dispatch
-        amortizes the per-dispatch overhead — and, behind a tunneled
-        device, the per-readback round trip — across the batch, which is
-        what lets a single chip serve concurrent load (see
-        create_server's micro-batching).  Serving still runs per query.
+        amortizes the per-dispatch and per-readback overhead across the
+        batch, which is what lets a single chip serve concurrent load
+        (see create_server's micro-batching).  Serving still runs per
+        query.
 
         Engages when every algorithm offers a serving-correct batch path:
         either an explicit ``serve_batch_predict`` (UR — its plain
@@ -198,6 +198,15 @@ class Engine(BaseEngine):
             preds = [algo.predict(model, query)
                      for algo, model in zip(algorithms, models)]
             return serving.serve(query, preds)
+
+        # where each algorithm that has such a choice resolved its
+        # serving work to (e.g. UR: scorer/tail on host or device) — the
+        # query server reports it on GET /
+        predict.placement = {}
+        for algo in algorithms:
+            resolved = getattr(algo, "serving_placement", None)
+            if resolved is not None:
+                predict.placement.update(resolved())
 
         def batch_fn(algo):
             fn = getattr(algo, "serve_batch_predict", None)

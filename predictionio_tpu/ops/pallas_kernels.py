@@ -15,12 +15,14 @@ beyond what XLA does automatically:
   2×2 contingency table + cooccurrence mask + significance threshold,
   fused into one VPU pass over each count tile.
 
-Both kernels run in compiled mode on TPU and interpret mode elsewhere
-(selected by ``pallas_mode()``), so the same code path is exercised by the
-CPU test suite.
+- ``tile_topk_desc`` — exact per-row top-k of a score tile as an in-VMEM
+  bitonic tournament (the tiled-CCO merge; selected by ``PIO_CCO_TOPK``).
 
 Control: ``PIO_PALLAS`` env var — ``auto`` (default: compiled on TPU, off
-otherwise), ``1``/``compiled``, ``interpret``, ``0``/``off``.
+otherwise), ``1``/``compiled``, ``interpret``, ``0``/``off``.  A kernel is
+interpreted ONLY when ``PIO_PALLAS=interpret`` says so (the CPU test suite
+sets it per test); compiled mode off-TPU raises instead of silently
+interpreting, so a run can never mistake the interpreter for the kernel.
 """
 
 from __future__ import annotations
@@ -53,11 +55,30 @@ def pallas_enabled() -> bool:
 
 
 def _interpret() -> bool:
-    return pallas_mode() == "interpret"
+    """True = run the kernel in the Pallas interpreter.  Anything but an
+    explicit ``PIO_PALLAS=interpret`` compiles for the TPU — and refuses
+    to run where there is none."""
+    if pallas_mode() == "interpret":
+        return True
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "Pallas TPU kernels compile only for a TPU backend (this one is "
+            f"{jax.default_backend()!r}); set PIO_PALLAS=interpret to run "
+            "them in the interpreter, or PIO_PALLAS=off for the XLA twins")
+    return False
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """Output spec that varies over the mesh axes its operands vary over:
+    inside ``shard_map`` (tiled CCO on several chips) the default
+    ``check_vma`` refuses a pallas_call whose outputs do not say; outside
+    one the set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # ---------------------------------------------------------------------------
@@ -112,7 +133,7 @@ def _masked_score_matmul(
             pl.BlockSpec((1, tile_i), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((tile_b, tile_i), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((bp, ip), jnp.float32),
+        out_shape=_out_struct((bp, ip), jnp.float32, u, v, seen, bias_row),
         cost_estimate=pl.CostEstimate(
             flops=2 * bp * ip * kp,
             bytes_accessed=4 * (bp * kp + ip * kp + 2 * bp * ip),
@@ -198,7 +219,7 @@ def _llr_padded(c, row, col, scalars, tile_r: int, tile_c: int, interpret: bool)
             pl.BlockSpec((1, 2), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rp, cp), jnp.float32),
+        out_shape=_out_struct((rp, cp), jnp.float32, c, row, col, scalars),
         cost_estimate=pl.CostEstimate(
             flops=30 * rp * cp,
             bytes_accessed=4 * 2 * rp * cp,
@@ -327,8 +348,8 @@ def _tile_topk_padded(scores, b: int, block_r: int, interpret: bool):
             pl.BlockSpec((block_r, b), lambda g: (g, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((rp, b), jnp.float32),
-            jax.ShapeDtypeStruct((rp, b), jnp.int32),
+            _out_struct((rp, b), jnp.float32, scores),
+            _out_struct((rp, b), jnp.int32, scores),
         ),
         cost_estimate=pl.CostEstimate(
             # block sort log²(bk) full-width stages + tournament ~2·log(bk)
@@ -342,7 +363,7 @@ def _tile_topk_padded(scores, b: int, block_r: int, interpret: bool):
 
 
 def tile_topk_desc(
-    scores: jnp.ndarray, b: int, block_r: int = 128,
+    scores: jnp.ndarray, b: int, block_r: int = 8,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Exact top-``b`` of each row, sorted descending, as ONE Pallas pass.
 
@@ -352,6 +373,12 @@ def tile_topk_desc(
     ``b`` must be a power of two (see ``ops.topk.block_width``); rows pad
     to the block, width pads to the next power of two with -inf (padded
     columns surface with -inf scores, which every caller already filters).
+
+    ``block_r`` is one f32 sublane group: the ~100 unrolled stages keep
+    (s, i, partner s, partner i) live across the whole [block_r, W]
+    block, and Mosaic's compile time and scoped-VMEM stack both grow with
+    it — at [100k, 4096] block_r 8/16/32 compile in 2.5/12/40 s, and 128
+    takes ~9 min to then exceed the 16 MiB scoped-VMEM limit (v5e AOT
+    compile, PERF.md "Bring-up on TPU v5e").
     """
-    interpret = _interpret() or jax.default_backend() != "tpu"
-    return _tile_topk_padded(scores, b, block_r, interpret)
+    return _tile_topk_padded(scores, b, block_r, _interpret())

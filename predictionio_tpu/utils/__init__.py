@@ -1,7 +1,4 @@
-from predictionio_tpu.utils.config import (  # noqa: F401
-    apply_platform_override,
-    load_pio_env,
-)
+from predictionio_tpu.utils.config import load_pio_env  # noqa: F401
 from predictionio_tpu.utils.tracing import named_scope, profile_to, timed  # noqa: F401
 from predictionio_tpu.utils.checkpoint import (  # noqa: F401
     CheckpointStore,

@@ -63,48 +63,37 @@ def load_pio_env(
     return out
 
 
-def apply_platform_override() -> None:
-    """PIO_JAX_PLATFORM=cpu|tpu pins the JAX backend before first use.
-
-    Env-var JAX_PLATFORMS alone can be overridden by host site config, so
-    entry points (pio CLI, bench.py) apply it programmatically via
-    jax.config; must run before any jax backend initialization.
-    """
-    plat = os.environ.get("PIO_JAX_PLATFORM")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
+# The one compile-cache location this program ever chooses: a fixed path
+# inside the checkout (listed in .gitignore).  Never the home directory,
+# a temp name, a pid or a time — every `pio` process of a checkout must
+# find what the previous one compiled.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def enable_compilation_cache() -> None:
     """Persistent XLA compilation cache shared across pio processes.
 
-    Every `pio train` / `pio deploy` is a fresh process; without this the
-    big CCO/ALS programs recompile each run (~76 s of a 108 s end-to-end
-    UR train at a 100k-item catalog measured on TPU v5e — 70% of the
-    wall clock).  The on-disk cache makes every run after the first skip
-    straight to execution, like the reference's long-lived warmed JVM.
-    PIO_JAX_CACHE overrides the location; PIO_JAX_CACHE=off disables.
+    Every `pio train` / `pio deploy` is a fresh process; without a cache
+    on disk the big CCO/ALS programs recompile on each run, and compiling
+    is most of a cold train at a 100k-item catalog (PERF.md "Bring-up on
+    TPU v5e").  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and this sets no directory at all — whoever runs the program
+    places the cache.  Where it is not, the cache lives at
+    ``COMPILE_CACHE_DIR``.  Thresholds are JAX's own
+    (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``, ...).  Every entry
+    point that compiles calls this before its first compile.
     """
-    loc = os.environ.get("PIO_JAX_CACHE", "")
-    if loc.lower() == "off":
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
-    if not loc:
-        loc = os.path.join(
-            os.path.expanduser("~"), ".cache", "predictionio_tpu", "xla")
     try:
-        os.makedirs(loc, exist_ok=True)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", loc)
-        # cache everything that took meaningful compile time; tiny programs
-        # stay in-memory only (PIO_JAX_CACHE_MIN_S tunes the cutoff)
-        min_s = float(os.environ.get("PIO_JAX_CACHE_MIN_S", "1.0"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # cache is an optimization, never a hard failure
+        COMPILE_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+    except OSError as e:  # a cache is an optimization, never a hard failure
         import logging
 
         logging.getLogger("pio.config").warning(
-            "persistent XLA cache unavailable at %s: %s", loc, e)
+            "persistent XLA cache unavailable at %s: %s",
+            COMPILE_CACHE_DIR, e)
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))

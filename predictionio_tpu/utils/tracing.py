@@ -32,28 +32,13 @@ def named_scope(name: str) -> Iterator[None]:
 def profile_to(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
     """Capture a jax.profiler trace into log_dir (view with xprof/tensorboard).
 
-    ``host_tracer_level`` (0 = host tracing off, 1 = critical events,
-    2 = info, 3 = verbose) is honored via ``jax.profiler.ProfileOptions``
-    where the installed jax exposes it (≥ 0.5); older jax (e.g. the 0.4.x
-    line) offers no per-trace option hook on ``start_trace`` at all — its
-    signature is ``(log_dir, create_perfetto_link, create_perfetto_trace)``
-    — so there the level is logged-and-skipped rather than silently
-    dropped."""
+    ``host_tracer_level``: 0 = host tracing off, 1 = critical events,
+    2 = info, 3 = verbose."""
     import jax
 
-    options = None
-    if host_tracer_level != 2 and hasattr(jax.profiler, "ProfileOptions"):
-        options = jax.profiler.ProfileOptions()
-        options.host_tracer_level = host_tracer_level
-    if options is not None:
-        jax.profiler.start_trace(log_dir, profiler_options=options)
-    else:
-        if host_tracer_level != 2:
-            log.warning(
-                "host_tracer_level=%d requested but this jax (%s) has no "
-                "ProfileOptions; tracing at the default level",
-                host_tracer_level, jax.__version__)
-        jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:

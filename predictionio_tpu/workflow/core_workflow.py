@@ -74,6 +74,15 @@ def run_train(
     """
     import os
 
+    from predictionio_tpu.ops.pallas_kernels import pallas_mode
+    from predictionio_tpu.utils import device as _device
+
+    # what this run executes on, as JAX reports it — logged, and stamped
+    # on the journal's root span below (a backend that fails to come up
+    # fails the train here, before an instance is recorded)
+    _device.watch_compiles()
+    runtime = {"device": _device.device_info(), "pallas": pallas_mode()}
+    log.info("training on %s", runtime)
     storage = storage or get_storage()
     if retries is None:
         retries = int(os.environ.get("PIO_TRAIN_RETRIES", "0"))
@@ -103,7 +112,7 @@ def run_train(
     t_run = _dt.datetime.now(_dt.timezone.utc).timestamp()
     with journal.activate():
         with journal.span("train", engine_id=engine_id,
-                          instance_id=instance_id):
+                          instance_id=instance_id, **runtime) as root:
             while True:
                 try:
                     log.info("training engine %s (instance %s, attempt %d)",
@@ -131,6 +140,9 @@ def run_train(
                     instance.status = "COMPLETED"
                     instance.end_time = _now()
                     storage.engine_instances.update(instance)
+                    root["attrs"].update(
+                        compile=_device.compile_stats(),
+                        peak_memory_bytes=_device.peak_memory_bytes())
                     log.info("training done: instance %s COMPLETED",
                              instance_id)
                     _M_TRAINS.inc(1, status="COMPLETED")

@@ -146,6 +146,11 @@ def run_train_from_args(args) -> int:
         if getattr(args, "follow", False):
             return _run_follow(args, variant, engine, engine_params,
                                engine_id)
+        from predictionio_tpu.utils.device import device_info
+
+        dev = device_info()
+        print(f"Training on {dev['count']} {dev['platform']} device(s) "
+              f"({dev['kind']}).", flush=True)
         instance = core_workflow.run_train(
             engine,
             engine_params,

@@ -55,7 +55,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("PIO_JAX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("PIO_UR_SERVE_SCORER", "host")
 
 ROUNDS = 3

@@ -56,7 +56,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("PIO_JAX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 READY_S = 180.0
 CONVERGE_S = 120.0
@@ -168,7 +168,7 @@ def main() -> int:
             "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "FS",
             "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "FS",
             "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "FS",
-            "PIO_JAX_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "PIO_MODEL_PLANE": "on",
             "PIO_MODEL_PLANE_POLL_S": "0.1",
             "PIO_PLANE_REPL_PING_S": "0.5",

@@ -256,7 +256,7 @@ def main() -> int:
     # env mutations live HERE, not at module level: bench.py and the
     # tests import writer_script without inheriting PIO_FSYNC=always
     os.environ["PIO_FSYNC"] = "always"
-    os.environ.setdefault("PIO_JAX_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     problems: list = []
     tmp = tempfile.mkdtemp(prefix="pio-failover-")
     try:

@@ -37,7 +37,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_HERE))
 sys.path.insert(0, _HERE)
 
-os.environ.setdefault("PIO_JAX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # forced-slow: every request's duration exceeds the threshold, so the
 # query below is retained exactly the way a production straggler would be
 os.environ["PIO_TRACE_SLOW_MS"] = "0"

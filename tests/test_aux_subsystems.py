@@ -194,21 +194,29 @@ def test_timed_tracer():
     assert "span" in sink and sink["span"] >= 0
 
 
-def test_persistent_compilation_cache_config(tmp_path, monkeypatch):
+def test_compile_cache_default_is_fixed_path_in_checkout(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR unset: the cache lives at the ONE fixed
+    path inside the checkout (gitignored), and compiles land there."""
+    import os
+    from pathlib import Path
+
     import jax
     from jax._src import compilation_cache as _cc
 
-    from predictionio_tpu.utils.config import enable_compilation_cache
+    from predictionio_tpu.utils import config as cfg
 
-    loc = str(tmp_path / "xla_cache")
-    monkeypatch.setenv("PIO_JAX_CACHE", loc)
-    enable_compilation_cache()
-    import os
+    repo = Path(__file__).resolve().parents[1]
+    assert cfg.COMPILE_CACHE_DIR == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
 
-    assert os.path.isdir(loc)
-    assert jax.config.jax_compilation_cache_dir == loc
+    loc = tmp_path / "xla_cache"   # keep the test's compile out of the repo
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cfg, "COMPILE_CACHE_DIR", loc)
+    cfg.enable_compilation_cache()
+    assert loc.is_dir()
+    assert jax.config.jax_compilation_cache_dir == str(loc)
     # a fresh-process compile lands in the cache (threshold forced to 0
-    # for the test; production keeps >=1s programs only)
+    # for the test; production keeps JAX's >=1s default)
     saved_min = jax.config.jax_persistent_cache_min_compile_time_secs
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     try:
@@ -238,12 +246,31 @@ def test_persistent_compilation_cache_config(tmp_path, monkeypatch):
         _cc.reset_cache()   # unpin our tmp dir for later tests
 
 
-def test_compilation_cache_off_switch(tmp_path, monkeypatch):
+def test_compile_cache_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets NO directory in
+    code — JAX reads the variable itself, in this and every child
+    process."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
     import jax
 
-    from predictionio_tpu.utils.config import enable_compilation_cache
+    from predictionio_tpu.utils import config as cfg
 
+    outside = tmp_path / "outside"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+    monkeypatch.setattr(cfg, "COMPILE_CACHE_DIR", tmp_path / "never")
     before = jax.config.jax_compilation_cache_dir
-    monkeypatch.setenv("PIO_JAX_CACHE", "off")
-    enable_compilation_cache()
+    cfg.enable_compilation_cache()
     assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "never").exists()
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "from predictionio_tpu.utils.config import enable_compilation_cache;"
+         "enable_compilation_cache(); import jax;"
+         "print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, timeout=120,
+        cwd=str(Path(__file__).resolve().parents[1]))
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == str(outside)

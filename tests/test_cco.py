@@ -134,6 +134,31 @@ def test_cco_mesh_matches_single():
     assert (i1 == i8).all()
 
 
+@pytest.mark.parametrize("kernels", ["xla", "pallas"])
+def test_tiled_mesh_matches_single(monkeypatch, kernels):
+    """The tiled strategy sharded over dp — what `pio train` takes by
+    default on a several-chip host once the catalog outgrows the dense
+    budget — equals one device; also with the Pallas LLR and top-k
+    kernels traced INSIDE the shard_map step (interpreted here), which
+    is how that step runs on TPUs."""
+    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    if kernels == "pallas":
+        monkeypatch.setenv("PIO_PALLAS", "interpret")
+        monkeypatch.setenv("PIO_CCO_TOPK", "pallas")
+    n_users, n_ip, n_it = 64, 12, 10
+    pu, pi = random_interactions(n_users, n_ip, 300, 6)
+    ou, oi = random_interactions(n_users, n_it, 300, 7)
+    p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
+    o = block_interactions(ou, oi, n_users, n_it, user_block=8)
+    s1, i1 = cco_indicators(p, o, None, None, n_users, top_k=5, item_tile=4)
+    mesh = create_mesh(MeshSpec(dp=8, mp=1))
+    s8, i8 = cco_indicators(p, o, None, None, n_users, top_k=5, item_tile=4,
+                            mesh=mesh)
+    np.testing.assert_allclose(s1, s8, rtol=1e-5)
+    for r in range(n_ip):   # tie order aside, the same correlators
+        assert set(i1[r][s1[r] > -np.inf]) == set(i8[r][s8[r] > -np.inf])
+
+
 def test_dense_matches_tiled(monkeypatch):
     """The dense user-chunked path and the tiled fallback agree exactly."""
     n_users, n_ip, n_it = 60, 12, 17

@@ -853,7 +853,7 @@ def test_prefork_plane_one_fold_one_reload(tmp_path):
            "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "FS",
            "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "FS",
            "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "FS",
-           "PIO_JAX_PLATFORM": "cpu",
+           "JAX_PLATFORMS": "cpu",
            "PIO_METRICS_FLUSH_S": "0.25",
            "PIO_MODEL_PLANE_POLL_S": "0.1",
            "PIO_FOLLOW_INTERVAL_S": "0.3"}

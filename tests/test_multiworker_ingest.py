@@ -368,7 +368,7 @@ def test_eventserver_prefork_workers_end_to_end(tmp_path, monkeypatch):
         "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "FS",
         "PIO_FSYNC": "always",
-        "PIO_JAX_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
     }
     for k, v in env_vars.items():
         monkeypatch.setenv(k, v)

@@ -440,7 +440,7 @@ def test_cross_worker_scrape_sees_both_prefork_workers(tmp_path, monkeypatch):
         "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "FS",
-        "PIO_JAX_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PIO_METRICS_FLUSH_S": "0.2",
     }
     for k, v in env_vars.items():

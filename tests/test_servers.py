@@ -177,11 +177,34 @@ def test_query_server_predicts(deployed_engine):
     base = deployed_engine["base"]
     status, info = http("GET", base + "/")
     assert status == 200 and info["engineId"] == "qs-engine"
+    # the server says what it runs on and what it resolved to (ALS has no
+    # scorer/tail choice; the batcher's auto is off on the CPU backend)
+    assert info["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
+    assert (info["scorer"], info["tail"], info["batcher"]) == (None, None, False)
+    assert info["compile"]["programs"] >= 0 and "cacheDir" in info["compile"]
     status, res = http("POST", base + "/queries.json", {"user": "u1", "num": 3})
     assert status == 200
     items = [s["item"] for s in res["itemScores"]]
     assert len(items) == 3
     assert all(int(i[1:]) < 4 for i in items), items
+
+
+def test_deploy_fails_when_backend_fails(deployed_engine, monkeypatch):
+    """A JAX backend that cannot initialise fails the deploy — no server
+    comes up quietly on something else than what it was deployed for."""
+    from predictionio_tpu.utils import device
+    from predictionio_tpu.workflow.create_server import deploy
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(device, "device_info", broken)
+    for batch in ("auto", "off"):
+        monkeypatch.setenv("PIO_SERVE_BATCH", batch)
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            deploy(engine_json=str(deployed_engine["engine_json"]),
+                   host="127.0.0.1", port=0, background=True,
+                   storage=deployed_engine["storage"])
 
 
 def test_query_server_bad_requests(deployed_engine):
@@ -497,7 +520,7 @@ def test_prefork_workers_share_port_and_die_with_server(tmp_path, monkeypatch):
         "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "FS",
-        "PIO_JAX_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
     }
     for k, v in env_vars.items():
         monkeypatch.setenv(k, v)

@@ -54,25 +54,46 @@ def test_running_merge_across_tiles_matches_global_topk():
     _check_topk(x, bs, bi, b)
 
 
-def test_pallas_tile_topk_desc_matches_lax():
+def test_pallas_tile_topk_desc_matches_lax(monkeypatch):
     from predictionio_tpu.ops.pallas_kernels import tile_topk_desc
 
+    monkeypatch.setenv("PIO_PALLAS", "interpret")
     rng = np.random.default_rng(2)
     for (r, w, b) in [(9, 300, 64), (3, 64, 128), (5, 520, 16)]:
         x = rng.standard_normal((r, w)).astype(np.float32)
         x[x < 0] = -np.inf
         x[0, : min(5, w)] = 2.0
-        s, i = tile_topk_desc(jnp.asarray(x), b, block_r=8)
+        s, i = tile_topk_desc(jnp.asarray(x), b)
         _check_topk(x, s, i, min(b, w))
+
+
+def test_pallas_kernels_never_interpret_silently(monkeypatch):
+    """Off-TPU a kernel runs only when PIO_PALLAS=interpret asks for the
+    interpreter; any other setting raises instead of quietly
+    interpreting (a CPU run must not pass for a kernel run)."""
+    from predictionio_tpu.ops.pallas_kernels import (
+        llr_masked_scores, tile_topk_desc)
+
+    x = jnp.zeros((8, 128), jnp.float32)
+    for conf in (None, "compiled"):
+        if conf is None:
+            monkeypatch.delenv("PIO_PALLAS", raising=False)
+        else:
+            monkeypatch.setenv("PIO_PALLAS", conf)
+        with pytest.raises(RuntimeError, match="PIO_PALLAS=interpret"):
+            tile_topk_desc(x, 8)
+        with pytest.raises(RuntimeError, match="PIO_PALLAS=interpret"):
+            llr_masked_scores(x, jnp.ones(8), jnp.ones(128), 10.0)
 
 
 @pytest.mark.parametrize("strategy", ["resident", "chunked", "dense"])
 def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
     """dense ≡ tiled parity contract extended to the merge impl: the CCO
     indicator tables are identical under PIO_CCO_TOPK=lax and =pallas on
-    every device strategy (the kernel runs in interpret mode on CPU)."""
+    every device strategy (kernels in interpret mode on CPU)."""
     from predictionio_tpu.ops import cco as cco_ops
 
+    monkeypatch.setenv("PIO_PALLAS", "interpret")
     rng = np.random.default_rng(3)
     n_users, n_ip, n_it = 80, 30, 47
     pu = rng.integers(0, n_users, 500)
@@ -113,7 +134,7 @@ def test_topk_impl_env(monkeypatch):
     monkeypatch.setenv("PIO_CCO_TOPK", "lax")
     assert topk_impl() == "lax"
     monkeypatch.delenv("PIO_CCO_TOPK", raising=False)
-    assert topk_impl() == "lax"    # auto stays lax until hardware-verified
+    assert topk_impl() == "lax"    # auto stays lax until S3 measures both
     assert _carry_width(50, "pallas") == 64
     assert _carry_width(50, "lax") == 50
     assert _carry_width(3, "pallas") == 8

@@ -300,7 +300,7 @@ def test_cross_worker_traces_merge(tmp_path, monkeypatch, fresh_recorder):
         "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "FS",
         "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "FS",
-        "PIO_JAX_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PIO_METRICS_FLUSH_S": "0.2",
     }.items():
         monkeypatch.setenv(k, v)
