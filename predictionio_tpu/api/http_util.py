@@ -56,6 +56,7 @@ from typing import Any, Dict, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
 from predictionio_tpu.native import core as _ncore
+from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.obs import tracing as _tracing
 from predictionio_tpu.obs.metrics import get_registry
 
@@ -970,7 +971,7 @@ class EventLoopHTTPServer:
         trace = recorder.begin(
             h.request_id, req.command,
             debug=req.headers.get("x-pio-debug") is not None)
-        token = _tracing._CURRENT.set(trace) if trace is not None else None
+        token = _spans._ACTIVE.set(trace) if trace is not None else None
         _M_INFLIGHT.inc()
         t0 = time.perf_counter()
         try:
@@ -997,7 +998,7 @@ class EventLoopHTTPServer:
             _M_INFLIGHT.dec()
             route = route_label(req.path)
             if token is not None:
-                _tracing._CURRENT.reset(token)
+                _spans._ACTIVE.reset(token)
                 recorder.finish(trace, h._status_sent or 0, route)
             # exemplar: the max-latency observation per window carries
             # its trace id, linking /metrics tails to /traces/<rid>.json

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+from collections.abc import Sized
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Type
 
 from predictionio_tpu.controller.params import EmptyParams, Params
@@ -26,6 +27,7 @@ from predictionio_tpu.core.base import (
     BaseServing,
     doer_name,
 )
+from predictionio_tpu.obs.spans import span
 
 #: fleet default for serving micro-batch size; engines tighten it via a
 #: ``serve_batch_max`` class attribute
@@ -106,9 +108,19 @@ class Engine(BaseEngine):
         algorithm (order preserved; serving combines their predictions).
         """
         data_source, preparator, algorithms, _ = self.make_components(engine_params)
-        td = data_source.read_training()
-        pd = preparator.prepare(td)
-        return [algo.train(pd) for algo in algorithms]
+        with span("read_training") as rec:
+            td = data_source.read_training()
+            if isinstance(td, Sized):      # rows read, where the batch knows
+                rec["attrs"] = {"events": len(td)}
+        with span("prepare"):
+            pd = preparator.prepare(td)
+        models = []
+        for algo in algorithms:
+            # its self time is the model built on the host around the
+            # device work (which has spans of its own in ops/)
+            with span("algo_train", algorithm=type(algo).__name__):
+                models.append(algo.train(pd))
+        return models
 
     # -- eval ----------------------------------------------------------------
 

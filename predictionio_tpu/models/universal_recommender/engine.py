@@ -271,6 +271,10 @@ class URTrainingData:
     interactions: Dict[str, Tuple[np.ndarray, np.ndarray, IdDict, np.ndarray]]
     item_properties: Dict[str, Dict[str, Any]]  # item id -> property map
 
+    def __len__(self) -> int:
+        """Interaction events read, over all event types."""
+        return sum(len(v[0]) for v in self.interactions.values())
+
 
 class URDataSource(DataSource):
     params_class = URDataSourceParams
@@ -1544,12 +1548,11 @@ class URAlgorithm(Algorithm):
         waterfall."""
         stages: List[Tuple[str, float]] = []
         meta: Dict[str, str] = {}
-        journal = _spans.current_journal()
-        trace = _tracing.current_trace() if journal is None else None
-        if journal is None and trace is None:
+        sink = _spans.active_collector()
+        if sink is None:
             return self._predict_staged(model, query, hist_override, stages,
                                         meta)
-        sink = journal if journal is not None else trace
+        trace = _tracing.current_trace()
         with sink.span("ur_predict") as rec:
             res = self._predict_staged(model, query, hist_override, stages,
                                        meta)

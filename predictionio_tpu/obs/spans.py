@@ -13,9 +13,17 @@ parent/child links.  Two consumers build on it:
 
 Parent/child structure comes from a per-thread stack: a span opened
 while another is active on the same thread becomes its child.  The
-ACTIVE journal travels via a contextvar, so any ``timed()`` call inside
-``engine.train`` — engine code never imports this module — lands in the
-run's journal automatically.
+ACTIVE collector — a run's journal or a request's trace, whichever was
+activated innermost — travels via ONE contextvar, and :func:`span` is the
+one way to open a span on it: ``engine.train``, the ops and the storage
+layer call it without knowing who, if anyone, is collecting.
+
+Every span is also a ``jax.profiler.TraceAnnotation("pio:<name>")`` over
+the same interval when ``jax`` is already imported in the process, so a
+profiler trace shows the host spans on the device trace's own clock.
+This package never imports JAX itself (the event server must not load
+it); with no profiler session open an annotation costs under a
+microsecond.
 
 Journal location (:func:`spans_dir`): ``PIO_SPANS_DIR`` if set, else
 ``<storage localfs/sharedfs METADATA path>/spans/`` (next to the engine
@@ -29,17 +37,60 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
+from collections import deque
 from pathlib import Path
 from typing import Iterator, List, Optional
 
-_CURRENT: contextvars.ContextVar[Optional["SpanJournal"]] = (
-    contextvars.ContextVar("pio_span_journal", default=None))
+_ACTIVE: contextvars.ContextVar[Optional["SpanCollector"]] = (
+    contextvars.ContextVar("pio_span_collector", default=None))
+
+# span lists of the journals this process completed last, newest last:
+# whoever is in the process (the benchmark's readers) reads them after
+# the files are gone
+_RECENT: deque = deque(maxlen=64)
+
+
+def active_collector() -> Optional["SpanCollector"]:
+    """The journal or request trace activated innermost in this context."""
+    return _ACTIVE.get()
 
 
 def current_journal() -> Optional["SpanJournal"]:
-    return _CURRENT.get()
+    c = _ACTIVE.get()
+    return c if isinstance(c, SpanJournal) else None
+
+
+def recent_runs() -> List[List[dict]]:
+    """The spans of the last 64 journals completed in this process."""
+    return [sorted(run, key=lambda s: s["id"]) for run in list(_RECENT)]
+
+
+def _annotation(name: str):
+    """The profiler's annotation for a span, or a no-op where JAX is not
+    (yet, or not fully) imported: this module never imports it."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation("pio:" + name)
+
+
+def span(name: str, **attrs):
+    """Open a span on the active collector; with none active only the
+    profiler annotation is entered and a throw-away record is yielded, so
+    a caller may set attrs on it either way."""
+    collector = _ACTIVE.get()
+    if collector is not None:
+        return collector.span(name, **attrs)
+    return _uncollected_span(name, attrs)
+
+
+@contextlib.contextmanager
+def _uncollected_span(name: str, attrs: dict) -> Iterator[dict]:
+    with _annotation(name):
+        yield {"name": name, "attrs": attrs}
 
 
 class SpanCollector:
@@ -76,7 +127,8 @@ class SpanCollector:
         stack.append(span_id)
         t0 = time.perf_counter()
         try:
-            yield rec
+            with _annotation(name):
+                yield rec
         except BaseException:
             rec["error"] = True
             raise
@@ -106,9 +158,24 @@ class SpanCollector:
             self._spans.append(rec)
         return rec
 
+    def open_span_id(self) -> Optional[int]:
+        """The innermost span open on this thread, for ``add_span``."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
     def spans(self) -> List[dict]:
         with self._lock:
             return sorted(self._spans, key=lambda s: s["id"])
+
+    @contextlib.contextmanager
+    def activate(self) -> Iterator["SpanCollector"]:
+        """Make this the collector :func:`span` records on, for the
+        duration."""
+        token = _ACTIVE.set(self)
+        try:
+            yield self
+        finally:
+            _ACTIVE.reset(token)
 
     def _on_root_complete(self) -> None:
         """Hook: a top-level span just finished (journals flush here)."""
@@ -126,6 +193,7 @@ class SpanJournal(SpanCollector):
         self.path = Path(path)
         self._file = None
         self._flushed = 0   # count of spans already appended to the file
+        self._recent = False   # whether _RECENT holds this run's spans
 
     def _on_root_complete(self) -> None:
         try:
@@ -161,6 +229,11 @@ class SpanJournal(SpanCollector):
         treat it as 'persist everything now')."""
         self.flush()
         with self._lock:
+            if self._spans and not self._recent:
+                # the live list, once: a later root span of this journal
+                # shows there too
+                self._recent = True
+                _RECENT.append(self._spans)
             if self._file is not None:
                 self._file.close()
                 self._file = None
@@ -173,14 +246,13 @@ class SpanJournal(SpanCollector):
 
     @contextlib.contextmanager
     def activate(self) -> Iterator["SpanJournal"]:
-        """Make this the process-current journal (timed() feeds it) for
-        the duration; the journal is fully persisted on exit, success or
-        not (and incrementally while running)."""
-        token = _CURRENT.set(self)
+        """Make this the active collector for the duration; the journal
+        is fully persisted on exit, success or not (and incrementally
+        while running)."""
         try:
-            yield self
+            with super().activate():
+                yield self
         finally:
-            _CURRENT.reset(token)
             try:
                 self.write()
             except OSError:

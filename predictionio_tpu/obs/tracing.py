@@ -2,8 +2,8 @@
 
 Every request on the event server, query server, and dashboard opens a
 live :class:`Trace` (keyed by the X-Request-ID the http_util middleware
-already mints/propagates); instrumented layers append spans to it
-through the ``current_trace()`` contextvar (``utils.tracing.timed``, the
+already mints/propagates) and makes it the active collector;
+instrumented layers append spans to it through ``obs.spans.span`` (the
 storage group commit, snapshot scans, and the UR serve tail all feed
 it).  At request end the :class:`FlightRecorder` makes the *tail
 sampling* decision (Dapper/Canopy style — record everything cheaply,
@@ -36,7 +36,6 @@ in-memory only.  Kill switch: ``PIO_TRACING=off``.
 from __future__ import annotations
 
 import contextlib
-import contextvars
 import json
 import os
 import random
@@ -47,6 +46,7 @@ from pathlib import Path
 from typing import Dict, List, Optional
 
 from predictionio_tpu.obs import metrics as _metrics
+from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.obs.spans import SpanCollector
 
 _REG = _metrics.get_registry()
@@ -58,9 +58,6 @@ _M_EVICTED = _REG.counter(
     "pio_trace_ring_evictions_total",
     "Retained traces evicted from the ring buffer by newer ones")
 
-_CURRENT: contextvars.ContextVar[Optional["Trace"]] = (
-    contextvars.ContextVar("pio_trace", default=None))
-
 # span/attr naming contract (linted by scripts/check_metrics_names.py):
 # lowercase snake with optional dots, like metric names without the
 # pio_ prefix — keeps waterfall rows greppable and dashboards stable
@@ -68,17 +65,12 @@ SPAN_NAME_PATTERN = r"^[a-z][a-z0-9_.]*$"
 
 
 def current_trace() -> Optional["Trace"]:
-    return _CURRENT.get()
+    c = _spans.active_collector()
+    return c if isinstance(c, Trace) else None
 
 
-def trace_span(name: str, **attrs):
-    """Span on the current request trace, or a no-op when none is active
-    — the one-liner instrumented layers use so they never import more
-    than this function."""
-    t = _CURRENT.get()
-    if t is None:
-        return contextlib.nullcontext()
-    return t.span(name, **attrs)
+# the name the storage layer and docs/operations.md use for obs.spans.span
+trace_span = _spans.span
 
 
 class Trace(SpanCollector):
@@ -110,14 +102,6 @@ class Trace(SpanCollector):
 
     def duration_s(self) -> float:
         return time.perf_counter() - self._t0
-
-    @contextlib.contextmanager
-    def activate(self):
-        token = _CURRENT.set(self)
-        try:
-            yield self
-        finally:
-            _CURRENT.reset(token)
 
 
 def _env_float(name: str, default: float) -> float:

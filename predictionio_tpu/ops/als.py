@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.obs.spans import span
+
 
 @dataclasses.dataclass
 class ALSData:
@@ -101,16 +103,17 @@ def prepare_als_data(
     n_items: int,
     dp: int,
 ) -> ALSData:
-    user_idx = np.asarray(user_idx, np.int32)
-    item_idx = np.asarray(item_idx, np.int32)
-    rating = np.asarray(rating, np.float32)
-    user_rows = max(math.ceil(n_users / dp), 1)
-    item_rows = max(math.ceil(n_items / dp), 1)
-    # flat index of the OTHER side's factor row: shard * rows + local_row
-    item_flat = (item_idx % dp) * item_rows + item_idx // dp
-    user_flat = (user_idx % dp) * user_rows + user_idx // dp
-    uu, ui, ur, um = _group_by_shard(user_idx, item_flat, rating, dp)
-    ii, iu, ir, im = _group_by_shard(item_idx, user_flat, rating, dp)
+    with span("layout"):
+        user_idx = np.asarray(user_idx, np.int32)
+        item_idx = np.asarray(item_idx, np.int32)
+        rating = np.asarray(rating, np.float32)
+        user_rows = max(math.ceil(n_users / dp), 1)
+        item_rows = max(math.ceil(n_items / dp), 1)
+        # flat index of the OTHER side's factor row: shard * rows + local_row
+        item_flat = (item_idx % dp) * item_rows + item_idx // dp
+        user_flat = (user_idx % dp) * user_rows + user_idx // dp
+        uu, ui, ur, um = _group_by_shard(user_idx, item_flat, rating, dp)
+        ii, iu, ir, im = _group_by_shard(item_idx, user_flat, rating, dp)
     return ALSData(
         dp=dp, n_users=n_users, n_items=n_items,
         user_rows=user_rows, item_rows=item_rows,
@@ -130,17 +133,24 @@ def _half_step(
 ) -> jnp.ndarray:
     """Solve per-row normal equations (YtCY + λ n_e I) x = Ytr on one shard."""
     k = other_full.shape[-1]
-    y = other_full[other_flat] * mask[:, None]            # [E, K]
-    # A: segment-summed outer products, MXU-batched as [E, K, K] contributions
-    outer = y[:, :, None] * y[:, None, :]
-    A = jax.ops.segment_sum(outer, local_idx, num_segments=rows)
-    b = jax.ops.segment_sum(y * rating[:, None], local_idx, num_segments=rows)
-    n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
-    # λ·n_e ridge (MLlib's ALS-WR weighting) + ε guard for empty rows
-    lam = reg * jnp.maximum(n_e, 1.0) + 1e-6
-    A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
-    cho = jax.scipy.linalg.cho_factor(A)
-    return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
+    with jax.named_scope("als.gather"):
+        y = other_full[other_flat] * mask[:, None]            # [E, K]
+    with jax.named_scope("als.normal_eq"):
+        # A: segment-summed outer products, MXU-batched as [E, K, K]
+        # contributions
+        outer = y[:, :, None] * y[:, None, :]
+        A = jax.ops.segment_sum(outer, local_idx, num_segments=rows)
+    with jax.named_scope("als.rhs"):
+        b = jax.ops.segment_sum(y * rating[:, None], local_idx,
+                                num_segments=rows)
+    with jax.named_scope("als.normal_eq"):
+        n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
+        # λ·n_e ridge (MLlib's ALS-WR weighting) + ε guard for empty rows
+        lam = reg * jnp.maximum(n_e, 1.0) + 1e-6
+        A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
+    with jax.named_scope("als.solve"):
+        cho = jax.scipy.linalg.cho_factor(A)
+        return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
 
 
 def _half_step_implicit(
@@ -295,7 +305,8 @@ def als_train(
             data, k, reg, iterations, mesh, seed, checkpoint, checkpoint_every,
             implicit=implicit, alpha=alpha,
         )
-    x0, y0 = _als_init(data, k, seed)
+    with span("dispatch", program="_als_init"):
+        x0, y0 = _als_init(data, k, seed)
     x, y = _als_sweeps(data, x0, y0, iterations, reg, mesh,
                        implicit=implicit, alpha=alpha)
     return _als_deinterleave(data, x, y, k)
@@ -318,12 +329,10 @@ def _als_init(data: ALSData, k: int, seed: int):
 
 
 def _als_device_args(data: ALSData):
-    return (
-        jnp.asarray(data.u_user_local), jnp.asarray(data.u_item_flat),
-        jnp.asarray(data.u_rating), jnp.asarray(data.u_mask),
-        jnp.asarray(data.i_item_local), jnp.asarray(data.i_user_flat),
-        jnp.asarray(data.i_rating), jnp.asarray(data.i_mask),
-    )
+    host = (data.u_user_local, data.u_item_flat, data.u_rating, data.u_mask,
+            data.i_item_local, data.i_user_flat, data.i_rating, data.i_mask)
+    with span("h2d", bytes=sum(a.nbytes for a in host)):
+        return tuple(jnp.asarray(a) for a in host)
 
 
 def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=None,
@@ -331,23 +340,28 @@ def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=Non
     if args is None:
         args = _als_device_args(data)
     if mesh is None:
-        return _als_run_single(
-            x0, y0, jnp.int32(n_sweeps), jnp.float32(reg), jnp.float32(alpha),
-            *args, user_rows=data.user_rows, item_rows=data.item_rows,
-            implicit=implicit,
-        )
+        with span("dispatch", program="_als_run_single"):
+            return _als_run_single(
+                x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
+                jnp.float32(alpha),
+                *args, user_rows=data.user_rows, item_rows=data.item_rows,
+                implicit=implicit,
+            )
     if mesh.shape.get("dp", 1) != data.dp:
         raise ValueError(
             f"ALSData prepared for dp={data.dp}, mesh has dp={mesh.shape.get('dp')}")
     sharding = NamedSharding(mesh, P("dp"))
     from predictionio_tpu.parallel.sharding import stage_global
 
-    x0 = stage_global(np.asarray(x0), sharding)
-    y0 = stage_global(np.asarray(y0), sharding)
-    return _als_run_sharded(
-        mesh, data.user_rows, data.item_rows, implicit,
-        x0, y0, jnp.int32(n_sweeps), jnp.float32(reg), jnp.float32(alpha), *args,
-    )
+    with span("h2d", bytes=x0.nbytes + y0.nbytes):
+        x0 = stage_global(np.asarray(x0), sharding)
+        y0 = stage_global(np.asarray(y0), sharding)
+    with span("dispatch", program="_als_run_sharded"):
+        return _als_run_sharded(
+            mesh, data.user_rows, data.item_rows, implicit,
+            x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
+            jnp.float32(alpha), *args,
+        )
 
 
 def _als_deinterleave(data: ALSData, x, y, k: int):
@@ -361,8 +375,10 @@ def _als_deinterleave(data: ALSData, x, y, k: int):
             a = multihost_utils.process_allgather(a, tiled=True)
         return np.asarray(a)
 
-    x = host(x).transpose(1, 0, 2).reshape(-1, k)[: data.n_users]
-    y_arr = host(y).transpose(1, 0, 2).reshape(-1, k)[: data.n_items]
+    with span("device_wait", bytes=x.nbytes + y.nbytes):
+        x, y = host(x), host(y)
+    x = x.transpose(1, 0, 2).reshape(-1, k)[: data.n_users]
+    y_arr = y.transpose(1, 0, 2).reshape(-1, k)[: data.n_items]
     return x, y_arr
 
 
@@ -401,7 +417,8 @@ def _als_train_checkpointed(
             x = jnp.asarray(state["x"])
             y = jnp.asarray(state["y"])
     if x is None:
-        x, y = _als_init(data, k, seed)
+        with span("dispatch", program="_als_init"):
+            x, y = _als_init(data, k, seed)
     args = _als_device_args(data)  # one host->device upload for all chunks
     while done < iterations:
         n = min(checkpoint_every, iterations - done)

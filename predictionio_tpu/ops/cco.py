@@ -47,6 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.obs.spans import span
+
 
 # ---------------------------------------------------------------------------
 # host-side layout
@@ -244,21 +246,28 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
         from predictionio_tpu.ops.topk import merge_desc
 
         b = best_scores.shape[1]
-        ts, ti = tile_topk_desc(scores, b)
-        return merge_desc(best_scores, best_idx, ts, tile_start + ti)
-    all_scores = jnp.concatenate([best_scores, scores], axis=1)
-    all_idx = jnp.concatenate(
-        [best_idx, jnp.broadcast_to(tile_idx, scores.shape)], axis=1)
-    new_scores, pos = jax.lax.top_k(all_scores, top_k)
-    return new_scores, jnp.take_along_axis(all_idx, pos, axis=1)
+        with jax.named_scope("cco.topk_merge"):
+            ts, ti = tile_topk_desc(scores, b)
+            return merge_desc(best_scores, best_idx, ts, tile_start + ti)
+    with jax.named_scope("cco.topk_merge"):
+        all_scores = jnp.concatenate([best_scores, scores], axis=1)
+        all_idx = jnp.concatenate(
+            [best_idx, jnp.broadcast_to(tile_idx, scores.shape)], axis=1)
+        new_scores, pos = jax.lax.top_k(all_scores, top_k)
+    with jax.named_scope("cco.topk_gather"):
+        return new_scores, jnp.take_along_axis(all_idx, pos, axis=1)
 
 
 def _finalize_topk(best_scores, best_idx, n_items_t: int,
                    top_k: Optional[int] = None):
     """Shared host epilogue: -1-pad entries that are -inf or tile padding;
     slice a pow2-widened pallas-merge carry back to the requested top_k."""
-    scores = np.asarray(best_scores)
-    idx = np.asarray(best_idx)
+    if isinstance(best_scores, np.ndarray):     # the host tail's own arrays
+        scores, idx = best_scores, np.asarray(best_idx)
+    else:
+        with span("device_wait", bytes=best_scores.nbytes + best_idx.nbytes):
+            scores = np.asarray(best_scores)
+            idx = np.asarray(best_idx)
     if top_k is not None and scores.shape[1] > top_k:
         scores, idx = scores[:, :top_k], idx[:, :top_k]
     idx = np.where((scores > -np.inf) & (idx < n_items_t), idx, -1)
@@ -374,14 +383,17 @@ def _cco_tile_body_resident(
     path which pays n_tiles × that cost."""
     n_rows = P.shape[0]
     n_items_p = P.shape[1]
-    a_local = a_gi - tile_start
-    in_tile = a_valid & (a_local >= 0) & (a_local < tile)
-    A_t = _densify_global(a_gu, jnp.where(in_tile, a_local, 0), in_tile,
-                          n_rows, tile)
-    c = _count_matmul(P, A_t, mm).astype(jnp.float32)
-    cct = _col_count(A_t).astype(jnp.float32)
-    scores = _llr_mask_scores(c, rc.astype(jnp.float32), cct, n_total,
-                              llr_threshold, pallas)
+    with jax.named_scope("cco.densify_tile"):
+        a_local = a_gi - tile_start
+        in_tile = a_valid & (a_local >= 0) & (a_local < tile)
+        A_t = _densify_global(a_gu, jnp.where(in_tile, a_local, 0), in_tile,
+                              n_rows, tile)
+    with jax.named_scope("cco.count_matmul"):
+        c = _count_matmul(P, A_t, mm).astype(jnp.float32)
+        cct = _col_count(A_t).astype(jnp.float32)
+    with jax.named_scope("cco.llr"):
+        scores = _llr_mask_scores(c, rc.astype(jnp.float32), cct, n_total,
+                                  llr_threshold, pallas)
     return _merge_topk(best_scores, best_idx, scores, tile_start, tile,
                        top_k, n_items_p, exclude_self, impl=topk)
 
@@ -446,28 +458,34 @@ def _cco_indicators_resident(
     n_total_users: int, top_k: int, llr_threshold: float,
     item_tile: int, exclude_self: bool,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    pu, pi = _flatten_blocked(primary)
-    au, ai = _flatten_blocked(other) if other is not primary else (pu, pi)
+    with span("layout"):
+        pu, pi = _flatten_blocked(primary)
+        au, ai = _flatten_blocked(other) if other is not primary else (pu, pi)
     n_items_p, n_items_t = primary.n_items, other.n_items
     n_rows = max(((primary.n_users + 127) // 128) * 128, 128)
     mm = _matmul_dtype()
-    P = _densify_global(jnp.asarray(pu), jnp.asarray(pi),
-                        jnp.ones(len(pu), bool), n_rows, n_items_p)
-    rc = _col_count(P)
-    a_gu, a_gi = jnp.asarray(au), jnp.asarray(ai)
-    a_valid = jnp.ones(len(au), bool)
+    with span("h2d", bytes=pu.nbytes + pi.nbytes):
+        p_gu, p_gi = jnp.asarray(pu), jnp.asarray(pi)
+        p_valid = jnp.ones(len(pu), bool)
+    with span("dispatch", program="_densify_global"):
+        P = _densify_global(p_gu, p_gi, p_valid, n_rows, n_items_p)
+        rc = _col_count(P)
+    with span("h2d", bytes=au.nbytes + ai.nbytes):
+        a_gu, a_gi = jnp.asarray(au), jnp.asarray(ai)
+        a_valid = jnp.ones(len(au), bool)
     tile = min(item_tile, max(n_items_t, 1))
     n_tiles = math.ceil(n_items_t / tile)
 
     from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
-    best_scores, best_idx = _cco_resident_all_tiles(
-        P, rc, a_gu, a_gi, a_valid, float(n_total_users),
-        n_tiles=n_tiles, tile=tile, top_k=top_k,
-        llr_threshold=float(llr_threshold),
-        exclude_self=exclude_self, pallas=pallas_mode(), mm=mm,
-        topk=topk_impl(),
-    )
+    with span("dispatch", program="_cco_resident_all_tiles"):
+        best_scores, best_idx = _cco_resident_all_tiles(
+            P, rc, a_gu, a_gi, a_valid, float(n_total_users),
+            n_tiles=n_tiles, tile=tile, top_k=top_k,
+            llr_threshold=float(llr_threshold),
+            exclude_self=exclude_self, pallas=pallas_mode(), mm=mm,
+            topk=topk_impl(),
+        )
     return _finalize_topk(best_scores, best_idx, n_items_t, top_k)
 
 
@@ -703,22 +721,25 @@ def _stage_chunked(
     if len(user) and (int(user.min()) < 0 or int(user.max()) >= chunk * n_chunks):
         raise ValueError(
             f"user ids outside [0, {chunk * n_chunks}) in _stage_chunked")
-    native = layout_chunks(user, item, chunk, n_chunks) if len(user) else None
-    if native is not None:
-        lu, it, counts = native   # O(E) two-pass counting layout in C++
-    else:
-        # numpy fallback: reuse the one shared layout implementation
-        b = block_interactions_stream(
-            [(user, item)], n_chunks * chunk, 0, user_block=chunk)
-        lu, it = b.local_u[:n_chunks], b.item[:n_chunks]
-        counts = b.mask[:n_chunks].sum(axis=1).astype(np.int32)
+    with span("layout"):
+        native = (layout_chunks(user, item, chunk, n_chunks)
+                  if len(user) else None)
+        if native is not None:
+            lu, it, counts = native   # O(E) two-pass counting layout in C++
+        else:
+            # numpy fallback: reuse the one shared layout implementation
+            b = block_interactions_stream(
+                [(user, item)], n_chunks * chunk, 0, user_block=chunk)
+            lu, it = b.local_u[:n_chunks], b.item[:n_chunks]
+            counts = b.mask[:n_chunks].sum(axis=1).astype(np.int32)
     if sharding is not None:
         from predictionio_tpu.parallel.sharding import stage_global
 
         put = lambda x: stage_global(np.asarray(x), sharding)  # noqa: E731
     else:
         put = jnp.asarray
-    return _StagedCOO(put(lu), put(it), put(counts))
+    with span("h2d", bytes=lu.nbytes + it.nbytes + counts.nbytes):
+        return _StagedCOO(put(lu), put(it), put(counts))
 
 
 def _dense_path_ok(n_items_p: int, n_items_t: int) -> bool:
@@ -1089,7 +1110,8 @@ class _SparseHostRunner:
         self.n_users = n_users
         self.n_total_users = n_total_users if n_total_users else n_users
         self.n_items_p = n_items_p
-        self.p = _SparseHostCSR(p_user, p_item, n_items_p, n_users)
+        with span("layout"):
+            self.p = _SparseHostCSR(p_user, p_item, n_items_p, n_users)
 
     def _dispatch_coo(self, a: _SparseHostCSR, n_items_t: int, top_k: int,
                       llr_threshold: float, exclude_self: bool,
@@ -1113,41 +1135,49 @@ class _SparseHostRunner:
     def dispatch(self, a_user, a_item, n_items_t: int, top_k: int,
                  llr_threshold: float, exclude_self: bool,
                  self_pair: bool = False):
-        a = self.p if self_pair else _SparseHostCSR(
-            a_user, a_item, n_items_t, self.n_users)
-        pairs = _cross_join_pairs(self.p, a)
-        tail = _sparse_tail()
-        if tail == "auto":
-            # nnz ≤ total cross-join pairs, so pairs/cells bounds the
-            # occupancy the host tail would have to sort; past ~0.25 the
-            # dense device tail is the better deal (measured crossover)
-            tail = "host" if pairs * 4 < self.n_items_p * n_items_t \
-                else "device"
-        host_tail = tail == "host"
-        if host_tail and self.n_items_p * n_items_t * 4 > _SPARSE_C_BYTES:
-            # the dense count matrix cannot exist at this catalog size;
-            # the pure-COO path is the only O(nnz) strategy left
-            return self._dispatch_coo(a, n_items_t, top_k, llr_threshold,
-                                      exclude_self, pairs)
-        got = _sparse_counts(self.p, a, want_coo=host_tail,
-                             total_pairs=pairs)
-        if got is None:
-            return None
-        if host_tail:
-            C, flat = got
-            s, i = _llr_topk_sparse_host(
-                C, self.p.col_counts, a.col_counts,
-                float(self.n_total_users), float(llr_threshold),
-                top_k=top_k, exclude_self=bool(exclude_self), flat=flat)
-        else:
-            # imported here, not at dispatch entry: the pallas machinery
-            # is a ~0.35 s one-time import the host tail never needs
-            from predictionio_tpu.ops.pallas_kernels import pallas_mode
+        # the counting, and the host tail where it is taken, are the
+        # host's own compute: one span
+        with span("host_compute"):
+            a = self.p if self_pair else _SparseHostCSR(
+                a_user, a_item, n_items_t, self.n_users)
+            pairs = _cross_join_pairs(self.p, a)
+            tail = _sparse_tail()
+            if tail == "auto":
+                # nnz ≤ total cross-join pairs, so pairs/cells bounds the
+                # occupancy the host tail would have to sort; past ~0.25
+                # the dense device tail is the better deal (measured
+                # crossover)
+                tail = "host" if pairs * 4 < self.n_items_p * n_items_t \
+                    else "device"
+            host_tail = tail == "host"
+            if host_tail and self.n_items_p * n_items_t * 4 > _SPARSE_C_BYTES:
+                # the dense count matrix cannot exist at this catalog
+                # size; the pure-COO path is the only O(nnz) strategy left
+                return self._dispatch_coo(a, n_items_t, top_k, llr_threshold,
+                                          exclude_self, pairs)
+            got = _sparse_counts(self.p, a, want_coo=host_tail,
+                                 total_pairs=pairs)
+            if got is None:
+                return None
+            if host_tail:
+                C, flat = got
+                s, i = _llr_topk_sparse_host(
+                    C, self.p.col_counts, a.col_counts,
+                    float(self.n_total_users), float(llr_threshold),
+                    top_k=top_k, exclude_self=bool(exclude_self), flat=flat)
+                return s, i, n_items_t, top_k
+        # imported here, not at dispatch entry: the pallas machinery
+        # is a ~0.35 s one-time import the host tail never needs
+        from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
-            C = got
+        C = got
+        with span("h2d", bytes=C.nbytes + self.p.col_counts.nbytes
+                  + a.col_counts.nbytes):
+            C_d, rc_d, cc_d = (jnp.asarray(C), jnp.asarray(self.p.col_counts),
+                               jnp.asarray(a.col_counts))
+        with span("dispatch", program="_llr_topk_dense"):
             s, i = _llr_topk_dense(
-                jnp.asarray(C), jnp.asarray(self.p.col_counts),
-                jnp.asarray(a.col_counts),
+                C_d, rc_d, cc_d,
                 float(self.n_total_users), float(llr_threshold),
                 top_k=min(top_k, C.shape[1]),
                 exclude_self=bool(exclude_self),
@@ -1227,13 +1257,15 @@ class _DenseRunner:
             it_pad = max(((n_items_t + 127) // 128) * 128, 128)
             a = _stage_chunked(a_user, a_item,
                                self.chunk, self.n_chunks, self.sharding)
-        C, rc, cc = self._counts(a, it_pad, self_pair)
+        with span("dispatch", program="_cco_counts_dense"):
+            C, rc, cc = self._counts(a, it_pad, self_pair)
         k = min(top_k, it_pad)
-        s, i = _llr_topk_dense(
-            C, rc, cc, float(self.n_total_users), float(llr_threshold),
-            top_k=k, exclude_self=bool(exclude_self), pallas=pallas_mode(),
-            topk=topk_impl(),
-        )
+        with span("dispatch", program="_llr_topk_dense"):
+            s, i = _llr_topk_dense(
+                C, rc, cc, float(self.n_total_users), float(llr_threshold),
+                top_k=k, exclude_self=bool(exclude_self),
+                pallas=pallas_mode(), topk=topk_impl(),
+            )
         return s, i, n_items_t, top_k
 
     @staticmethod
@@ -1377,10 +1409,11 @@ def cco_indicators_coo(
             p_user, p_item, a_user, a_item, n_users, n_items_p, n_items_t,
             top_k, llr_threshold, mesh, exclude_self,
         )
-    p = block_interactions(p_user, p_item, n_users, n_items_p,
-                           user_block=user_block)
-    a = block_interactions(a_user, a_item, n_users, n_items_t,
-                           user_block=user_block)
+    with span("layout"):
+        p = block_interactions(p_user, p_item, n_users, n_items_p,
+                               user_block=user_block)
+        a = block_interactions(a_user, a_item, n_users, n_items_t,
+                               user_block=user_block)
     return cco_indicators(
         p, a, None, None, n_users, top_k=top_k, llr_threshold=llr_threshold,
         item_tile=item_tile, mesh=mesh, exclude_self=exclude_self,
@@ -1426,8 +1459,9 @@ def cco_indicators(
     if _dense_path_ok(primary.n_items, other.n_items):
         if primary.n_users != other.n_users:
             raise ValueError("primary/other must share the user space")
-        pu, pi = _flatten_blocked(primary)
-        au, ai = (pu, pi) if other is primary else _flatten_blocked(other)
+        with span("layout"):
+            pu, pi = _flatten_blocked(primary)
+            au, ai = (pu, pi) if other is primary else _flatten_blocked(other)
         return _cco_indicators_dense_coo(
             pu, pi, au, ai, primary.n_users, primary.n_items, other.n_items,
             top_k, llr_threshold, mesh, exclude_self,
@@ -1457,17 +1491,19 @@ def cco_indicators(
 
     pallas = pallas_mode()
 
+    host_args = (primary.local_u, primary.item, primary.mask,
+                 other.local_u, other.item, other.mask)
     if mesh is None:
-        args = (
-            jnp.asarray(primary.local_u), jnp.asarray(primary.item), jnp.asarray(primary.mask),
-            jnp.asarray(other.local_u), jnp.asarray(other.item), jnp.asarray(other.mask),
-        )
-        best_scores, best_idx = _cco_chunked_all_tiles(
-            *args, float(n_total_users),
-            n_tiles=n_tiles, block=primary.user_block, n_items_p=n_items_p,
-            tile=tile, top_k=top_k, llr_threshold=float(llr_threshold),
-            pallas=pallas, exclude_self=exclude_self, topk=topk,
-        )
+        with span("h2d", bytes=sum(a.nbytes for a in host_args)):
+            args = tuple(jnp.asarray(a) for a in host_args)
+        with span("dispatch", program="_cco_chunked_all_tiles"):
+            best_scores, best_idx = _cco_chunked_all_tiles(
+                *args, float(n_total_users),
+                n_tiles=n_tiles, block=primary.user_block,
+                n_items_p=n_items_p,
+                tile=tile, top_k=top_k, llr_threshold=float(llr_threshold),
+                pallas=pallas, exclude_self=exclude_self, topk=topk,
+            )
     else:
         dp = mesh.shape["dp"]
         nb = primary.n_blocks
@@ -1483,13 +1519,9 @@ def cco_indicators(
         spec = P("dp")
         rep = P()
         shard = NamedSharding(mesh, spec)
-        args = tuple(
-            stage_global(pad(np.asarray(a)), shard)
-            for a in (
-                primary.local_u, primary.item, primary.mask,
-                other.local_u, other.item, other.mask,
-            )
-        )
+        with span("h2d", bytes=sum(a.nbytes for a in host_args)):
+            args = tuple(stage_global(pad(np.asarray(a)), shard)
+                         for a in host_args)
 
         @partial(
             jax.shard_map, mesh=mesh,
@@ -1506,10 +1538,11 @@ def cco_indicators(
                 topk=topk,
             )
 
-        for t in range(n_tiles):
-            best_scores, best_idx = tile_step_sharded(
-                *args, best_scores, best_idx, jnp.int32(t * tile),
-            )
+        with span("dispatch", program="_cco_tile_step"):
+            for t in range(n_tiles):
+                best_scores, best_idx = tile_step_sharded(
+                    *args, best_scores, best_idx, jnp.int32(t * tile),
+                )
 
     return _finalize_topk(best_scores, best_idx, n_items_t, top_k)
 

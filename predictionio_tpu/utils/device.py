@@ -52,17 +52,37 @@ def watch_compiles() -> None:
         if _watching:
             return
         _watching = True
+    import time
+
     from jax import monitoring
 
-    def on_duration(event: str, duration: float, **_kw) -> None:
-        if event == _BACKEND_COMPILE:
-            with _lock:
-                _compiles["programs"] += 1
-                _compiles["seconds"] += duration
+    from predictionio_tpu.obs import spans
+
+    # JAX records a cache hit on the compiling thread, inside the interval
+    # whose duration it reports next on that thread
+    hit = threading.local()
+
+    def on_duration(event: str, duration: float, **kw) -> None:
+        if event != _BACKEND_COMPILE:
+            return
+        with _lock:
+            _compiles["programs"] += 1
+            _compiles["seconds"] += duration
+        cache_hit, hit.seen = getattr(hit, "seen", False), False
+        # the compile as a span of the job and stage that paid for it
+        collector = spans.active_collector()
+        if collector is not None:
+            attrs = {"seconds": round(duration, 6), "cache_hit": cache_hit}
+            if kw.get("fun_name"):
+                attrs["program"] = kw["fun_name"]
+            collector.add_span("compile", time.time() - duration, duration,
+                               parent=collector.open_span_id(), attrs=attrs)
 
     def on_event(event: str, **_kw) -> None:
         key = {_CACHE_HIT: "cacheHits", _CACHE_WRITE: "cacheWrites"}.get(event)
         if key is not None:
+            if event == _CACHE_HIT:
+                hit.seen = True
             with _lock:
                 _compiles[key] += 1
 

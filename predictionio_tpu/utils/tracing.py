@@ -1,12 +1,10 @@
 """Tracing/profiling helpers.
 
 The reference has no custom tracer (SURVEY.md §5) — it leans on the Spark UI.
-The TPU-native equivalents: ``jax.named_scope`` for XLA-visible annotation,
-``jax.profiler`` traces viewable in xprof/tensorboard, and a lightweight
-wall-clock timer that feeds the workflow logs — and, when a span journal
-is active (``obs.spans``: ``pio train``/``pio eval`` activate one per
-run), every ``timed()`` block also lands in the journal as a structured
-span with parent/child links.
+The TPU-native equivalents: ``jax.profiler`` traces viewable in
+xprof/tensorboard, and a lightweight wall-clock timer that feeds the
+workflow logs and is a span of ``obs.spans.span`` (the run's journal or
+the request's trace when one is active, and the profiler's trace).
 """
 
 from __future__ import annotations
@@ -17,15 +15,6 @@ import time
 from typing import Iterator, Optional
 
 log = logging.getLogger("pio.trace")
-
-
-@contextlib.contextmanager
-def named_scope(name: str) -> Iterator[None]:
-    """XLA-visible scope (shows up in xprof timelines and HLO names)."""
-    import jax
-
-    with jax.named_scope(name):
-        yield
 
 
 @contextlib.contextmanager
@@ -51,19 +40,13 @@ def timed(name: str, sink: Optional[dict] = None) -> Iterator[None]:
 
     ``sink[name]`` accumulates seconds across calls and
     ``sink[name + ".count"]`` the number of calls, so a sink consumer can
-    tell one 10 s span from a thousand 10 ms ones.  When a span journal
-    is active (obs.spans: train/eval runs), the block is also recorded
-    there as a structured span (with parent/child nesting); otherwise,
-    when a request trace is live (obs.tracing flight recorder), it lands
-    in that trace's waterfall instead."""
-    from predictionio_tpu.obs import spans as _spans
-    from predictionio_tpu.obs import tracing as _tracing
+    tell one 10 s span from a thousand 10 ms ones.  The block is also a
+    span of ``obs.spans.span``."""
+    from predictionio_tpu.obs.spans import span
 
-    sink_obj = _spans.current_journal() or _tracing.current_trace()
-    ctx = sink_obj.span(name) if sink_obj is not None else contextlib.nullcontext()
     t0 = time.perf_counter()
     try:
-        with ctx:
+        with span(name):
             yield
     finally:
         dt = time.perf_counter() - t0

@@ -105,9 +105,10 @@ def run_train(
     instance.status = "TRAINING"
     storage.engine_instances.update(instance)
     attempt = 0
-    # span journal persisted next to the engine instance: every timed()
-    # inside engine.train nests under this run's root span, and
-    # `pio dashboard` renders the breakdown per completed train
+    # span journal persisted next to the engine instance: every span
+    # opened inside engine.train and save_models nests under this run's
+    # root span, and `pio dashboard` renders the breakdown per completed
+    # train
     journal = _spans.SpanJournal(_spans.journal_path(storage, instance_id))
     t_run = _dt.datetime.now(_dt.timezone.utc).timestamp()
     with journal.activate():

@@ -14,6 +14,7 @@ import pickle
 from typing import Any, List
 
 from predictionio_tpu.controller.dase import PersistentModel
+from predictionio_tpu.obs.spans import span
 from predictionio_tpu.storage.locator import Storage
 
 
@@ -46,7 +47,11 @@ def deserialize_models(blob: bytes) -> List[Any]:
 
 
 def save_models(storage: Storage, instance_id: str, models: List[Any]) -> None:
-    storage.models.insert(instance_id, serialize_models(models))
+    with span("serialize_models") as rec:
+        blob = serialize_models(models)
+        rec["attrs"] = {"bytes": len(blob)}
+    with span("models_insert", bytes=len(blob)):
+        storage.models.insert(instance_id, blob)
 
 
 def load_models(storage: Storage, instance_id: str) -> List[Any]:

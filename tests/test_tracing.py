@@ -13,6 +13,7 @@ import time
 import urllib.request
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from predictionio_tpu.obs import tracing as obs_tracing
@@ -522,3 +523,252 @@ def test_pio_trace_cli(event_server, capsys):
     assert cli_main(["trace", base, "--slow"]) == 0
     out = capsys.readouterr().out
     assert "trace " in out
+
+
+# -- spans inside run_train, on the journal and on the profiler's clock -------
+
+TRAIN_SPANS = {"train", "engine_train", "staging_summary", "save_models",
+               "read_training", "prepare", "algo_train", "layout", "h2d",
+               "dispatch", "device_wait", "serialize_models", "models_insert"}
+
+# how each CCO strategy a TPU or the rehearsal can reach is forced on the CPU
+# (the dense count matrix allowed or not; the resident budget taken away)
+UR_STRATEGIES = {
+    "dense": {"PIO_CCO_SPARSE": "0", "PIO_CCO_DENSE": "1"},
+    "resident": {"PIO_CCO_SPARSE": "0", "PIO_CCO_DENSE": "0"},
+    "chunked": {"PIO_CCO_SPARSE": "0", "PIO_CCO_DENSE": "0",
+                "_TILED_P_BYTES": 0},
+}
+
+
+def _interaction_app(storage, name, events_of_pair):
+    app_id = storage.apps.insert(App(0, name))
+    rng = np.random.default_rng(11)
+    events = []
+    for u in range(40):
+        for i in range(12):
+            if rng.random() < (0.7 if (i < 6) == (u < 20) else 0.1):
+                events.extend(events_of_pair(f"u{u}", f"i{i}", rng))
+    storage.l_events.insert_batch(events, app_id)
+    return storage
+
+
+def _ur_job(storage, monkeypatch, strategy="resident"):
+    from predictionio_tpu.events.event import Event
+    from predictionio_tpu.models.universal_recommender import (
+        UniversalRecommenderEngine)
+    from predictionio_tpu.ops import cco as cco_ops
+
+    for key, value in UR_STRATEGIES.get(strategy, {}).items():
+        if key.startswith("PIO_"):
+            monkeypatch.setenv(key, value)
+        else:
+            monkeypatch.setattr(cco_ops, key, value)
+
+    def pair(u, i, rng):
+        out = [Event(event="view", entity_type="user", entity_id=u,
+                     target_entity_type="item", target_entity_id=i)]
+        if rng.random() < 0.6:
+            out.append(Event(event="buy", entity_type="user", entity_id=u,
+                             target_entity_type="item", target_entity_id=i))
+        return out
+
+    _interaction_app(storage, "spanapp", pair)
+    engine = UniversalRecommenderEngine.apply()
+    ep = engine.engine_params_from_variant({
+        "datasource": {"params": {"appName": "spanapp",
+                                  "eventNames": ["buy", "view"]}},
+        "algorithms": [{"name": "ur", "params": {
+            "appName": "spanapp", "meshDp": 1, "maxCorrelatorsPerItem": 4,
+            "itemTile": 8}}]})
+    return engine, ep
+
+
+def _als_job(storage, monkeypatch, strategy=None):
+    from predictionio_tpu.controller.engine import EngineParams
+    from predictionio_tpu.events.event import DataMap, Event
+    from predictionio_tpu.models.recommendation import RecommendationEngine
+    from predictionio_tpu.models.recommendation.engine import (
+        ALSAlgorithmParams, DataSourceParams)
+
+    def pair(u, i, rng):
+        return [Event(event="rate", entity_type="user", entity_id=u,
+                      target_entity_type="item", target_entity_id=i,
+                      properties=DataMap({"rating": float(rng.integers(1, 6))}))]
+
+    _interaction_app(storage, "spanapp", pair)
+    ep = EngineParams(
+        data_source_params=DataSourceParams(app_name="spanapp"),
+        algorithm_params_list=[("als", ALSAlgorithmParams(
+            rank=4, num_iterations=3, lambda_=0.05, mesh_dp=1))])
+    return RecommendationEngine.apply(), ep
+
+
+EXPECTED_PROGRAM = {"dense": "_cco_counts_dense",
+                    "resident": "_cco_resident_all_tiles",
+                    "chunked": "_cco_chunked_all_tiles",
+                    None: "_als_run_single"}
+TRAIN_JOBS = [(_ur_job, s) for s in UR_STRATEGIES] + [(_als_job, None)]
+TRAIN_JOB_IDS = [f"ur-{s}" for s in UR_STRATEGIES] + ["als"]
+
+
+def _run_train(make, strategy, storage, monkeypatch):
+    from predictionio_tpu.obs import spans as obs_spans
+    from predictionio_tpu.workflow import core_workflow
+
+    engine, ep = make(storage, monkeypatch, strategy)
+    instance = core_workflow.run_train(engine, ep, engine_id="span-engine",
+                                       storage=storage)
+    spans = obs_spans.read_journal(
+        obs_spans.journal_path(storage, instance.id))
+    # the same run, kept in memory for whoever is in the process
+    assert obs_spans.recent_runs()[-1] == spans
+    return spans
+
+
+@pytest.mark.parametrize("make,strategy", TRAIN_JOBS, ids=TRAIN_JOB_IDS)
+def test_train_journal_holds_a_span_at_every_host_boundary(
+        make, strategy, fs_storage, monkeypatch):
+    spans = _run_train(make, strategy, fs_storage, monkeypatch)
+    by_id = {s["id"]: s for s in spans}
+    names = {s["name"] for s in spans}
+    assert TRAIN_SPANS <= names, TRAIN_SPANS - names
+    assert "host_compute" not in names      # no strategy of a TPU counts on the host
+
+    def parents(name):
+        return {by_id[s["parent"]]["name"] if s["parent"] else None
+                for s in spans if s["name"] == name}
+
+    assert parents("train") == {None}
+    for name in ("engine_train", "staging_summary", "save_models"):
+        assert parents(name) == {"train"}
+    for name in ("read_training", "prepare", "algo_train"):
+        assert parents(name) == {"engine_train"}
+    for name in ("layout", "h2d", "dispatch", "device_wait"):
+        assert parents(name) == {"algo_train"}
+    for name in ("serialize_models", "models_insert"):
+        assert parents(name) == {"save_models"}
+
+    # a span's self time is its duration less its children's; no child
+    # outlasts its parent, and the self times add up to the whole run
+    self_s = {s["id"]: s["duration_s"] for s in spans}
+    for s in spans:
+        if s["parent"] is not None:
+            self_s[s["parent"]] -= s["duration_s"]
+    assert min(self_s.values()) > -1e-3
+    root = next(s for s in spans if s["parent"] is None)
+    assert sum(self_s.values()) == pytest.approx(root["duration_s"])
+
+    attrs = lambda name: [s.get("attrs", {}) for s in spans   # noqa: E731
+                          if s["name"] == name]
+    assert all(a["bytes"] > 0 for a in attrs("h2d") + attrs("device_wait")
+               + attrs("serialize_models") + attrs("models_insert"))
+    programs = {a["program"] for a in attrs("dispatch")}
+    assert EXPECTED_PROGRAM[strategy] in programs, programs
+    assert attrs("algo_train")[0]["algorithm"] in ("URAlgorithm",
+                                                   "ALSAlgorithm")
+    assert attrs("read_training")[0]["events"] > 100
+
+
+def test_host_sparse_strategy_is_one_host_compute_span(fs_storage,
+                                                       monkeypatch):
+    """What `auto` takes on the CPU backend: the counting is the host's."""
+    monkeypatch.setenv("PIO_CCO_SPARSE", "1")
+    monkeypatch.delenv("PIO_CCO_DENSE", raising=False)
+    spans = _run_train(_ur_job, "host-sparse", fs_storage, monkeypatch)
+    by_id = {s["id"]: s for s in spans}
+    host = [s for s in spans if s["name"] == "host_compute"]
+    assert len(host) == 2                                  # buy, view
+    assert {by_id[s["parent"]]["name"] for s in host} == {"algo_train"}
+
+
+@pytest.mark.parametrize("make,strategy", [TRAIN_JOBS[1], TRAIN_JOBS[3]],
+                         ids=["ur", "als"])
+def test_spans_lie_on_the_profilers_clock(make, strategy, fs_storage,
+                                          monkeypatch, tmp_path):
+    """Under a profiler session every span is a `pio:<name>` event on the
+    thread's host line, nested inside `pio:train`."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 2
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"),
+                             profiler_options=options)
+    try:
+        spans = _run_train(make, strategy, fs_storage, monkeypatch)
+    finally:
+        jax.profiler.stop_trace()
+    [pb] = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                     recursive=True)
+    lines = [ln for plane in ProfileData.from_file(pb).planes
+             if not plane.name.startswith("/device:") for ln in plane.lines]
+    on_line = {}
+    for k, ln in enumerate(lines):
+        for e in ln.events:
+            if e.name.startswith("pio:"):
+                on_line.setdefault(k, []).append(
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns))
+    [events] = on_line.values()           # one thread ran the job: one line
+    [(_, lo, hi)] = [e for e in events if e[0] == "pio:train"]
+    # (the app's ingest before the job had no collector: annotation alone)
+    inside = {n for n, s, e in events if lo <= s and e <= hi}
+    assert inside == {"pio:" + s["name"] for s in spans
+                      if s["name"] != "compile"}
+    assert {"pio:" + n for n in TRAIN_SPANS} <= inside
+    assert {n for n, _, _ in events} - inside <= {"pio:group_commit_append"}
+
+
+def test_spans_without_jax_import_no_jax():
+    """The event server's process: spans open, JAX stays out."""
+    code = """
+import sys
+from predictionio_tpu.obs import spans
+import predictionio_tpu.obs
+j = spans.SpanJournal(sys.argv[1])
+with spans.span("no_collector") as rec:
+    rec["attrs"]["seen"] = 1
+with j.activate():
+    with spans.span("outer", k=1):
+        with spans.span("inner"):
+            pass
+assert [s["name"] for s in spans.recent_runs()[-1]] == ["outer", "inner"]
+assert "jax" not in sys.modules, "obs.spans imported jax"
+print("ok")
+"""
+    import tempfile
+
+    with tempfile.TemporaryDirectory() as d:
+        r = subprocess.run([sys.executable, "-c", code, f"{d}/j.jsonl"],
+                           capture_output=True, text=True, timeout=120,
+                           cwd=str(REPO))
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
+
+
+def test_a_compile_inside_a_span_is_its_compile_child(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.obs import spans as obs_spans
+    from predictionio_tpu.utils import device as device_
+
+    device_.watch_compiles()
+    before = device_.compile_stats()["programs"]
+    journal = obs_spans.SpanJournal(tmp_path / "c.jsonl")
+    with journal.activate():
+        with obs_spans.span("dispatch", program="fresh") as rec:
+            # a function object of its own: no jit cache can hold it
+            jax.jit(lambda x: jnp.tanh(x) * 3.25 + 1)(jnp.arange(7.0))
+    compiles = [s for s in journal.spans() if s["name"] == "compile"]
+    assert len(compiles) == device_.compile_stats()["programs"] - before >= 1
+    for c in compiles:
+        assert c["parent"] == rec["id"]
+        assert c["attrs"]["seconds"] > 0
+        assert c["attrs"]["cache_hit"] in (True, False)
+        assert rec["start"] <= c["start"] + 1e-3
+    # outside any collector a compile is only counted
+    jax.jit(lambda x: jnp.tanh(x) * 4.5 - 2)(jnp.arange(5.0))
+    assert len(journal.spans()) == 1 + len(compiles)
