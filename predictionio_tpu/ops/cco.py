@@ -203,15 +203,19 @@ def _llr_mask_scores(c, row_counts, col_counts, n_total, llr_threshold,
 
 
 def topk_impl() -> str:
-    """'lax' | 'pallas' for the tiled running top-k merge.
+    """'pallas' | 'lax' for the tiled running top-k merge's per-tile
+    selection, derived from ``pallas_mode()``: the in-VMEM tournament
+    (pallas_kernels.tile_topk_desc) wherever Pallas kernels run — a TPU
+    backend, or ``PIO_PALLAS=interpret`` — and ``lax.top_k`` otherwise
+    (a Mosaic kernel compiles only for a TPU).
 
-    ``PIO_CCO_TOPK`` overrides; auto selects **lax**.  The Pallas bitonic
-    kernel (pallas_kernels.tile_topk_desc) compiles on a TPU v5e and
-    matches lax.top_k there (chip_smoke.py's kernel phase); which of the
-    two is faster in the merge has not been measured on the chip —
-    ROADMAP S3 decides the default."""
-    conf = _os.environ.get("PIO_CCO_TOPK", "auto").lower()
-    return "pallas" if conf in ("pallas", "bitonic") else "lax"
+    Why: in ``ur-ecom-100k.train`` the lax merge's tile-wide sort and the
+    index gather after it took 16.31 + 4.46 s of a 32.0 s job (ledger,
+    PR 24); the tournament takes 7.64 s of an 18.8 s job, the sort and
+    the gather gone (chip run, PR 25: PERF.md section 6)."""
+    from predictionio_tpu.ops.pallas_kernels import pallas_mode
+
+    return "lax" if pallas_mode() == "off" else "pallas"
 
 
 def _carry_width(top_k: int, impl: str) -> int:
@@ -229,11 +233,14 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
     """Shared running top-k merge for the tiled strategies; masks self-pairs
     BEFORE the merge so every row still gets a full top_k correlators.
 
-    impl='lax': top_k over concat(carry, tile) — XLA's full variadic row
-    sort, measured 78% of tiled steady-state device time (PERF.md r3).
+    impl='lax': top_k over concat(carry, tile), then a gather of the
+    kept indices — on a TPU XLA's full variadic row sort, 326 + 89 ms a
+    [100000, 4096] tile (ledger, PR 24); the off-TPU path and the parity
+    reference.
     impl='pallas': one in-VMEM bitonic pass selects the tile's top block
     (pallas_kernels.tile_topk_desc), then a log2(b)-stage sorted merge
-    with the carry on [I, 2b] — the tile-wide sort never happens.  The
+    with the carry on [I, 2b] — the tile-wide sort never happens: 153 ms
+    a tile for the kernel, ~2 ms for the merge (chip run, PR 25).  The
     carry is then [I, block_width(top_k)], sorted desc; _finalize_topk
     slices back to top_k.
     """
@@ -478,13 +485,14 @@ def _cco_indicators_resident(
 
     from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
-    with span("dispatch", program="_cco_resident_all_tiles"):
+    topk = topk_impl()
+    with span("dispatch", program="_cco_resident_all_tiles", topk=topk):
         best_scores, best_idx = _cco_resident_all_tiles(
             P, rc, a_gu, a_gi, a_valid, float(n_total_users),
             n_tiles=n_tiles, tile=tile, top_k=top_k,
             llr_threshold=float(llr_threshold),
             exclude_self=exclude_self, pallas=pallas_mode(), mm=mm,
-            topk=topk_impl(),
+            topk=topk,
         )
     return _finalize_topk(best_scores, best_idx, n_items_t, top_k)
 
@@ -679,6 +687,11 @@ def _llr_topk_dense(
     C, rc, cc, n_total, llr_threshold,
     top_k: int, exclude_self: bool, pallas: str, topk: str = "lax",
 ):
+    """LLR + whole-row top-k over the full count matrix.  Every caller
+    leaves ``topk`` at ``lax`` on every backend: a row here is the whole
+    target catalogue, which ``tile_topk_desc`` would pad to a power of two
+    and unroll over, and nothing has compiled or timed that for a TPU at
+    a dense-path width; ``topk='pallas'`` is the parity test's."""
     scores = _llr_mask_scores(
         C.astype(jnp.float32), rc.astype(jnp.float32), cc.astype(jnp.float32),
         n_total, llr_threshold, pallas)
@@ -1181,7 +1194,7 @@ class _SparseHostRunner:
                 float(self.n_total_users), float(llr_threshold),
                 top_k=min(top_k, C.shape[1]),
                 exclude_self=bool(exclude_self),
-                pallas=pallas_mode(), topk=topk_impl(),
+                pallas=pallas_mode(),
             )
         return s, i, n_items_t, top_k
 
@@ -1264,7 +1277,7 @@ class _DenseRunner:
             s, i = _llr_topk_dense(
                 C, rc, cc, float(self.n_total_users), float(llr_threshold),
                 top_k=k, exclude_self=bool(exclude_self),
-                pallas=pallas_mode(), topk=topk_impl(),
+                pallas=pallas_mode(),
             )
         return s, i, n_items_t, top_k
 
@@ -1496,7 +1509,7 @@ def cco_indicators(
     if mesh is None:
         with span("h2d", bytes=sum(a.nbytes for a in host_args)):
             args = tuple(jnp.asarray(a) for a in host_args)
-        with span("dispatch", program="_cco_chunked_all_tiles"):
+        with span("dispatch", program="_cco_chunked_all_tiles", topk=topk):
             best_scores, best_idx = _cco_chunked_all_tiles(
                 *args, float(n_total_users),
                 n_tiles=n_tiles, block=primary.user_block,

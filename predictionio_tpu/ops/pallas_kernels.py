@@ -16,7 +16,8 @@ beyond what XLA does automatically:
   fused into one VPU pass over each count tile.
 
 - ``tile_topk_desc`` — exact per-row top-k of a score tile as an in-VMEM
-  bitonic tournament (the tiled-CCO merge; selected by ``PIO_CCO_TOPK``).
+  bitonic tournament (the tiled-CCO merge's per-tile selection wherever
+  these kernels run: ``ops.cco.topk_impl``).
 
 Control: ``PIO_PALLAS`` env var — ``auto`` (default: compiled on TPU, off
 otherwise), ``1``/``compiled``, ``interpret``, ``0``/``off``.  A kernel is
@@ -368,8 +369,9 @@ def tile_topk_desc(
     """Exact top-``b`` of each row, sorted descending, as ONE Pallas pass.
 
     Replaces ``lax.top_k`` in the tiled-CCO running merge, where XLA's
-    full variadic row sort measured 78% of steady-state device time
-    (PERF.md round 3: 13.3 s of 17 s at the 400k-event/25-tile ablation).
+    full variadic row sort and the gather after it took 326 + 89 ms a
+    [100000, 4096] tile (ledger, PR 24); this kernel takes 153 ms a tile
+    in the same job (chip run, PR 25: PERF.md section 6).
     ``b`` must be a power of two (see ``ops.topk.block_width``); rows pad
     to the block, width pads to the next power of two with -inf (padded
     columns surface with -inf scores, which every caller already filters).

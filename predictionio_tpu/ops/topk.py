@@ -2,11 +2,12 @@
 
 Why this exists: XLA lowers ``lax.top_k`` on TPU to a full variadic sort
 of each row.  In the tiled CCO path that sort — top_k(concat(best, tile))
-over a [I_p, top_k + 4096] buffer per tile — measured 78% of steady-state
-device time at the 400k-event/25-tile ablation (PERF.md round 3), and the
-two obvious escapes both failed: ``approx_max_k`` inside ``lax.scan``
-exploded compile time (>40 min at [100k, 4096]), and a lane-level Mosaic
-sort kernel is high-risk with no hardware to measure on.
+over a [I_p, top_k + 4096] buffer per tile — and the index gather after
+it took 16.31 + 4.46 s of a 32.0 s ``ur-ecom-100k.train`` job (ledger,
+PR 24: 65% of the job); the tournament as a Pallas kernel plus
+``merge_desc`` takes 7.64 + 0.1 s of the same job, now 18.8 s (chip run,
+PR 25: PERF.md section 6).  ``approx_max_k`` inside ``lax.scan`` was no
+escape: it exploded compile time (>40 min at [100k, 4096]).
 
 The tournament does strictly less work than a full sort and lowers to
 nothing but elementwise min/max/select chains plus static reshapes, which

@@ -1040,7 +1040,6 @@ class URFoldState:
             _DenseRunner,
             _llr_topk_dense,
             _llr_topk_sparse_rows,
-            topk_impl,
         )
         from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
@@ -1053,7 +1052,7 @@ class URFoldState:
         n_t = st.n_items
         n_p = p_st.n_items
         n_total = float(len(self.user_dict))
-        default_kernels = topk_impl() == "lax" and pallas_mode() == "off"
+        default_kernels = pallas_mode() == "off"
         small_dense = (default_kernels
                        and n_p * n_t * 4 <= _dense_rellr_bytes())
         if st.sc is not None and default_kernels and not small_dense:
@@ -1086,27 +1085,26 @@ class URFoldState:
             # dense kernels over a transient materialization: the tiny-
             # catalog fast path (sub-ms regime, where the dense jit beats
             # the sparse gather+lexsort ~2× — and exactly the code path
-            # the dense state and PR 8 always took), or a non-default
-            # kernel selection (pallas top-k / pallas LLR) whose only
-            # entry points are dense — there, unaffordable means the
-            # follower must retrain
+            # the dense state and PR 8 always took), or the Pallas LLR
+            # kernel, whose only entry points are dense — there,
+            # unaffordable means the follower must retrain
             if not small_dense and n_p * n_t * 4 > state_budget_bytes():
                 raise FoldUnsupported(
-                    f"non-default kernels ({topk_impl()}/{pallas_mode()}) "
+                    f"non-default kernels (PIO_PALLAS {pallas_mode()}) "
                     f"need a dense [{n_p}, {n_t}] count pass that exceeds "
                     "PIO_FOLLOW_STATE_BYTES")
             C_full = st.sc.to_dense(n_p, n_t)
         else:
             C_full = st.C
-        # non-default kernel selections (pallas top-k / pallas LLR) only
-        # have full-matrix entry points — take the full path so the fold
-        # reproduces exactly what training would have computed
+        # the Pallas LLR kernel has only full-matrix entry points — take
+        # the full path so the fold reproduces exactly what training
+        # would have computed
         if rows is None or not default_kernels:
             s, i = _llr_topk_dense(
                 jnp.asarray(C_full), jnp.asarray(self.row_counts),
                 jnp.asarray(st.col_counts), n_total, float(t_llr),
                 top_k=min(t_k, n_t), exclude_self=bool(excl),
-                pallas=pallas_mode(), topk=topk_impl())
+                pallas=pallas_mode())
             scores, idx = _DenseRunner.collect((s, i, n_t, t_k))
             st.idx = idx.astype(np.int32)
             st.llr = np.where(np.isfinite(scores), scores,

@@ -174,9 +174,8 @@ def main():
     print(f"=> {total / wall:,.0f} events/s  "
           f"(vs_baseline {total / wall / 200_000:.2f}, target >= 20)")
 
-    # 7. lax vs pallas tiled merge (ROADMAP S3: decides topk_impl()'s
-    # auto) at the tiled path's production shape: [100k rows, 4096-wide
-    # tiles].
+    # 7. lax vs pallas tiled merge, each alone, at the tiled path's
+    # production shape: [100k rows, 4096-wide tiles].
     from predictionio_tpu.ops.pallas_kernels import tile_topk_desc
     from predictionio_tpu.ops.topk import block_width, merge_desc
 
@@ -211,8 +210,7 @@ def main():
     print(f"  pallas merge compile+first-run: {time.perf_counter()-t0:.1f}s")
     tp = t(f"tile merge PALLAS   [{rows}, {tile_w}]", lambda: sync(
         merge_pallas(bs_p, bi_p, tile_scores)))
-    print(f"=> merge speedup {tl / tp:.2f}x  "
-          f"({'FLIP topk_impl auto to pallas-on-tpu' if tp < tl else 'keep lax'})")
+    print(f"=> merge speedup {tl / tp:.2f}x")
 
     # 8. MFU / roofline for the headline kernel: achieved TFLOP/s of the
     # count-matmul stage, % of the chip's peak, and the top non-matmul

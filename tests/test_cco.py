@@ -144,7 +144,6 @@ def test_tiled_mesh_matches_single(monkeypatch, kernels):
     monkeypatch.setenv("PIO_CCO_DENSE", "0")
     if kernels == "pallas":
         monkeypatch.setenv("PIO_PALLAS", "interpret")
-        monkeypatch.setenv("PIO_CCO_TOPK", "pallas")
     n_users, n_ip, n_it = 64, 12, 10
     pu, pi = random_interactions(n_users, n_ip, 300, 6)
     ou, oi = random_interactions(n_users, n_it, 300, 7)

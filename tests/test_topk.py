@@ -1,6 +1,7 @@
 """Bitonic tournament top-k (ops/topk.py) and its Pallas tile kernel
-(pallas_kernels.tile_topk_desc) vs lax.top_k, plus the tiled-CCO merge
-parity under PIO_CCO_TOPK=pallas."""
+(pallas_kernels.tile_topk_desc) vs lax.top_k, plus the tiled-CCO merge:
+its per-tile selection follows PIO_PALLAS, and kernels and XLA twins keep
+the same indicators."""
 
 import numpy as np
 import pytest
@@ -86,14 +87,21 @@ def test_pallas_kernels_never_interpret_silently(monkeypatch):
             llr_masked_scores(x, jnp.ones(8), jnp.ones(128), 10.0)
 
 
+def _dispatch_attrs(collector):
+    return {s["attrs"]["program"]: s["attrs"] for s in collector.spans()
+            if s["name"] == "dispatch"}
+
+
 @pytest.mark.parametrize("strategy", ["resident", "chunked", "dense"])
 def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
-    """dense ≡ tiled parity contract extended to the merge impl: the CCO
-    indicator tables are identical under PIO_CCO_TOPK=lax and =pallas on
-    every device strategy (kernels in interpret mode on CPU)."""
+    """dense ≡ tiled parity contract extended to the kernels: the CCO
+    indicator tables are identical under PIO_PALLAS=off (XLA twins, the
+    lax merge) and =interpret (Pallas LLR + tournament merge) on every
+    device strategy, and the tiled programs' dispatch span says which
+    merge ran."""
+    from predictionio_tpu.obs.spans import SpanCollector
     from predictionio_tpu.ops import cco as cco_ops
 
-    monkeypatch.setenv("PIO_PALLAS", "interpret")
     rng = np.random.default_rng(3)
     n_users, n_ip, n_it = 80, 30, 47
     pu = rng.integers(0, n_users, 500)
@@ -108,15 +116,16 @@ def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
         if strategy == "chunked":
             monkeypatch.setattr(cco_ops, "_TILED_P_BYTES", 0)
 
-    def run():
-        return cco_ops.cco_indicators_coo(
-            pu, pi, ou, oi, n_users, n_ip, n_it,
-            top_k=7, llr_threshold=0.5, user_block=32, item_tile=16)
+    def run(pallas):
+        monkeypatch.setenv("PIO_PALLAS", pallas)
+        with SpanCollector().activate() as spans:
+            out = cco_ops.cco_indicators_coo(
+                pu, pi, ou, oi, n_users, n_ip, n_it,
+                top_k=7, llr_threshold=0.5, user_block=32, item_tile=16)
+        return (*out, _dispatch_attrs(spans))
 
-    monkeypatch.setenv("PIO_CCO_TOPK", "lax")
-    s1, i1 = run()
-    monkeypatch.setenv("PIO_CCO_TOPK", "pallas")
-    s2, i2 = run()
+    s1, i1, d1 = run("off")
+    s2, i2, d2 = run("interpret")
 
     finite = np.isfinite(s1)
     assert (np.isfinite(s2) == finite).all()
@@ -124,17 +133,115 @@ def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
     # ids equal wherever scores have no exact ties at the cut
     np.testing.assert_allclose(
         np.sort(s1, axis=1), np.sort(s2, axis=1), rtol=1e-5, atol=1e-5)
+    if strategy == "dense":
+        # the whole-row top-k stays lax.top_k on every backend; the
+        # tournament over a whole row is reachable from here alone
+        assert "topk" not in d2["_llr_topk_dense"]
+        C = jnp.asarray(rng.integers(0, 6, (n_ip, n_it)).astype(np.int32))
+        rc = jnp.full((n_ip,), 9, jnp.int32)
+        cc = jnp.full((n_it,), 9, jnp.int32)
+        got = {impl: cco_ops._llr_topk_dense(
+            C, rc, cc, float(n_users), 0.5, top_k=7, exclude_self=False,
+            pallas="off", topk=impl) for impl in ("lax", "pallas")}
+        np.testing.assert_array_equal(got["lax"][0], got["pallas"][0])
+    else:
+        program = f"_cco_{strategy}_all_tiles"
+        assert d1[program]["topk"] == "lax"
+        assert d2[program]["topk"] == "pallas"
 
 
-def test_topk_impl_env(monkeypatch):
+def test_topk_impl_follows_pallas_mode(monkeypatch):
+    """No knob of its own: the merge's selection is the Pallas tournament
+    exactly where Pallas kernels run."""
     from predictionio_tpu.ops.cco import _carry_width, topk_impl
 
-    monkeypatch.setenv("PIO_CCO_TOPK", "pallas")
-    assert topk_impl() == "pallas"
-    monkeypatch.setenv("PIO_CCO_TOPK", "lax")
+    monkeypatch.setenv("PIO_PALLAS", "off")
     assert topk_impl() == "lax"
-    monkeypatch.delenv("PIO_CCO_TOPK", raising=False)
-    assert topk_impl() == "lax"    # auto stays lax until S3 measures both
+    monkeypatch.setenv("PIO_PALLAS", "interpret")
+    assert topk_impl() == "pallas"
+    monkeypatch.delenv("PIO_PALLAS", raising=False)
+    assert topk_impl() == "lax"    # auto on a CPU backend: no kernels
     assert _carry_width(50, "pallas") == 64
     assert _carry_width(50, "lax") == 50
     assert _carry_width(3, "pallas") == 8
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive in ``jaxpr`` and the jaxprs nested in its
+    equations' parameters (pjit, scan, ...)."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found |= _primitives(sub)
+    return found
+
+
+@pytest.mark.parametrize("topk", ["pallas", "lax"])
+def test_resident_program_selects_with_one_impl_only(monkeypatch, topk):
+    """The traced tile program holds the Pallas selection and no top_k
+    sort, or the reverse: an edit cannot put the tile-wide sort back under
+    the tournament (or lose the kernel) unnoticed.  The LLR runs as its
+    XLA twin here, so the selection's is the only pallas_call."""
+    from functools import partial
+
+    from predictionio_tpu.ops import cco as cco_ops
+
+    monkeypatch.setenv("PIO_PALLAS", "interpret")
+    n_rows, n_ip, e = 128, 24, 60
+    traced = jax.make_jaxpr(partial(
+        cco_ops._cco_resident_all_tiles, n_tiles=3, tile=16, top_k=5,
+        llr_threshold=0.0, exclude_self=True, pallas="off", mm="bf16",
+        topk=topk))(
+        jnp.zeros((n_rows, n_ip), jnp.bfloat16), jnp.zeros((n_ip,), jnp.int32),
+        jnp.zeros((e,), jnp.int32), jnp.zeros((e,), jnp.int32),
+        jnp.ones((e,), bool), 100.0)
+    found = _primitives(traced.jaxpr)
+    assert "scan" in found          # the walk reached the tile loop's body
+    assert ("pallas_call" in found) == (topk == "pallas")
+    assert ("top_k" in found) == (topk == "lax")
+
+
+def test_merge_ties_at_the_cut_over_a_partial_last_tile(monkeypatch):
+    """Target items in groups of identical columns, a group's members
+    spread over all three tiles, so a row's cut at top_k 50 falls inside
+    a run of exactly equal scores (7 or 8 to a group, so in most rows);
+    the last tile is partial (150 = 2 x 64 + 22).  The tournament merge (carry 64) keeps, per row, the
+    same multiset of scores as lax.top_k, only items that exist, and
+    -1 / -inf elsewhere."""
+    from predictionio_tpu.ops import cco as cco_ops
+    from predictionio_tpu.ops.cco import block_interactions, cco_indicators
+
+    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    rng = np.random.default_rng(5)
+    n_users, n_ip, n_it, groups, top_k = 96, 20, 150, 21, 50
+    pu, pi = np.nonzero(rng.random((n_users, n_ip)) < 0.3)
+    pattern = rng.random((n_users, groups)) < 0.35
+    ou, oi = np.nonzero(pattern[:, np.arange(n_it) % groups])
+    p = block_interactions(pu, pi, n_users, n_ip, user_block=32)
+    o = block_interactions(ou, oi, n_users, n_it, user_block=32)
+    assert cco_ops._resident_p_ok(n_users, n_ip, 64)
+
+    def run(pallas):
+        monkeypatch.setenv("PIO_PALLAS", pallas)
+        return cco_indicators(p, o, None, None, n_users, top_k=top_k,
+                              item_tile=64)
+
+    s_lax, _ = run("off")
+    s_pal, i_pal = run("interpret")
+    assert s_pal.shape == i_pal.shape == (n_ip, top_k)
+    kept = np.isfinite(s_pal)
+    # ties at the cut are really there, in rows that fill all 50 places
+    full = kept.all(axis=1)
+    assert full.sum() >= n_ip // 2
+    tied_at_cut = (s_lax[full] == s_lax[full, -1:]).sum(axis=1) >= 2
+    assert tied_at_cut.sum() >= n_ip // 2
+    np.testing.assert_allclose(np.sort(s_pal, axis=1), np.sort(s_lax, axis=1),
+                               rtol=1e-5, atol=1e-5)
+    assert (i_pal[kept] >= 0).all() and (i_pal[kept] < n_it).all()
+    assert (i_pal[~kept] == -1).all() and (s_pal[~kept] == -np.inf).all()
+    for r in range(n_ip):           # no item kept twice
+        assert len(set(i_pal[r][kept[r]].tolist())) == kept[r].sum()
