@@ -4429,7 +4429,7 @@ def bench_scale(smoke: bool) -> dict:
         stage_s = time.perf_counter() - t0
         t1 = time.perf_counter()
         scores, idx = cco_ops.cco_indicators(
-            blocked, blocked, None, None, n_users, top_k=50,
+            blocked, blocked, n_users, top_k=50,
             item_tile=tile, exclude_self=True)
         wall = time.perf_counter() - t1
     finally:

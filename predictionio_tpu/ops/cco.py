@@ -59,15 +59,16 @@ from predictionio_tpu.obs.spans import span
 class BlockedInteractions:
     """COO pairs grouped into fixed-size user blocks, padded to equal length.
 
-    local_u[b, e] is the in-block user row (or 0 with mask 0), item[b, e] the
-    item id.  Block b covers global users [b*block, (b+1)*block).  Pairs need
-    NOT be unique: every device consumer densifies by scatter-max, which
-    collapses duplicates.
+    local_u[b, e] is the in-block user row, item[b, e] the item id, for the
+    first count[b] slots of block b; the slots after them are padding (0).
+    Block b covers global users [b*block, (b+1)*block).  Pairs need NOT be
+    unique: every device consumer densifies by scatter-max, which collapses
+    duplicates.
     """
 
     local_u: np.ndarray   # int32 [n_blocks, E]
     item: np.ndarray      # int32 [n_blocks, E]
-    mask: np.ndarray      # f32   [n_blocks, E]
+    count: np.ndarray     # int32 [n_blocks]: the valid slots of each block
     n_users: int
     n_items: int
     user_block: int
@@ -75,6 +76,11 @@ class BlockedInteractions:
     @property
     def n_blocks(self) -> int:
         return self.local_u.shape[0]
+
+    @property
+    def mask(self) -> np.ndarray:
+        """bool [n_blocks, E]: which slots hold a pair."""
+        return np.arange(self.local_u.shape[1]) < self.count[:, None]
 
 
 def block_interactions(
@@ -100,8 +106,8 @@ def block_interactions(
         native = layout_chunks(user, item, user_block, n_blocks, pad_multiple)
         if native is not None:
             lu, it, cnt = native
-            mask = (np.arange(lu.shape[1]) < cnt[:, None]).astype(np.float32)
-            return BlockedInteractions(lu, it, mask, n_users, n_items, user_block)
+            return BlockedInteractions(lu, it, cnt, n_users, n_items,
+                                       user_block)
     return block_interactions_stream(
         [(user, item)], n_users, n_items,
         user_block=user_block, pad_multiple=pad_multiple,
@@ -144,15 +150,14 @@ def block_interactions_stream(
     width = ((width + pad_multiple - 1) // pad_multiple) * pad_multiple
     lu = np.zeros((n_blocks, width), np.int32)
     it = np.zeros((n_blocks, width), np.int32)
-    mk = np.zeros((n_blocks, width), np.float32)
     for b in range(n_blocks):
         c = sizes[b]
         if c:
             lu[b, :c] = np.concatenate(per_block_u[b])
             it[b, :c] = np.concatenate(per_block_i[b])
-            mk[b, :c] = 1.0
         per_block_u[b] = per_block_i[b] = []  # free as we go
-    return BlockedInteractions(lu, it, mk, n_users, n_items, user_block)
+    return BlockedInteractions(lu, it, np.asarray(sizes, np.int32),
+                               n_users, n_items, user_block)
 
 
 def interaction_counts(item: np.ndarray, n_items: int) -> np.ndarray:
@@ -362,11 +367,12 @@ def _mm_in_dtype():
 # P-resident tiled path (huge catalogs, but the densified primary fits HBM)
 # ---------------------------------------------------------------------------
 
-# Working-set budget for the P-resident strategy (P + per-tile A slab +
-# f32 count tile).  8 GB of a 16 GB v5e leaves headroom for XLA transients;
-# e.g. the 100k-item serving bench (20k users) needs ~6 GB and saves 25
-# re-densifies of a 4 GB primary vs the chunked path.
-_TILED_P_BYTES = 8 << 30
+# Budget for the P-resident program's plan (see _resident_p_ok for what the
+# plan counts): three quarters of a 16 GB v5e.  The quarter left over is for
+# what the plan does not count: the COO arrays and the top-k carry of the
+# event type in flight, the results of the one before it, and the
+# allocator's own slack.
+_TILED_P_BYTES = 12 * 10**9
 
 
 @partial(jax.jit, static_argnames=("n_rows", "n_cols"))
@@ -445,16 +451,23 @@ def _cco_resident_all_tiles(
 
 
 def _resident_p_ok(n_users: int, n_items_p: int, item_tile: int = 4096) -> bool:
-    """The P-resident strategy is used only when its WHOLE working set
-    fits the budget (resident P + per-tile densified A + the f32 count
-    tile), AND counts stay exact: bf16 contracts the full user space in
-    one f32 pass, so n_users must stay below 2²⁴ (int8 accumulates int32
-    and has no such cap)."""
+    """The P-resident strategy is used only when the program's plan fits
+    the budget, AND counts stay exact: bf16 contracts the full user space
+    in one f32 pass, so n_users must stay below 2²⁴ (int8 accumulates int32
+    and has no such cap).
+
+    The plan is what the compiler holds for ``_cco_resident_all_tiles``:
+    the densified primary as an argument, and per tile the densified slab
+    of the other type, the float32 count tile and the float32 scores made
+    from it.  At 32,768 × 100,000, tile 4,096, bf16 that is 6.55 + 0.27 +
+    2 × 1.64 = 10.10 GB; the TPU compiler plans 6.11 GiB of arguments +
+    3.20 GiB of temporaries = 10.0 GB there [AOT, PR 25] and the chip's
+    peak read 10.08 GB (chip run, PR 25)."""
     bytes_per = 2 if _matmul_dtype() == "bf16" else 1
     n_rows = max(((n_users + 127) // 128) * 128, 128)
-    working = (n_rows * n_items_p + n_rows * item_tile) * bytes_per \
-        + n_items_p * item_tile * 4
-    if working > _TILED_P_BYTES:
+    plan = (n_rows * n_items_p + n_rows * item_tile) * bytes_per \
+        + 2 * n_items_p * item_tile * 4
+    if plan > _TILED_P_BYTES:
         return False
     return _matmul_dtype() == "int8" or n_users < (1 << 24)
 
@@ -503,8 +516,8 @@ def _cco_indicators_resident(
 
 
 def _cooccurrence_tile(
-    p_lu, p_it, p_mk,        # primary blocks [n_blocks, E_p]
-    a_lu, a_it, a_mk,        # other blocks   [n_blocks, E_a]
+    p_lu, p_it, p_cnt,       # primary blocks [n_blocks, E_p], [n_blocks]
+    a_lu, a_it, a_cnt,       # other blocks   [n_blocks, E_a], [n_blocks]
     block: int,
     n_items_p: int,
     tile_start,
@@ -514,21 +527,28 @@ def _cooccurrence_tile(
     """One item tile's counts AND the LLR marginals, on device:
     C_tile [I_p, tile] = Σ_b P_bᵀ A_b[:, tile];  rc = Σ_b colsum(P_b);
     cc_tile = Σ_b colsum(A_b[:, tile]).  Marginals come from the densified
-    (hence dedup'd) matrices — no host unique pass feeds this path."""
+    (hence dedup'd) matrices — no host unique pass feeds this path.
+    ``p_cnt``/``a_cnt`` give each block's valid slots; validity is an iota
+    comparison on device, as in ``_cco_counts_dense``."""
     in_dtype = _mm_in_dtype()
     mm = _matmul_dtype()
+    e_p, e_a = p_lu.shape[1], a_lu.shape[1]
 
     def body(carry, xs):
         C, rc, cct = carry
-        plu, pit, pmk, alu, ait, amk = xs
-        pb = _densify(plu, pit, pmk, block, n_items_p, in_dtype)
-        a_local = ait - tile_start
-        in_tile = (a_local >= 0) & (a_local < tile)
-        ab = _densify(alu, jnp.where(in_tile, a_local, 0),
-                      amk * in_tile, block, tile, in_dtype)
-        C = C + _count_matmul(pb, ab, mm)
-        rc = rc + _col_count(pb)
-        cct = cct + _col_count(ab)
+        plu, pit, pcnt, alu, ait, acnt = xs
+        with jax.named_scope("cco.densify_block"):
+            pvalid = jax.lax.iota(jnp.int32, e_p) < pcnt
+            pb = _densify(plu, pit, pvalid, block, n_items_p, in_dtype)
+            a_local = ait - tile_start
+            in_tile = ((jax.lax.iota(jnp.int32, e_a) < acnt)
+                       & (a_local >= 0) & (a_local < tile))
+            ab = _densify(alu, jnp.where(in_tile, a_local, 0), in_tile,
+                          block, tile, in_dtype)
+        with jax.named_scope("cco.count_matmul"):
+            C = C + _count_matmul(pb, ab, mm)
+            rc = rc + _col_count(pb)
+            cct = cct + _col_count(ab)
         return (C, rc, cct), None
 
     init = (
@@ -540,7 +560,8 @@ def _cooccurrence_tile(
         # under shard_map the carry varies per dp shard
         init = jax.tree.map(
             lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), init)
-    out, _ = jax.lax.scan(body, init, (p_lu, p_it, p_mk, a_lu, a_it, a_mk))
+    out, _ = jax.lax.scan(body, init,
+                          (p_lu, p_it, p_cnt, a_lu, a_it, a_cnt))
     return out
 
 
@@ -552,7 +573,7 @@ def _cooccurrence_tile(
     ),
 )
 def _cco_tile_step(
-    p_lu, p_it, p_mk, a_lu, a_it, a_mk,
+    p_lu, p_it, p_cnt, a_lu, a_it, a_cnt,
     n_total,
     best_scores, best_idx,
     tile_start,
@@ -565,14 +586,15 @@ def _cco_tile_step(
 ):
     """Process one item tile: cooccurrence counts → LLR → merge into top-k."""
     c, rc, cct = _cooccurrence_tile(
-        p_lu, p_it, p_mk, a_lu, a_it, a_mk, block, n_items_p, tile_start, tile,
-        axis_name,
+        p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, block, n_items_p, tile_start,
+        tile, axis_name,
     )
     if axis_name is not None:
         c, rc, cct = jax.lax.psum((c, rc, cct), axis_name)
-    scores = _llr_mask_scores(
-        c.astype(jnp.float32), rc.astype(jnp.float32), cct.astype(jnp.float32),
-        n_total, llr_threshold, pallas)
+    with jax.named_scope("cco.llr"):
+        scores = _llr_mask_scores(
+            c.astype(jnp.float32), rc.astype(jnp.float32),
+            cct.astype(jnp.float32), n_total, llr_threshold, pallas)
     return _merge_topk(best_scores, best_idx, scores, tile_start, tile,
                        top_k, n_items_p, exclude_self, impl=topk)
 
@@ -585,7 +607,7 @@ def _cco_tile_step(
     ),
 )
 def _cco_chunked_all_tiles(
-    p_lu, p_it, p_mk, a_lu, a_it, a_mk, n_total,
+    p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, n_total,
     n_tiles: int, block: int, n_items_p: int, tile: int, top_k: int,
     llr_threshold, pallas: str, exclude_self: bool, topk: str = "lax",
 ):
@@ -593,7 +615,7 @@ def _cco_chunked_all_tiles(
 
     def step(bs, bi, tile_start):
         return _cco_tile_step(
-            p_lu, p_it, p_mk, a_lu, a_it, a_mk, n_total, bs, bi, tile_start,
+            p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, n_total, bs, bi, tile_start,
             block=block, n_items_p=n_items_p, tile=tile, top_k=top_k,
             llr_threshold=llr_threshold, pallas=pallas,
             exclude_self=exclude_self, topk=topk)
@@ -615,7 +637,7 @@ _DENSE_C_BYTES = 2 << 30       # full count-matrix budget (4-byte accum)
 def _flatten_blocked(b: BlockedInteractions) -> Tuple[np.ndarray, np.ndarray]:
     """Blocked layout → global COO (inverse of block_interactions)."""
     gu = (np.arange(b.n_blocks, dtype=np.int64)[:, None] * b.user_block + b.local_u)
-    keep = b.mask.ravel() > 0
+    keep = b.mask.ravel()
     return gu.ravel()[keep].astype(np.int32), b.item.ravel()[keep].astype(np.int32)
 
 
@@ -744,7 +766,7 @@ def _stage_chunked(
             b = block_interactions_stream(
                 [(user, item)], n_chunks * chunk, 0, user_block=chunk)
             lu, it = b.local_u[:n_chunks], b.item[:n_chunks]
-            counts = b.mask[:n_chunks].sum(axis=1).astype(np.int32)
+            counts = b.count[:n_chunks]
     if sharding is not None:
         from predictionio_tpu.parallel.sharding import stage_global
 
@@ -1422,13 +1444,17 @@ def cco_indicators_coo(
             p_user, p_item, a_user, a_item, n_users, n_items_p, n_items_t,
             top_k, llr_threshold, mesh, exclude_self,
         )
-    with span("layout"):
+    with span("layout") as rec:
         p = block_interactions(p_user, p_item, n_users, n_items_p,
                                user_block=user_block)
         a = block_interactions(a_user, a_item, n_users, n_items_t,
                                user_block=user_block)
+        slots = p.local_u.size + a.local_u.size
+        rec["attrs"] = {
+            "user_blocks": p.n_blocks, "slots": slots,
+            "pad_slots": slots - int(p.count.sum()) - int(a.count.sum())}
     return cco_indicators(
-        p, a, None, None, n_users, top_k=top_k, llr_threshold=llr_threshold,
+        p, a, n_users, top_k=top_k, llr_threshold=llr_threshold,
         item_tile=item_tile, mesh=mesh, exclude_self=exclude_self,
     )
 
@@ -1436,9 +1462,7 @@ def cco_indicators_coo(
 def cco_indicators(
     primary: BlockedInteractions,
     other: BlockedInteractions,
-    primary_item_counts: Optional[np.ndarray] = None,
-    other_item_counts: Optional[np.ndarray] = None,
-    n_total_users: int = 0,
+    n_total_users: int,
     top_k: int = 50,
     llr_threshold: float = 0.0,
     item_tile: int = 4096,
@@ -1458,14 +1482,13 @@ def cco_indicators(
       one MXU matmul per chunk (exact int32 counts), marginals as column
       sums; then one fused LLR+top-k over the full count matrix.
     - **tiled** (huge item catalogs): an item-tile loop that never
-      materializes the full count matrix, re-densifying per tile and
-      merging a running top-k; marginals accumulate in the same scan.
+      materializes the full count matrix and merges a running top-k,
+      with the densified primary resident where its plan fits
+      (``_resident_p_ok``) and re-densified per user block and tile
+      where it does not; marginals accumulate in the same scan.
 
-    ``primary_item_counts``/``other_item_counts`` are DEPRECATED and ignored:
-    both strategies derive the LLR marginals from the interactions
-    themselves ON DEVICE (densified matrices are dedup'd by construction),
-    so the two paths are semantically identical and no host unique/count
-    pass exists for callers to get wrong.
+    Every strategy derives the LLR marginals from the interactions
+    themselves ON DEVICE (densified matrices are dedup'd by construction).
     """
     if n_total_users <= 0:
         raise ValueError(f"n_total_users must be positive, got {n_total_users}")
@@ -1504,12 +1527,13 @@ def cco_indicators(
 
     pallas = pallas_mode()
 
-    host_args = (primary.local_u, primary.item, primary.mask,
-                 other.local_u, other.item, other.mask)
+    host_args = (primary.local_u, primary.item, primary.count,
+                 other.local_u, other.item, other.count)
     if mesh is None:
         with span("h2d", bytes=sum(a.nbytes for a in host_args)):
             args = tuple(jnp.asarray(a) for a in host_args)
-        with span("dispatch", program="_cco_chunked_all_tiles", topk=topk):
+        with span("dispatch", program="_cco_chunked_all_tiles", topk=topk,
+                  tiles=n_tiles, block_steps=n_tiles * primary.n_blocks):
             best_scores, best_idx = _cco_chunked_all_tiles(
                 *args, float(n_total_users),
                 n_tiles=n_tiles, block=primary.user_block,
@@ -1541,9 +1565,9 @@ def cco_indicators(
             in_specs=(spec,) * 6 + (rep,) * 3,
             out_specs=(rep, rep),
         )
-        def tile_step_sharded(plu, pit, pmk, alu, ait, amk, bs, bi, ts):
+        def tile_step_sharded(plu, pit, pcnt, alu, ait, acnt, bs, bi, ts):
             return _cco_tile_step(
-                plu, pit, pmk, alu, ait, amk, float(n_total_users),
+                plu, pit, pcnt, alu, ait, acnt, float(n_total_users),
                 bs, bi, ts,
                 block=primary.user_block, n_items_p=n_items_p,
                 tile=tile, top_k=top_k, llr_threshold=llr_threshold,

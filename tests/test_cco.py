@@ -7,7 +7,6 @@ import pytest
 from predictionio_tpu.ops.cco import (
     block_interactions,
     cco_indicators,
-    interaction_counts,
     llr_score,
 )
 from predictionio_tpu.parallel.mesh import MeshSpec, create_mesh
@@ -59,8 +58,30 @@ def test_llr_matches_naive_formula():
         assert abs(got - want) < 1e-3, (k, got, want)
 
 
+# how each device program is reached on the CPU: by what the rule is given
+PROGRAMS = {"dense": {"PIO_CCO_DENSE": "1"},
+            "resident": {"PIO_CCO_DENSE": "0"},
+            "chunked": {"PIO_CCO_DENSE": "0", "_TILED_P_BYTES": 0}}
+
+
+def _take_program(monkeypatch, program):
+    from predictionio_tpu.ops import cco as cco_mod
+
+    monkeypatch.setenv("PIO_CCO_SPARSE", "0")
+    for key, value in PROGRAMS[program].items():
+        if key.startswith("PIO_"):
+            monkeypatch.setenv(key, value)
+        else:
+            monkeypatch.setattr(cco_mod, key, value)
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
 @pytest.mark.parametrize("user_block,item_tile", [(64, 64), (16, 8), (1024, 4096)])
-def test_cco_matches_naive(user_block, item_tile):
+def test_cco_matches_naive(monkeypatch, user_block, item_tile, program):
+    """Every device program keeps the naive table's cells with its scores:
+    users not a multiple of the block (50 = 3 x 16 + 2), items not a
+    multiple of the tile (15 = 8 + 7)."""
+    _take_program(monkeypatch, program)
     n_users, n_ip, n_it = 50, 20, 15
     pu, pi = random_interactions(n_users, n_ip, 300, 1)
     ou, oi = random_interactions(n_users, n_it, 400, 2)
@@ -70,13 +91,10 @@ def test_cco_matches_naive(user_block, item_tile):
     p = block_interactions(pu, pi, n_users, n_ip, user_block=user_block, dedup=True)
     o = block_interactions(ou, oi, n_users, n_it, user_block=user_block, dedup=True)
     # distinct-user counts from dedup'd blocked data
-    rc = np.zeros(n_ip, np.float32)
-    np.add.at(rc, p.item[p.mask > 0], 1)
-    cc = np.zeros(n_it, np.float32)
-    np.add.at(cc, o.item[o.mask > 0], 1)
-    assert np.allclose(rc, C.sum(1) * 0 + (np.zeros((n_users, n_ip)) + _dense(pu, pi, n_users, n_ip)).sum(0))
+    assert np.array_equal(np.bincount(p.item[p.mask], minlength=n_ip),
+                          _dense(pu, pi, n_users, n_ip).sum(0))
 
-    scores, idx = cco_indicators(p, o, rc, cc, n_users, top_k=n_it, item_tile=item_tile)
+    scores, idx = cco_indicators(p, o, n_users, top_k=n_it, item_tile=item_tile)
     for i in range(n_ip):
         got = {int(j): float(s) for s, j in zip(scores[i], idx[i]) if j >= 0}
         want = {j: llr[i, j] for j in range(n_it) if np.isfinite(llr[i, j]) and llr[i, j] >= 0}
@@ -97,15 +115,13 @@ def test_cco_top_k_and_threshold():
     ou, oi = random_interactions(n_users, n_it, 250, 4)
     p = block_interactions(pu, pi, n_users, n_ip)
     o = block_interactions(ou, oi, n_users, n_it)
-    rc = _dense(pu, pi, n_users, n_ip).sum(0).astype(np.float32)
-    cc = _dense(ou, oi, n_users, n_it).sum(0).astype(np.float32)
-    scores, idx = cco_indicators(p, o, rc, cc, n_users, top_k=3)
+    scores, idx = cco_indicators(p, o, n_users, top_k=3)
     assert scores.shape == (n_ip, 3)
     # scores sorted descending per row
     finite = np.where(np.isfinite(scores), scores, -1e30)
     assert (np.diff(finite, axis=1) <= 1e-6).all()
     # high threshold kills everything
-    s2, i2 = cco_indicators(p, o, rc, cc, n_users, top_k=3, llr_threshold=1e9)
+    s2, i2 = cco_indicators(p, o, n_users, top_k=3, llr_threshold=1e9)
     assert (i2 == -1).all()
 
 
@@ -113,8 +129,7 @@ def test_cco_exclude_self():
     n_users, n_items = 30, 8
     u, i = random_interactions(n_users, n_items, 150, 5)
     b = block_interactions(u, i, n_users, n_items, dedup=True)
-    counts = _dense(u, i, n_users, n_items).sum(0).astype(np.float32)
-    scores, idx = cco_indicators(b, b, counts, counts, n_users, top_k=4, exclude_self=True)
+    scores, idx = cco_indicators(b, b, n_users, top_k=4, exclude_self=True)
     for row in range(n_items):
         assert row not in idx[row][idx[row] >= 0]
 
@@ -125,11 +140,9 @@ def test_cco_mesh_matches_single():
     ou, oi = random_interactions(n_users, n_it, 300, 7)
     p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
     o = block_interactions(ou, oi, n_users, n_it, user_block=8)
-    rc = _dense(pu, pi, n_users, n_ip).sum(0).astype(np.float32)
-    cc = _dense(ou, oi, n_users, n_it).sum(0).astype(np.float32)
-    s1, i1 = cco_indicators(p, o, rc, cc, n_users, top_k=5)
+    s1, i1 = cco_indicators(p, o, n_users, top_k=5)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
-    s8, i8 = cco_indicators(p, o, rc, cc, n_users, top_k=5, mesh=mesh)
+    s8, i8 = cco_indicators(p, o, n_users, top_k=5, mesh=mesh)
     assert np.allclose(np.where(np.isfinite(s1), s1, -1), np.where(np.isfinite(s8), s8, -1), atol=1e-3)
     assert (i1 == i8).all()
 
@@ -149,9 +162,9 @@ def test_tiled_mesh_matches_single(monkeypatch, kernels):
     ou, oi = random_interactions(n_users, n_it, 300, 7)
     p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
     o = block_interactions(ou, oi, n_users, n_it, user_block=8)
-    s1, i1 = cco_indicators(p, o, None, None, n_users, top_k=5, item_tile=4)
+    s1, i1 = cco_indicators(p, o, n_users, top_k=5, item_tile=4)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
-    s8, i8 = cco_indicators(p, o, None, None, n_users, top_k=5, item_tile=4,
+    s8, i8 = cco_indicators(p, o, n_users, top_k=5, item_tile=4,
                             mesh=mesh)
     np.testing.assert_allclose(s1, s8, rtol=1e-5)
     for r in range(n_ip):   # tie order aside, the same correlators
@@ -165,13 +178,11 @@ def test_dense_matches_tiled(monkeypatch):
     ou, oi = random_interactions(n_users, n_it, 500, 12)
     p = block_interactions(pu, pi, n_users, n_ip, user_block=16, dedup=True)
     o = block_interactions(ou, oi, n_users, n_it, user_block=16, dedup=True)
-    rc = interaction_counts(p.item[p.mask > 0], n_ip)
-    cc = interaction_counts(o.item[o.mask > 0], n_it)
 
     monkeypatch.setenv("PIO_CCO_DENSE", "1")
-    sd, idd = cco_indicators(p, o, rc, cc, n_users, top_k=6, item_tile=8)
+    sd, idd = cco_indicators(p, o, n_users, top_k=6, item_tile=8)
     monkeypatch.setenv("PIO_CCO_DENSE", "0")
-    st, idt = cco_indicators(p, o, rc, cc, n_users, top_k=6, item_tile=8)
+    st, idt = cco_indicators(p, o, n_users, top_k=6, item_tile=8)
     np.testing.assert_allclose(sd, st, rtol=1e-5)
     # indices may tie-break differently only where scores tie; require
     # identical index sets per row for non-padding entries
@@ -188,11 +199,9 @@ def test_dense_mesh_matches_single(monkeypatch):
     ou, oi = random_interactions(n_users, n_it, 400, 22)
     p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
     o = block_interactions(ou, oi, n_users, n_it, user_block=8)
-    rc = interaction_counts(p.item[p.mask > 0], n_ip)
-    cc = interaction_counts(o.item[o.mask > 0], n_it)
-    s1, i1 = cco_indicators(p, o, rc, cc, n_users, top_k=5)
+    s1, i1 = cco_indicators(p, o, n_users, top_k=5)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
-    s8, i8 = cco_indicators(p, o, rc, cc, n_users, top_k=5, mesh=mesh)
+    s8, i8 = cco_indicators(p, o, n_users, top_k=5, mesh=mesh)
     np.testing.assert_allclose(s1, s8, rtol=1e-5, atol=1e-5)
 
 
@@ -201,9 +210,8 @@ def test_dense_exclude_self_and_topk_overflow(monkeypatch):
     n_users, n_items = 40, 6
     u, i = random_interactions(n_users, n_items, 200, 31)
     b = block_interactions(u, i, n_users, n_items, dedup=True)
-    counts = interaction_counts(b.item[b.mask > 0], n_items)
     # top_k wider than the (padded) item space still returns [I, top_k]
-    scores, idx = cco_indicators(b, b, counts, counts, n_users,
+    scores, idx = cco_indicators(b, b, n_users,
                                  top_k=300, exclude_self=True)
     assert scores.shape == (n_items, 300) and idx.shape == (n_items, 300)
     for r in range(n_items):
@@ -216,13 +224,12 @@ def test_dense_matches_tiled_exclude_self(monkeypatch):
     n_users, n_items = 60, 14
     u, i = random_interactions(n_users, n_items, 400, 41)
     b = block_interactions(u, i, n_users, n_items, user_block=16, dedup=True)
-    counts = interaction_counts(b.item[b.mask > 0], n_items)
 
     monkeypatch.setenv("PIO_CCO_DENSE", "1")
-    sd, idd = cco_indicators(b, b, counts, counts, n_users, top_k=5,
+    sd, idd = cco_indicators(b, b, n_users, top_k=5,
                              item_tile=8, exclude_self=True)
     monkeypatch.setenv("PIO_CCO_DENSE", "0")
-    st, idt = cco_indicators(b, b, counts, counts, n_users, top_k=5,
+    st, idt = cco_indicators(b, b, n_users, top_k=5,
                              item_tile=8, exclude_self=True)
     np.testing.assert_allclose(sd, st, rtol=1e-5)
     for r in range(n_items):
@@ -329,40 +336,49 @@ def test_block_interactions_stream_matches_batch():
     streamed = block_interactions_stream(
         ((u[s:s + 37], i[s:s + 37]) for s in range(0, 400, 37)),
         n_users, n_items, user_block=16)
-    s1, i1 = cco_indicators(whole, whole, None, None, n_users, top_k=5,
+    s1, i1 = cco_indicators(whole, whole, n_users, top_k=5,
                             item_tile=8, exclude_self=True)
-    s2, i2 = cco_indicators(streamed, streamed, None, None, n_users, top_k=5,
+    s2, i2 = cco_indicators(streamed, streamed, n_users, top_k=5,
                             item_tile=8, exclude_self=True)
     np.testing.assert_allclose(s1, s2, rtol=1e-5)
     for r in range(n_items):
         assert set(i1[r][s1[r] > -np.inf]) == set(i2[r][s2[r] > -np.inf])
 
 
-def test_resident_tiled_matches_chunked_tiled(monkeypatch):
+@pytest.mark.parametrize("n_users,n_ip,n_it,user_block,item_tile", [
+    (70, 14, 19, 16, 8),
+    (131, 37, 45, 32, 16),     # a last block of 3 users, a last tile of 13
+])
+def test_resident_tiled_matches_chunked_tiled(monkeypatch, n_users, n_ip, n_it,
+                                              user_block, item_tile):
     """The P-resident tiled strategy (primary densified once, reused per
-    tile) returns the same scores as the chunked tiled path and the dense
-    path."""
+    tile), the chunked tiled path (re-densified per user block and tile)
+    and the dense path return the same kept cells with the same scores."""
     from predictionio_tpu.ops import cco as cco_mod
     from predictionio_tpu.ops.cco import cco_indicators_coo
 
-    n_users, n_ip, n_it = 70, 14, 19
     pu, pi = random_interactions(n_users, n_ip, 400, 101)
     ou, oi = random_interactions(n_users, n_it, 600, 102)
 
-    monkeypatch.setenv("PIO_CCO_DENSE", "1")
-    sd, _ = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
-                               top_k=5, item_tile=8)
-    monkeypatch.setenv("PIO_CCO_DENSE", "0")
-    # resident path active (P easily fits)
-    assert cco_mod._resident_p_ok(n_users, n_ip)
-    sr, _ = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
-                               top_k=5, item_tile=8, user_block=16)
-    # force the chunked tiled path by shrinking the resident budget
-    monkeypatch.setattr(cco_mod, "_TILED_P_BYTES", 1)
-    st, _ = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
-                               top_k=5, item_tile=8, user_block=16)
+    def run():
+        return cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it, top_k=5,
+                                  item_tile=item_tile, user_block=user_block)
+
+    _take_program(monkeypatch, "dense")
+    sd, idd = run()
+    _take_program(monkeypatch, "resident")
+    assert cco_mod._resident_p_ok(n_users, n_ip)      # P easily fits
+    sr, idr = run()
+    # the chunked tiled path: no budget for a resident primary
+    _take_program(monkeypatch, "chunked")
+    assert not cco_mod._resident_p_ok(n_users, n_ip)
+    st, idt = run()
     np.testing.assert_allclose(sd, sr, rtol=1e-4)
     np.testing.assert_allclose(sr, st, rtol=1e-4)
+    for r in range(n_ip):   # tie order aside, the same correlators
+        kept = set(idd[r][sd[r] > -np.inf])
+        assert kept == set(idr[r][sr[r] > -np.inf])
+        assert kept == set(idt[r][st[r] > -np.inf])
 
 
 def test_resident_tiled_self_pair(monkeypatch):

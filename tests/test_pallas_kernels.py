@@ -75,7 +75,7 @@ def test_llr_masked_scores_matches_reference():
 
 
 def test_cco_indicators_pallas_matches_xla(monkeypatch):
-    from predictionio_tpu.ops.cco import block_interactions, cco_indicators, interaction_counts
+    from predictionio_tpu.ops.cco import block_interactions, cco_indicators
 
     rng = np.random.default_rng(3)
     n_users, n_ip, n_it = 60, 25, 40
@@ -85,12 +85,11 @@ def test_cco_indicators_pallas_matches_xla(monkeypatch):
     oi = rng.integers(0, n_it, 800)
     p = block_interactions(pu, pi, n_users, n_ip, user_block=16)
     o = block_interactions(ou, oi, n_users, n_it, user_block=16)
-    rc, cc = interaction_counts(pi, n_ip), interaction_counts(oi, n_it)
 
     monkeypatch.setenv("PIO_PALLAS", "0")
-    s1, i1 = cco_indicators(p, o, rc, cc, n_users, top_k=5, llr_threshold=1.0, item_tile=16)
+    s1, i1 = cco_indicators(p, o, n_users, top_k=5, llr_threshold=1.0, item_tile=16)
     monkeypatch.setenv("PIO_PALLAS", "interpret")
-    s2, i2 = cco_indicators(p, o, rc, cc, n_users, top_k=5, llr_threshold=1.0, item_tile=16)
+    s2, i2 = cco_indicators(p, o, n_users, top_k=5, llr_threshold=1.0, item_tile=16)
 
     finite = np.isfinite(s1)
     assert (np.isfinite(s2) == finite).all()

@@ -227,7 +227,7 @@ def test_merge_ties_at_the_cut_over_a_partial_last_tile(monkeypatch):
 
     def run(pallas):
         monkeypatch.setenv("PIO_PALLAS", pallas)
-        return cco_indicators(p, o, None, None, n_users, top_k=top_k,
+        return cco_indicators(p, o, n_users, top_k=top_k,
                               item_tile=64)
 
     s_lax, _ = run("off")
