@@ -13,19 +13,25 @@ TPU design (not a translation of MLlib's shuffle pattern):
   so each half-step is pure local compute after one ``all_gather`` of the
   opposite factor block (the collective rides ICI; this replaces MLlib's
   shuffle of in/out-link blocks).
-- Each half-step forms per-entity normal equations with one
-  ``segment_sum`` of rank-1 outer products (MXU-batched) and solves the
-  K×K systems with a batched Cholesky — no data-dependent shapes, one
-  compiled program for the whole training run (`lax.fori_loop` over
-  sweeps).
+- Each half-step forms per-entity normal equations from ONE packed row a
+  rating — the upper triangle of y yᵀ and r·y, K(K+3)/2 floats (65 at rank
+  10: one 128-lane row) — summed by ONE ``segment_sum`` over the ratings,
+  and solves the K×K systems with a batched Cholesky.  No per-rating K×K
+  block exists: the TPU pads one to [16, 128] (20× its size), which was 8.2
+  GB a half-step at a million ratings and 57% of a train (PERF.md, PR 27).
+  The ratings-per-row counts behind the ridge depend on the layout alone
+  and are reduced once, before the sweeps.  No data-dependent shapes, one
+  compiled program for the whole training run (`lax.fori_loop` over sweeps).
 
-Memory: A-blocks are [rows_per_shard, K, K] f32; events are padded to the
-max per-shard count. f32 throughout the solves (K ≤ a few hundred);
-gathers/matmuls stay f32 in storage; what precision a TPU multiplies
-them at is the compiler's choice, so parity with MLlib's f64 holds on CPU
-only (on a v5e the Pallas scoring kernel's products are bf16-rounded:
-max abs error 0.085 on one query's 100k scores, where the XLA path it
-replaces stayed at 3e-6 — PERF.md "Bring-up on TPU v5e").
+Memory: the packed rows are [E, K(K+3)/2] f32 per shard (lane-padded to a
+multiple of 128), the reduced systems [rows_per_shard, K, K]; events are
+padded to the max per-shard count. f32 throughout the solves (K ≤ a few
+hundred) and in the 0/1 matmuls that lay the packed row out (HIGHEST:
+exact); gathers/matmuls stay f32 in storage; what precision a TPU multiplies
+the serving matmuls at is the compiler's choice, so parity with MLlib's f64
+holds on CPU only (on a v5e the Pallas scoring kernel's products are
+bf16-rounded: max abs error 0.085 on one query's 100k scores, where the XLA
+path it replaces stayed at 3e-6 — PERF.md "Bring-up on TPU v5e").
 """
 
 from __future__ import annotations
@@ -122,35 +128,90 @@ def prepare_als_data(
     )
 
 
+def _packed_width(k: int) -> int:
+    """Width of a rating's packed row: the triangle of y yᵀ, then r·y."""
+    return k * (k + 1) // 2 + k
+
+
+@functools.lru_cache(maxsize=None)
+def _pack_selectors(k: int) -> Tuple[np.ndarray, np.ndarray]:
+    """0/1 matrices ([K+1, W], [K, W]) that lay ``[yw | rhs_w]`` and ``y``
+    out along the packed row: column (p, q), p ≤ q, of the triangle takes
+    ``yw[p]`` and ``y[q]``; column j of the tail takes ``rhs_w`` and ``y[j]``."""
+    p, q = np.triu_indices(k)
+    col = np.arange(_packed_width(k))
+    sel_l = np.zeros((k + 1, len(col)), np.float32)
+    sel_r = np.zeros((k, len(col)), np.float32)
+    sel_l[np.concatenate([p, np.full(k, k)]), col] = 1.0
+    sel_r[np.concatenate([q, np.arange(k)]), col] = 1.0
+    return sel_l, sel_r
+
+
+def _packed_rows(yw: jnp.ndarray, y: jnp.ndarray, rhs_w: jnp.ndarray) -> jnp.ndarray:
+    """One flat row a rating, [E, W]: ``yw[p]·y[q]`` for p ≤ q (row by row of
+    the upper triangle), then ``rhs_w·y``.  Summed over a row's ratings it
+    holds that row's whole normal equations; at rank 10 it is 65 floats, one
+    128-lane row, where a K×K block pads to [16, 128].
+
+    The two operands are spread over the W columns by 0/1 matmuls (exact at
+    HIGHEST: each column copies one float32): XLA:TPU fuses the product into
+    the second matmul's output, where W lane slices concatenated cost a pass
+    over [E, 128] each (my chip runs, PR 27: 2.9 against ~32 ms a half-step)."""
+    sel_l, sel_r = _pack_selectors(y.shape[-1])
+    with jax.named_scope("als.rhs"):
+        left = jnp.concatenate([yw, rhs_w[:, None]], axis=1)
+    hi = jax.lax.Precision.HIGHEST
+    return jnp.dot(left, sel_l, precision=hi) * jnp.dot(y, sel_r, precision=hi)
+
+
+def _unpack_rows(red: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """[rows, W] sums of packed rows → (A [rows, K, K] symmetric, b [rows, K])."""
+    t = k * (k + 1) // 2
+    at = np.zeros((k, k), np.int32)
+    at[np.triu_indices(k)] = np.arange(t, dtype=np.int32)
+    at = np.maximum(at, at.T)
+    return red[:, at], red[:, t:]
+
+
+def _row_ridge(local_idx: jnp.ndarray, mask: jnp.ndarray, rows: int, reg) -> jnp.ndarray:
+    """λ·n_e ridge (MLlib's ALS-WR weighting) + ε guard for empty rows.  It
+    depends on the layout alone: computed once, before the sweeps."""
+    n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
+    return reg * jnp.maximum(n_e, 1.0) + 1e-6
+
+
+def _solve_rows(z, local_idx, lam, k: int, gram=None) -> jnp.ndarray:
+    """The half-step's one segment reduction, over the packed rows, then the
+    K×K solves.  XLA:TPU's scatter-add of [E, W] rows costs 6.8 ns a rating
+    on a v5e, 8.7 where runs of ratings share a row, and more again with
+    ``indices_are_sorted``; a blockwise one-hot matmul over rows sorted by
+    segment gained 5% of the program for a device sort that compiles for
+    15–27 s, and was not kept (my chip runs, PR 27)."""
+    with jax.named_scope("als.normal_eq"):
+        A, b = _unpack_rows(
+            jax.ops.segment_sum(z, local_idx, num_segments=lam.shape[0]), k)
+        if gram is not None:
+            A = A + gram
+        A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
+    with jax.named_scope("als.solve"):
+        cho = jax.scipy.linalg.cho_factor(A)
+        return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
+
+
 def _half_step(
     other_full: jnp.ndarray,   # [dp*other_rows, K] gathered opposite factors
     local_idx: jnp.ndarray,    # [E] rows to solve for (this shard)
     other_flat: jnp.ndarray,   # [E] flat gather index into other_full
     rating: jnp.ndarray,       # [E]
     mask: jnp.ndarray,         # [E]
-    rows: int,
-    reg: float,
+    lam: jnp.ndarray,          # [rows] ridge of each row (_row_ridge)
 ) -> jnp.ndarray:
-    """Solve per-row normal equations (YtCY + λ n_e I) x = Ytr on one shard."""
-    k = other_full.shape[-1]
+    """Solve per-row normal equations (YtY + λ n_e I) x = Ytr on one shard."""
     with jax.named_scope("als.gather"):
         y = other_full[other_flat] * mask[:, None]            # [E, K]
     with jax.named_scope("als.normal_eq"):
-        # A: segment-summed outer products, MXU-batched as [E, K, K]
-        # contributions
-        outer = y[:, :, None] * y[:, None, :]
-        A = jax.ops.segment_sum(outer, local_idx, num_segments=rows)
-    with jax.named_scope("als.rhs"):
-        b = jax.ops.segment_sum(y * rating[:, None], local_idx,
-                                num_segments=rows)
-    with jax.named_scope("als.normal_eq"):
-        n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
-        # λ·n_e ridge (MLlib's ALS-WR weighting) + ε guard for empty rows
-        lam = reg * jnp.maximum(n_e, 1.0) + 1e-6
-        A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
-    with jax.named_scope("als.solve"):
-        cho = jax.scipy.linalg.cho_factor(A)
-        return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
+        z = _packed_rows(y, y, rating)
+    return _solve_rows(z, local_idx, lam, y.shape[-1])
 
 
 def _half_step_implicit(
@@ -160,8 +221,7 @@ def _half_step_implicit(
     other_flat: jnp.ndarray,   # [E]
     rating: jnp.ndarray,       # [E] raw counts/strengths r ≥ 0
     mask: jnp.ndarray,         # [E]
-    rows: int,
-    reg: float,
+    lam: jnp.ndarray,          # [rows]
     alpha: jnp.ndarray,
 ) -> jnp.ndarray:
     """Implicit-feedback half-step (Hu/Koren/Volinsky; MLlib trainImplicit).
@@ -171,93 +231,82 @@ def _half_step_implicit(
     is the precomputed ``gram`` (one [N,K]×[K,N] MXU matmul per sweep),
     and only the observed events contribute the (c−1)-weighted correction.
     """
-    k = other_full.shape[-1]
-    y = other_full[other_flat] * mask[:, None]            # [E, K]
-    c1 = alpha * rating * mask                            # c − 1, 0 on padding
-    outer = (c1[:, None] * y)[:, :, None] * y[:, None, :]
-    A = jax.ops.segment_sum(outer, local_idx, num_segments=rows) + gram
-    b = jax.ops.segment_sum((1.0 + c1)[:, None] * y, local_idx, num_segments=rows)
-    n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
-    lam = reg * jnp.maximum(n_e, 1.0) + 1e-6
-    A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
-    cho = jax.scipy.linalg.cho_factor(A)
-    return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
+    with jax.named_scope("als.gather"):
+        y = other_full[other_flat] * mask[:, None]            # [E, K]
+    with jax.named_scope("als.normal_eq"):
+        c1 = alpha * rating * mask                            # c − 1, 0 on padding
+        z = _packed_rows(c1[:, None] * y, y, 1.0 + c1)
+    return _solve_rows(z, local_idx, lam, y.shape[-1], gram)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("user_rows", "item_rows", "implicit"))
+def _sweeps(x0, y0, iters, reg, alpha, u_side, i_side, implicit: bool, gather_full):
+    """``iters`` ALS sweeps over the factor rows this program solves (``x0``
+    [rows_u, K], ``y0`` [rows_i, K]) and their events (``u_side``,
+    ``i_side``: local row, opposite flat index, rating, mask, each [E]).
+    ``gather_full`` turns its rows of one side into that side's whole table."""
+    # the counts depend on the layout alone: once, not in each half-step
+    lam_u = _row_ridge(u_side[0], u_side[3], x0.shape[0], reg)
+    lam_i = _row_ridge(i_side[0], i_side[3], y0.shape[0], reg)
+
+    def half(other_full, side, lam):
+        if implicit:
+            gram = other_full.T @ other_full
+            return _half_step_implicit(other_full, gram, *side, lam, alpha)
+        return _half_step(other_full, *side, lam)
+
+    def sweep(_, carry):
+        x, y = carry
+        x = half(gather_full(y), u_side, lam_u)
+        y = half(gather_full(x), i_side, lam_i)
+        return (x, y)
+
+    return jax.lax.fori_loop(0, iters, sweep, (x0, y0))
+
+
+def _as_one_shard(local, other_flat, rating, mask, rows: int):
+    """A [dp, E] layout as ONE shard of dp·rows rows: shard s's local row r
+    is row s·rows + r, its flat index in the factor blocks."""
+    offset = jnp.arange(local.shape[0], dtype=local.dtype)[:, None] * rows
+    return tuple(a.reshape(-1) for a in (local + offset, other_flat, rating, mask))
+
+
+@functools.partial(jax.jit, static_argnames=("implicit",))
 def _als_run_single(
     x0, y0, iters, reg, alpha,
     uu, ui, ur, um, ii, iu, ir, im,
-    *, user_rows: int, item_rows: int, implicit: bool = False,
+    *, implicit: bool = False,
 ):
-    """Single-program ALS sweeps, vmapped over the shard axis.
+    """Single-program ALS sweeps: every shard's rows and events on one device.
 
     Module-level jit with DYNAMIC iteration count, reg, and alpha: one
     compiled program per data/factor shape/mode serves every (iterations,
     reg, alpha) setting — retraining and hyperparameter grids never
     recompile.
     """
-    dp, _, k = y0.shape
-
-    def sweep(_, carry):
-        x, y = carry
-        y_full = y.reshape(dp * item_rows, k)
-        if implicit:
-            gram_y = y_full.T @ y_full
-            x = jax.vmap(
-                lambda lo, ot, rr, mm: _half_step_implicit(
-                    y_full, gram_y, lo, ot, rr, mm, user_rows, reg, alpha)
-            )(uu, ui, ur, um)
-        else:
-            x = jax.vmap(
-                lambda lo, ot, rr, mm: _half_step(y_full, lo, ot, rr, mm, user_rows, reg)
-            )(uu, ui, ur, um)
-        x_full = x.reshape(dp * user_rows, k)
-        if implicit:
-            gram_x = x_full.T @ x_full
-            y = jax.vmap(
-                lambda lo, ot, rr, mm: _half_step_implicit(
-                    x_full, gram_x, lo, ot, rr, mm, item_rows, reg, alpha)
-            )(ii, iu, ir, im)
-        else:
-            y = jax.vmap(
-                lambda lo, ot, rr, mm: _half_step(x_full, lo, ot, rr, mm, item_rows, reg)
-            )(ii, iu, ir, im)
-        return (x, y)
-
-    return jax.lax.fori_loop(0, iters, sweep, (x0, y0))
+    k = y0.shape[-1]
+    x, y = _sweeps(
+        x0.reshape(-1, k), y0.reshape(-1, k), iters, reg, alpha,
+        _as_one_shard(uu, ui, ur, um, x0.shape[1]),
+        _as_one_shard(ii, iu, ir, im, y0.shape[1]),
+        implicit, gather_full=lambda f: f)
+    return x.reshape(x0.shape), y.reshape(y0.shape)
 
 
 @functools.lru_cache(maxsize=8)
-def _als_sharded_fn(mesh: Mesh, user_rows: int, item_rows: int, implicit: bool):
-    """Build (and cache per mesh/layout/mode) the shard_map'd ALS runner."""
+def _als_sharded_fn(mesh: Mesh, implicit: bool):
+    """Build (and cache per mesh/mode) the shard_map'd ALS runner."""
 
-    def per_shard(x0_, y0_, iters, reg, alpha, uu, ui, ur, um, ii, iu, ir, im):
-        def sweep(_, carry):
-            # Every array here is this shard's block: factors [1, rows, K],
-            # events [1, E].  all_gather pulls the opposite side's blocks
-            # over ICI — the only communication in the sweep.  The implicit
-            # Gram is computed from the gathered full matrix (replicated
-            # K×K work, negligible next to the solves).
-            x, y = carry
-            y_full = jax.lax.all_gather(y[0], "dp", tiled=True)  # [dp*item_rows, K]
-            if implicit:
-                gram_y = y_full.T @ y_full
-                x = _half_step_implicit(
-                    y_full, gram_y, uu[0], ui[0], ur[0], um[0], user_rows, reg, alpha)[None]
-            else:
-                x = _half_step(y_full, uu[0], ui[0], ur[0], um[0], user_rows, reg)[None]
-            x_full = jax.lax.all_gather(x[0], "dp", tiled=True)
-            if implicit:
-                gram_x = x_full.T @ x_full
-                y = _half_step_implicit(
-                    x_full, gram_x, ii[0], iu[0], ir[0], im[0], item_rows, reg, alpha)[None]
-            else:
-                y = _half_step(x_full, ii[0], iu[0], ir[0], im[0], item_rows, reg)[None]
-            return (x, y)
-
-        return jax.lax.fori_loop(0, iters, sweep, (x0_, y0_))
+    def per_shard(x0_, y0_, iters, reg, alpha, *sides):
+        # Every array here is this shard's block: factors [1, rows, K],
+        # events [1, E].  all_gather pulls the opposite side's blocks over
+        # ICI — the only communication in a sweep.  The implicit Gram is
+        # computed from the gathered full matrix (replicated K×K work,
+        # negligible next to the solves).
+        mine = [a[0] for a in sides]
+        x, y = _sweeps(
+            x0_[0], y0_[0], iters, reg, alpha, mine[:4], mine[4:], implicit,
+            gather_full=lambda f: jax.lax.all_gather(f, "dp", tiled=True))
+        return x[None], y[None]
 
     spec, rep = P("dp"), P()
     return jax.jit(jax.shard_map(
@@ -267,9 +316,8 @@ def _als_sharded_fn(mesh: Mesh, user_rows: int, item_rows: int, implicit: bool):
     ))
 
 
-def _als_run_sharded(mesh, user_rows, item_rows, implicit, x0, y0, iters, reg, alpha, *args):
-    return _als_sharded_fn(mesh, user_rows, item_rows, implicit)(
-        x0, y0, iters, reg, alpha, *args)
+def _als_run_sharded(mesh, implicit, x0, y0, iters, reg, alpha, *args):
+    return _als_sharded_fn(mesh, implicit)(x0, y0, iters, reg, alpha, *args)
 
 
 def als_train(
@@ -339,13 +387,16 @@ def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=Non
                 implicit: bool = False, alpha: float = 1.0):
     if args is None:
         args = _als_device_args(data)
+    # which program shape ran: the packed row's width and the event slots a
+    # shard of each layout (the reduction always engages; this is its witness)
+    shape = dict(packed_width=_packed_width(x0.shape[-1]),
+                 events=int(data.u_mask.shape[1]))
     if mesh is None:
-        with span("dispatch", program="_als_run_single"):
+        with span("dispatch", program="_als_run_single", **shape):
             return _als_run_single(
                 x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
                 jnp.float32(alpha),
-                *args, user_rows=data.user_rows, item_rows=data.item_rows,
-                implicit=implicit,
+                *args, implicit=implicit,
             )
     if mesh.shape.get("dp", 1) != data.dp:
         raise ValueError(
@@ -356,10 +407,9 @@ def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=Non
     with span("h2d", bytes=x0.nbytes + y0.nbytes):
         x0 = stage_global(np.asarray(x0), sharding)
         y0 = stage_global(np.asarray(y0), sharding)
-    with span("dispatch", program="_als_run_sharded"):
+    with span("dispatch", program="_als_run_sharded", **shape):
         return _als_run_sharded(
-            mesh, data.user_rows, data.item_rows, implicit,
-            x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
+            mesh, implicit, x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
             jnp.float32(alpha), *args,
         )
 

@@ -159,3 +159,165 @@ def test_implicit_als_mesh_matches_single_device():
     s1, s8 = separation(p1), separation(p8)
     assert s1 > 0 and s8 > 0
     assert abs(s1 - s8) / max(s1, s8) < 0.15, (s1, s8)
+
+
+# -- the half-step itself: one packed row a rating, one reduction -------------
+
+
+def _half_step_case(k, seed=0):
+    """A shard's events over 7 rows with row 3 empty and two padded slots
+    (mask 0) that still carry an index and a rating, as no layout pads them:
+    the mask alone must keep them out."""
+    rng = np.random.default_rng(seed)
+    rows, n_other, e = 7, 9, 48
+    local = rng.integers(0, rows - 1, e)
+    local = np.where(local >= 3, local + 1, local).astype(np.int32)
+    other = rng.integers(0, n_other, e).astype(np.int32)
+    rating = rng.integers(1, 6, e).astype(np.float32)
+    mask = np.ones(e, np.float32)
+    mask[[5, 17]] = 0.0
+    factors = rng.normal(size=(n_other, k)).astype(np.float32)
+    return rows, local, other, rating, mask, factors
+
+
+def _half_step_float64(rows, local, other, rating, mask, factors, reg, alpha):
+    """Each row's normal equations solved alone, in float64.  ``alpha`` None
+    is the explicit system, else the implicit one."""
+    f = factors.astype(np.float64)
+    k = f.shape[1]
+    out = np.zeros((rows, k))
+    for r in range(rows):
+        sel = (local == r) & (mask > 0)
+        Y, rr = f[other[sel]], rating[sel].astype(np.float64)
+        ridge = (reg * max(int(sel.sum()), 1) + 1e-6) * np.eye(k)
+        if alpha is None:
+            A, b = Y.T @ Y + ridge, Y.T @ rr
+        else:
+            c1 = alpha * rr
+            A = f.T @ f + (Y * c1[:, None]).T @ Y + ridge
+            b = Y.T @ (1.0 + c1)
+        out[r] = np.linalg.solve(A, b)
+    return out
+
+
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+@pytest.mark.parametrize("k", [1, 10, 14, 15, 32])
+def test_half_step_matches_float64_row_solves(k, implicit):
+    """W = K(K+3)/2 is 2, 65, 119 (under 128 lanes), 135 (over) and 560."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als as als_ops
+
+    rows, local, other, rating, mask, factors = _half_step_case(k)
+    reg, alpha = 0.1, 1.5
+    assert als_ops._packed_width(k) == k * (k + 3) // 2
+    lam = als_ops._row_ridge(jnp.asarray(local), jnp.asarray(mask), rows, reg)
+    if implicit:
+        got = als_ops._half_step_implicit(
+            jnp.asarray(factors), jnp.asarray(factors.T @ factors), local,
+            other, rating, mask, lam, jnp.float32(alpha))
+    else:
+        got = als_ops._half_step(jnp.asarray(factors), local, other, rating,
+                                 mask, lam)
+    want = _half_step_float64(rows, local, other, rating, mask, factors, reg,
+                              alpha if implicit else None)
+    got = np.asarray(got, np.float64)
+    assert got.shape == (rows, k)
+    if not implicit:
+        assert np.all(got[3] == 0.0)        # an empty row solves to exactly 0
+    # float32's level: 5e-8 (K = 1) to 3e-5 (K = 32, implicit: a Gram of 9
+    # rows at rank 32), digit for digit what the [E, K, K] form read here
+    gap = np.abs(got - want).max() / np.abs(want).max()
+    assert gap < 1e-4, gap
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else [v]):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from _walk(j)
+
+
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+def test_program_has_no_event_by_rank_squared_array_and_counts_once(implicit):
+    """What keeps the [E, K, K] outer products from coming back: in the jaxpr
+    of the whole program nothing is as large as E·K·K, a sweep's body holds
+    one segment reduction a half-step, over packed rows, and the constant
+    ratings-per-row counts are reduced before the loop, not in it."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als as als_ops
+
+    k = 10
+    u, i, r, _, _ = synthetic_ratings(n_users=40, n_items=30, density=0.4)
+    data = prepare_als_data(u, i, r, 40, 30, dp=1)
+    e = data.u_mask.shape[1]
+    x0, y0 = als_ops._als_init(data, k, 0)
+    program = functools.partial(als_ops._als_run_single, implicit=implicit)
+    jaxpr = jax.make_jaxpr(program)(
+        x0, y0, jnp.int32(3), jnp.float32(0.1), jnp.float32(1.0),
+        *als_ops._als_device_args(data)).jaxpr
+    eqns = list(_walk(jaxpr))
+
+    largest = max(int(np.prod(v.aval.shape)) for q in eqns for v in q.outvars
+                  if hasattr(v.aval, "shape"))
+    assert largest == e * als_ops._packed_width(k)      # the packed rows
+    assert largest < e * k * k
+
+    (loop,) = [q for q in eqns if q.primitive.name == "while"]
+    body = list(_walk(loop.params["body_jaxpr"].jaxpr))
+    in_body = {id(q) for q in body}
+
+    def reductions(some):
+        # (operand shape, updates shape) of each scatter-add
+        return [(q.invars[0].aval.shape, q.invars[2].aval.shape)
+                for q in some if q.primitive.name == "scatter-add"]
+
+    w = als_ops._packed_width(k)
+    assert sorted(reductions(body)) == sorted(
+        [((data.user_rows, w), (e, w)), ((data.item_rows, w), (e, w))])
+    # the counts: one reduction of each layout's mask, outside the loop
+    outside = reductions(q for q in eqns if id(q) not in in_body)
+    assert sorted(outside) == sorted(
+        [((data.user_rows,), (e,)), ((data.item_rows,), (e,))])
+
+
+@pytest.mark.parametrize("dp", [1, 8], ids=["single", "sharded"])
+def test_dispatch_span_says_which_program_shape_ran(dp, tmp_path):
+    from predictionio_tpu.obs import spans as obs_spans
+    from predictionio_tpu.ops import als as als_ops
+
+    u, i, r, _, _ = synthetic_ratings(n_users=33, n_items=17)
+    data = prepare_als_data(u, i, r, 33, 17, dp=dp)
+    mesh = create_mesh(MeshSpec(dp=dp, mp=1)) if dp > 1 else None
+    with obs_spans.SpanJournal(tmp_path / "als.jsonl").activate():
+        als_train(data, k=6, reg=0.05, iterations=2, mesh=mesh)
+    (dispatch,) = [s for s in obs_spans.recent_runs()[-1]
+                   if s["name"] == "dispatch"
+                   and s["attrs"]["program"].startswith("_als_run_")]
+    assert dispatch["attrs"]["program"] == (
+        "_als_run_sharded" if dp > 1 else "_als_run_single")
+    assert dispatch["attrs"]["packed_width"] == als_ops._packed_width(6) == 27
+    assert dispatch["attrs"]["events"] == data.u_mask.shape[1]
+
+
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+def test_one_device_runs_a_sharded_layout_as_the_mesh_does(implicit):
+    """The same dp = 8 layout and start on one device (shard s's row r as row
+    s·rows + r of one shard) and on the mesh: the same factors."""
+    u, i, r, _, _ = synthetic_ratings(n_users=33, n_items=17)
+    if implicit:
+        r = np.abs(r)
+    data = prepare_als_data(u, i, r, 33, 17, dp=8)
+    kw = dict(k=5, reg=0.05, iterations=4, implicit=implicit, alpha=1.5)
+    x1, y1 = als_train(data, **kw)
+    x8, y8 = als_train(data, mesh=create_mesh(MeshSpec(dp=8, mp=1)), **kw)
+    assert np.abs(x1 - x8).max() < 1e-4 * np.abs(x8).max()
+    assert np.abs(y1 - y8).max() < 1e-4 * np.abs(y8).max()
