@@ -4,8 +4,7 @@
 Drives the README quick-start path once, through the entry points a user
 would call, at the full width of one model the repo supports — Universal
 Recommender, 20,000 users x 100,000 items, 400,000 `buy` + 800,000 `view`
-events generated from a seed, maxCorrelatorsPerItem 50 (the shape
-`bench.py`'s http section uses in full mode):
+events generated from a seed, maxCorrelatorsPerItem 50:
 
     pio status -> pio app new -> pio import -> pio train -> pio train again
     (the second process must find the first one's compile cache) ->
@@ -172,9 +171,8 @@ class Smoke:
         return device
 
     def make_events(self) -> dict:
-        """Seeded commerce events, the generator bench.py's http section
-        uses: every item bought at least once (so the catalog IS n_items
-        wide), the rest Zipf-popular."""
+        """Seeded commerce events: every item bought at least once (so
+        the catalog IS n_items wide), the rest Zipf-popular."""
         s = self.shape
         rng = np.random.default_rng(3)
         nu, ni, nb, nv = s["n_users"], s["n_items"], s["n_buy"], s["n_view"]

@@ -1221,10 +1221,10 @@ class URAlgorithm(Algorithm):
                 f"blacklist_events {unknown} not in event_names {td.event_names}")
         dp = self.params.mesh_dp or len(jax.devices())
         mesh = create_mesh(MeshSpec(dp=dp, mp=1)) if dp > 1 else None
-        # one staged-primary pass over all event types: the primary uploads
-        # once, device work for type t overlaps host layout of type t+1, and
-        # no host dedup runs anywhere (cco_train_indicators dedups on device
-        # via its scatter-max densify)
+        # one call for all event types: cco_train_indicators plans each
+        # type's strategy, its dense and host-sparse runners stage the
+        # primary once, and no host dedup runs on a device strategy (the
+        # scatter-max densify is the dedup)
         others = []
         event_item_dicts: Dict[str, IdDict] = {}
         for name in td.event_names:

@@ -17,11 +17,15 @@ Recommender').  TPU-first re-expression (SURVEY.md §7.5):
   (bf16 inputs by default; int8 — 2× MXU rate on v5e — via
   PIO_CCO_MM_DTYPE once measured faster).  ``lax.scan`` over chunks keeps
   it one compiled program.
-- Training runs **all event types against one staged primary**:
-  ``cco_train_indicators`` lays out and uploads the primary once, then
-  dispatches each event type's counts+LLR+top-k asynchronously — host
-  layout of event type t+1 overlaps device compute of event type t, and
-  results download once at the end.
+- Every strategy takes what the engines have: COO pairs.  ``_plan``
+  decides the strategy of an event type once, from the sizes, the backend
+  and the mesh, before any host layout; ``cco_train_indicators`` (all of
+  a train's event types) and ``cco_indicators_coo`` (one) run it.  The
+  dense and host-sparse runners stage the primary once a train and
+  dispatch each event type asynchronously — host layout of event type
+  t+1 overlaps device compute of event type t, results download at the
+  end; the tiled strategies stage and densify it once per event type and
+  wait for each.
 - Huge item catalogs take the tiled path: item columns are processed in
   tiles, each tile's LLR scores merging into a running per-row top-k
   (concat + ``lax.top_k``), so the full I_p×I_t count matrix is never
@@ -57,7 +61,8 @@ from predictionio_tpu.obs.spans import span
 
 @dataclasses.dataclass
 class BlockedInteractions:
-    """COO pairs grouped into fixed-size user blocks, padded to equal length.
+    """COO pairs grouped into fixed-size user blocks, padded to equal length:
+    the layout of the chunked tiled strategy, and of no other.
 
     local_u[b, e] is the in-block user row, item[b, e] the item id, for the
     first count[b] slots of block b; the slots after them are padding (0).
@@ -90,13 +95,9 @@ def block_interactions(
     n_items: int,
     user_block: int = 1024,
     pad_multiple: int = 8,
-    dedup: bool = False,
 ) -> BlockedInteractions:
-    """Group raw COO by user block.  ``dedup`` is optional and OFF by
-    default — device consumers dedup by construction (scatter-max densify);
-    it only shrinks the padded width when the data is heavily duplicated."""
-    if dedup:
-        user, item = dedup_pairs(user, item, n_items)
+    """Group raw COO by user block.  No dedup: the device consumer dedups
+    by construction (scatter-max densify)."""
     user = np.asarray(user, np.int32)
     item = np.asarray(item, np.int32)
     n_blocks = max(math.ceil(n_users / user_block), 1)
@@ -160,12 +161,6 @@ def block_interactions_stream(
                                n_users, n_items, user_block)
 
 
-def interaction_counts(item: np.ndarray, n_items: int) -> np.ndarray:
-    """Distinct-user count per item (column counts for the LLR table).
-    Caller must pass dedup'd items; prefer the device-side marginals."""
-    return np.bincount(item, minlength=n_items).astype(np.float32)
-
-
 def dedup_pairs(user: np.ndarray, item: np.ndarray, n_items: int):
     """Dedup (user, item) pairs — CCO is binary occurrence.  Host-side
     O(E log E); the training hot path no longer calls this (device
@@ -176,12 +171,6 @@ def dedup_pairs(user: np.ndarray, item: np.ndarray, n_items: int):
         return user.astype(np.int32), item.astype(np.int32)
     flat = np.unique(user * n_items + item)
     return (flat // n_items).astype(np.int32), (flat % n_items).astype(np.int32)
-
-
-def distinct_user_counts(user: np.ndarray, item: np.ndarray, n_items: int) -> np.ndarray:
-    """Distinct users per item, straight from raw COO."""
-    _, di = dedup_pairs(user, item, n_items)
-    return interaction_counts(di, n_items)
 
 
 # ---------------------------------------------------------------------------
@@ -324,9 +313,8 @@ def _matmul_dtype() -> str:
     """'bf16' (default) or 'int8' via PIO_CCO_MM_DTYPE.
 
     Both are exact for 0/1 inputs.  int8 runs the v5e MXU at 2× the bf16
-    rate on paper, but XLA CPU lowers s8 GEMMs ~6× SLOWER than bf16
-    (measured with profile_tpu.py), so int8 stays opt-in until the real
-    chip confirms the MXU lowering wins."""
+    rate on paper; no chip run has timed it (ROADMAP S3), so int8 stays
+    opt-in until one shows the MXU lowering wins."""
     conf = _os.environ.get("PIO_CCO_MM_DTYPE", "bf16").lower()
     return conf if conf in ("int8", "bf16") else "bf16"
 
@@ -363,12 +351,17 @@ def _mm_in_dtype():
     return jnp.int8 if _matmul_dtype() == "int8" else jnp.bfloat16
 
 
+def _pad128(n: int) -> int:
+    """``n`` rounded up to whole 128-wide tiles, at least one."""
+    return max(((n + 127) // 128) * 128, 128)
+
+
 # ---------------------------------------------------------------------------
 # P-resident tiled path (huge catalogs, but the densified primary fits HBM)
 # ---------------------------------------------------------------------------
 
-# Budget for the P-resident program's plan (see _resident_p_ok for what the
-# plan counts): three quarters of a 16 GB v5e.  The quarter left over is for
+# Budget for the P-resident program's plan (see _plan for what the plan
+# counts): three quarters of a 16 GB v5e.  The quarter left over is for
 # what the plan does not count: the COO arrays and the top-k carry of the
 # event type in flight, the results of the one before it, and the
 # allocator's own slack.
@@ -450,39 +443,17 @@ def _cco_resident_all_tiles(
                        carry_k=_carry_width(top_k, topk))
 
 
-def _resident_p_ok(n_users: int, n_items_p: int, item_tile: int = 4096) -> bool:
-    """The P-resident strategy is used only when the program's plan fits
-    the budget, AND counts stay exact: bf16 contracts the full user space
-    in one f32 pass, so n_users must stay below 2²⁴ (int8 accumulates int32
-    and has no such cap).
-
-    The plan is what the compiler holds for ``_cco_resident_all_tiles``:
-    the densified primary as an argument, and per tile the densified slab
-    of the other type, the float32 count tile and the float32 scores made
-    from it.  At 32,768 × 100,000, tile 4,096, bf16 that is 6.55 + 0.27 +
-    2 × 1.64 = 10.10 GB; the TPU compiler plans 6.11 GiB of arguments +
-    3.20 GiB of temporaries = 10.0 GB there [AOT, PR 25] and the chip's
-    peak read 10.08 GB (chip run, PR 25)."""
-    bytes_per = 2 if _matmul_dtype() == "bf16" else 1
-    n_rows = max(((n_users + 127) // 128) * 128, 128)
-    plan = (n_rows * n_items_p + n_rows * item_tile) * bytes_per \
-        + 2 * n_items_p * item_tile * 4
-    if plan > _TILED_P_BYTES:
-        return False
-    return _matmul_dtype() == "int8" or n_users < (1 << 24)
-
-
-def _cco_indicators_resident(
-    primary: BlockedInteractions,
-    other: BlockedInteractions,
-    n_total_users: int, top_k: int, llr_threshold: float,
-    item_tile: int, exclude_self: bool,
+def _cco_resident(
+    pu: np.ndarray, pi: np.ndarray, au: np.ndarray, ai: np.ndarray,
+    n_users: int, n_items_p: int, n_items_t: int,
+    top_k: int, llr_threshold: float, item_tile: int, exclude_self: bool,
 ) -> Tuple[np.ndarray, np.ndarray]:
+    """One event type on the P-resident program, from the pairs as the
+    engine has them: the int32 casts are all the host layout there is."""
     with span("layout"):
-        pu, pi = _flatten_blocked(primary)
-        au, ai = _flatten_blocked(other) if other is not primary else (pu, pi)
-    n_items_p, n_items_t = primary.n_items, other.n_items
-    n_rows = max(((primary.n_users + 127) // 128) * 128, 128)
+        pu, pi = np.asarray(pu, np.int32), np.asarray(pi, np.int32)
+        au, ai = np.asarray(au, np.int32), np.asarray(ai, np.int32)
+    n_rows = _pad128(n_users)
     mm = _matmul_dtype()
     with span("h2d", bytes=pu.nbytes + pi.nbytes):
         p_gu, p_gi = jnp.asarray(pu), jnp.asarray(pi)
@@ -501,7 +472,7 @@ def _cco_indicators_resident(
     topk = topk_impl()
     with span("dispatch", program="_cco_resident_all_tiles", topk=topk):
         best_scores, best_idx = _cco_resident_all_tiles(
-            P, rc, a_gu, a_gi, a_valid, float(n_total_users),
+            P, rc, a_gu, a_gi, a_valid, float(n_users),
             n_tiles=n_tiles, tile=tile, top_k=top_k,
             llr_threshold=float(llr_threshold),
             exclude_self=exclude_self, pallas=pallas_mode(), mm=mm,
@@ -634,13 +605,6 @@ _DENSE_CHUNK_BYTES = 1 << 30   # per-chunk densified P+A budget
 _DENSE_C_BYTES = 2 << 30       # full count-matrix budget (4-byte accum)
 
 
-def _flatten_blocked(b: BlockedInteractions) -> Tuple[np.ndarray, np.ndarray]:
-    """Blocked layout → global COO (inverse of block_interactions)."""
-    gu = (np.arange(b.n_blocks, dtype=np.int64)[:, None] * b.user_block + b.local_u)
-    keep = b.mask.ravel()
-    return gu.ravel()[keep].astype(np.int32), b.item.ravel()[keep].astype(np.int32)
-
-
 def _dense_chunk_users(n_items_p: int, it_pad: int, n_users: int, dp: int = 1) -> int:
     """Chunk size minimizing padded-user waste: pick the number of chunks
     the HBM budget forces (×dp for sharding), then split users evenly —
@@ -704,16 +668,15 @@ def _cco_counts_dense(
     return C, rc, cc
 
 
-@partial(jax.jit, static_argnames=("top_k", "exclude_self", "pallas", "topk"))
+@partial(jax.jit, static_argnames=("top_k", "exclude_self", "pallas"))
 def _llr_topk_dense(
     C, rc, cc, n_total, llr_threshold,
-    top_k: int, exclude_self: bool, pallas: str, topk: str = "lax",
+    top_k: int, exclude_self: bool, pallas: str,
 ):
-    """LLR + whole-row top-k over the full count matrix.  Every caller
-    leaves ``topk`` at ``lax`` on every backend: a row here is the whole
-    target catalogue, which ``tile_topk_desc`` would pad to a power of two
-    and unroll over, and nothing has compiled or timed that for a TPU at
-    a dense-path width; ``topk='pallas'`` is the parity test's."""
+    """LLR + whole-row top-k over the full count matrix: ``lax.top_k`` on
+    every backend.  A row here is the whole target catalogue, which the
+    tiled merge's ``tile_topk_desc`` would pad to a power of two and
+    unroll over."""
     scores = _llr_mask_scores(
         C.astype(jnp.float32), rc.astype(jnp.float32), cc.astype(jnp.float32),
         n_total, llr_threshold, pallas)
@@ -722,12 +685,6 @@ def _llr_topk_dense(
         eye = jnp.arange(n_p, dtype=jnp.int32)[:, None] == jnp.arange(
             n_t, dtype=jnp.int32)[None, :]
         scores = jnp.where(eye, -jnp.inf, scores)
-    if topk == "pallas":
-        from predictionio_tpu.ops.pallas_kernels import tile_topk_desc
-        from predictionio_tpu.ops.topk import block_width
-
-        bs, bi = tile_topk_desc(scores, block_width(top_k))
-        return bs[:, :top_k], bi[:, :top_k]
     best_scores, best_idx = jax.lax.top_k(scores, top_k)
     return best_scores, best_idx.astype(jnp.int32)
 
@@ -777,16 +734,6 @@ def _stage_chunked(
         return _StagedCOO(put(lu), put(it), put(counts))
 
 
-def _dense_path_ok(n_items_p: int, n_items_t: int) -> bool:
-    conf = _os.environ.get("PIO_CCO_DENSE", "auto").lower()
-    if conf in ("0", "off", "false"):
-        return False
-    if conf in ("1", "on", "true"):
-        return True
-    it_pad = max(((n_items_t + 127) // 128) * 128, 128)
-    return n_items_p * it_pad * 4 <= _DENSE_C_BYTES
-
-
 # ---------------------------------------------------------------------------
 # host sparse-count path (CPU backend, low-density workloads)
 # ---------------------------------------------------------------------------
@@ -808,21 +755,6 @@ _SPARSE_BINCOUNT_CELLS = 16 << 20
 # this pair count the tail falls back to one flatnonzero scan of C
 # (O(cells), bounded by the 512 MB C budget) instead of collecting.
 _SPARSE_COO_PAIRS = 32_000_000   # ~0.25 GB int64 + transient ≈ C budget
-
-
-def _sparse_path_ok() -> bool:
-    """The host sparse-count strategy is a CPU-backend specialization: at
-    low occupancy (events ≪ users×items) the densified count matmul does
-    O(U·I_p·I_t) work for O(E) information — measured 25× slower than a
-    host bincount at the reduced bench shape (4k users, 5k items, 120k
-    events).  On TPU the MXU inverts the comparison, so auto never picks
-    this path there."""
-    conf = _os.environ.get("PIO_CCO_SPARSE", "auto").lower()
-    if conf in ("0", "off", "false"):
-        return False
-    if conf in ("1", "on", "true"):
-        return True
-    return jax.default_backend() != "tpu"
 
 
 class _SparseHostCSR:
@@ -1027,9 +959,9 @@ def _llr_topk_sparse_host(C, rc, cc, n_total, llr_threshold,
     carry no information), then per-row top-k on host via one lexsort.
 
     At the low occupancies this path serves (events ≪ users·items, e.g.
-    ~0.6% at the bench shape) the dense [I_p, I_t] LLR + lax.top_k tail
-    does ~99% wasted work on CPU; this is O(nnz) scoring + O(nnz·log nnz)
-    selection.  Output is bit-identical to _llr_topk_dense: scores come
+    ~0.6% at 4k users, 5k items, 120k events) the dense [I_p, I_t] LLR +
+    lax.top_k tail does ~99% wasted work on CPU; this is O(nnz) scoring +
+    O(nnz·log nnz) selection.  Output is bit-identical to _llr_topk_dense: scores come
     from the same jitted elementwise chain, and ties at equal scores pick
     the smaller column index — exactly lax.top_k's stable order.
 
@@ -1110,27 +1042,12 @@ def _sparse_counts_coo(p: _SparseHostCSR, a: _SparseHostCSR,
     return cells[starts], summed.astype(np.int32)
 
 
-def _sparse_tail() -> str:
-    """'auto' (default) | 'host' | 'device' via PIO_CCO_SPARSE_TAIL.
-
-    auto picks per event type by pair density (see dispatch): the host
-    tail's cost scales with the nonzero cells, the device tail's with ALL
-    cells, and the measured crossover on this class of host is at
-    pairs/cells ≈ 0.25 (sweep in PERF.md round 5)."""
-    conf = _os.environ.get("PIO_CCO_SPARSE_TAIL", "auto").lower()
-    if conf in ("device", "dense"):
-        return "device"
-    if conf == "host":
-        return "host"
-    return "auto"
-
-
 class _SparseHostRunner:
     """Host-count twin of _DenseRunner: same dispatch/collect contract,
-    and a bit-identical tail — sparse host LLR/top-k by default (same
-    elementwise scores, same tie order as the device tail), or the device
-    _llr_topk_dense via PIO_CCO_SPARSE_TAIL=device.  Only the count
-    production ever differs from the dense strategy: it never does.
+    and a bit-identical tail — the sparse host LLR/top-k (same elementwise
+    scores, same tie order as the device tail) or the device
+    _llr_topk_dense, chosen per event type by pair density.  Only the
+    count production ever differs from the dense strategy: it never does.
     dispatch returns None when budgets say 'use the device'.
 
     Two count representations: the dense host matrix (original path,
@@ -1140,10 +1057,8 @@ class _SparseHostRunner:
     cells (``_sparse_counts_coo`` + ``_llr_topk_sparse_rows``), making
     million-item CPU training O(nnz + I·K) instead of impossible."""
 
-    def __init__(self, p_user, p_item, n_users: int, n_items_p: int,
-                 n_total_users: Optional[int] = None):
+    def __init__(self, p_user, p_item, n_users: int, n_items_p: int):
         self.n_users = n_users
-        self.n_total_users = n_total_users if n_total_users else n_users
         self.n_items_p = n_items_p
         with span("layout"):
             self.p = _SparseHostCSR(p_user, p_item, n_items_p, n_users)
@@ -1162,26 +1077,28 @@ class _SparseHostRunner:
                      if exclude_self else None)
         s, i = _llr_topk_sparse_rows(
             rows, cols, counts, self.p.col_counts, a.col_counts,
-            float(self.n_total_users), float(llr_threshold),
+            float(self.n_users), float(llr_threshold),
             top_k=top_k, n_rows=self.n_items_p, n_cols=n_items_t,
             self_cols=self_cols)
         return s, i, n_items_t, top_k
 
     def dispatch(self, a_user, a_item, n_items_t: int, top_k: int,
                  llr_threshold: float, exclude_self: bool,
-                 self_pair: bool = False):
+                 self_pair: bool = False, tail: Optional[str] = None):
+        """``tail`` ('host' | 'device') is for the test that holds the two
+        tails to each other; left None it follows the pair density."""
         # the counting, and the host tail where it is taken, are the
         # host's own compute: one span
         with span("host_compute"):
             a = self.p if self_pair else _SparseHostCSR(
                 a_user, a_item, n_items_t, self.n_users)
             pairs = _cross_join_pairs(self.p, a)
-            tail = _sparse_tail()
-            if tail == "auto":
-                # nnz ≤ total cross-join pairs, so pairs/cells bounds the
-                # occupancy the host tail would have to sort; past ~0.25
-                # the dense device tail is the better deal (measured
-                # crossover)
+            if tail is None:
+                # the host tail's cost scales with the nonzero cells, the
+                # device tail's with ALL cells.  nnz ≤ total cross-join
+                # pairs, so pairs/cells bounds the occupancy the host tail
+                # would have to sort; past ~0.25 the dense device tail is
+                # the better deal (the crossover a CPU sweep found)
                 tail = "host" if pairs * 4 < self.n_items_p * n_items_t \
                     else "device"
             host_tail = tail == "host"
@@ -1198,7 +1115,7 @@ class _SparseHostRunner:
                 C, flat = got
                 s, i = _llr_topk_sparse_host(
                     C, self.p.col_counts, a.col_counts,
-                    float(self.n_total_users), float(llr_threshold),
+                    float(self.n_users), float(llr_threshold),
                     top_k=top_k, exclude_self=bool(exclude_self), flat=flat)
                 return s, i, n_items_t, top_k
         # imported here, not at dispatch entry: the pallas machinery
@@ -1213,17 +1130,12 @@ class _SparseHostRunner:
         with span("dispatch", program="_llr_topk_dense"):
             s, i = _llr_topk_dense(
                 C_d, rc_d, cc_d,
-                float(self.n_total_users), float(llr_threshold),
+                float(self.n_users), float(llr_threshold),
                 top_k=min(top_k, C.shape[1]),
                 exclude_self=bool(exclude_self),
                 pallas=pallas_mode(),
             )
         return s, i, n_items_t, top_k
-
-    @staticmethod
-    def collect(dispatched) -> Tuple[np.ndarray, np.ndarray]:
-        return _DenseRunner.collect(dispatched)
-
 
 class _DenseRunner:
     """Stages a primary event type once and runs per-event-type dense CCO
@@ -1289,7 +1201,7 @@ class _DenseRunner:
             it_pad = self.n_items_p
             a = self.p
         else:
-            it_pad = max(((n_items_t + 127) // 128) * 128, 128)
+            it_pad = _pad128(n_items_t)
             a = _stage_chunked(a_user, a_item,
                                self.chunk, self.n_chunks, self.sharding)
         with span("dispatch", program="_cco_counts_dense"):
@@ -1317,6 +1229,74 @@ class _DenseRunner:
         return scores, idx
 
 
+# ---------------------------------------------------------------------------
+# the plan, and the two ways in
+# ---------------------------------------------------------------------------
+
+
+def _switch(name: str) -> Optional[bool]:
+    """An on/off environment switch; None when unset or 'auto'."""
+    conf = _os.environ.get(name, "auto").lower()
+    if conf in ("1", "on", "true"):
+        return True
+    if conf in ("0", "off", "false"):
+        return False
+    return None
+
+
+def _plan(n_users: int, n_items_p: int, n_items_t: int,
+          mesh: Optional[Mesh], item_tile: int) -> Tuple[str, ...]:
+    """The strategies one event type tries, in order: ``host_sparse``
+    where it applies (it may decline from the data: its dispatch returns
+    None when its budgets say 'use the device'), then the one device
+    strategy that always answers.  Decided here and nowhere else, from
+    the sizes, the backend and the mesh, before any host layout.
+
+    - ``host_sparse`` (one device; PIO_CCO_SPARSE): a CPU-backend
+      specialization.  At low occupancy (events ≪ users×items) the
+      densified count matmul does O(U·I_p·I_t) work for O(E) information:
+      25× slower on a CPU than a host bincount at 4k users, 5k items, 120k
+      events.  On a TPU the MXU inverts the comparison, so auto never
+      picks it there.
+    - ``dense`` (PIO_CCO_DENSE): the full I_p×I_t 32-bit count matrix fits
+      ``_DENSE_C_BYTES``.
+    - ``resident`` (one device): tiled over items with the densified
+      primary kept in HBM, when the program's plan fits ``_TILED_P_BYTES``
+      and counts stay exact: bf16 contracts the full user space in one f32
+      pass, so n_users must stay below 2²⁴ (int8 accumulates int32 and has
+      no such cap).  The plan is what the compiler holds for
+      ``_cco_resident_all_tiles``: the densified primary as an argument,
+      and per tile the densified slab of the other type, the float32 count
+      tile and the float32 scores made from it.  At 32,768 × 100,000, tile
+      4,096, bf16 that is 6.55 + 0.27 + 2 × 1.64 = 10.10 GB; the TPU
+      compiler plans 6.11 GiB of arguments + 3.20 GiB of temporaries =
+      10.0 GB there [AOT, PR 25] and the chip's peak read 10.08 GB (chip
+      run, PR 25).
+    - ``chunked``: tiled over items, the primary re-densified per user
+      block and tile; whatever is left."""
+    host: Tuple[str, ...] = ()
+    if mesh is None:
+        sparse = _switch("PIO_CCO_SPARSE")
+        if sparse is None:
+            sparse = jax.default_backend() != "tpu"
+        if sparse:
+            host = ("host_sparse",)
+    dense = _switch("PIO_CCO_DENSE")
+    if dense is None:
+        dense = n_items_p * _pad128(n_items_t) * 4 <= _DENSE_C_BYTES
+    if dense:
+        return host + ("dense",)
+    if mesh is None:
+        int8 = _matmul_dtype() == "int8"
+        n_rows = _pad128(n_users)
+        tile = min(item_tile, max(n_items_t, 1))
+        plan = (n_rows * n_items_p + n_rows * tile) * (1 if int8 else 2) \
+            + 2 * n_items_p * tile * 4
+        if plan <= _TILED_P_BYTES and (int8 or n_users < (1 << 24)):
+            return host + ("resident",)
+    return host + ("chunked",)
+
+
 def cco_train_indicators(
     p_user: np.ndarray, p_item: np.ndarray,
     others: Sequence[Tuple[str, np.ndarray, np.ndarray, int]],
@@ -1330,93 +1310,80 @@ def cco_train_indicators(
     per_type: Optional[Dict[str, Tuple[int, float]]] = None,
 ) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
     """The UR train loop's entry: indicators for every event type against
-    ONE staged primary.
+    one primary, each under the strategy ``_plan`` gives it.
 
     ``others`` is an ordered list of ``(name, a_user, a_item, n_items_t)``;
     pass the primary's own name/arrays for the self-indicator (detected by
-    array identity, which skips the second densify).  The primary is laid
-    out and uploaded once; each event type's device work is dispatched
-    asynchronously so host layout of type t+1 overlaps device compute of
-    type t.  Event types whose count matrix exceeds the HBM budget fall
-    back to the tiled path transparently.
+    array identity, which lets the runners skip the second layout).  The
+    dense and host-sparse runners lay out and upload the primary once for
+    all event types and dispatch asynchronously, so host layout of type
+    t+1 overlaps device compute of type t.  The tiled strategies (count
+    matrix past the HBM budget) upload and densify the primary once per
+    event type and wait for each result before the next.
 
     ``per_type`` optionally overrides ``(top_k, llr_threshold)`` for named
     event types (reference UR: per-indicator maxCorrelatorsPerItem/minLLR).
+
+    Returns ``{name: (scores [I_p, top_k], indices [I_p, top_k])}``;
+    entries with score == -inf (index -1) are padding: fewer than top_k
+    significant correlators.  Every strategy derives the LLR marginals
+    from the interactions themselves.
     """
     per_type = per_type or {}
-    dense_names = [nm for nm, _, _, nt in others if _dense_path_ok(n_items_p, nt)]
-    sparse_runner: Optional[_SparseHostRunner] = None
-    if mesh is None and _sparse_path_ok():
-        sparse_runner = _SparseHostRunner(p_user, p_item, n_users, n_items_p)
-    runner: Optional[_DenseRunner] = None
+    plans = {nt: _plan(n_users, n_items_p, nt, mesh, item_tile)
+             for _, _, _, nt in others}
+    runners: Dict[str, object] = {}
 
-    def dense_runner() -> _DenseRunner:
-        nonlocal runner
-        if runner is None:
-            it_pad_max = max(
-                max(((nt + 127) // 128) * 128, 128)
-                for nm, _, _, nt in others if nm in dense_names
-            )
-            it_pad_max = max(it_pad_max, n_items_p)
-            runner = _DenseRunner(p_user, p_item, n_users, n_items_p,
-                                  it_pad_max, mesh)
-        return runner
+    def runner(strategy: str):
+        """The primary staged for a runner, once, when a plan first asks."""
+        if strategy not in runners:
+            staged = (p_user, p_item, n_users, n_items_p)
+            if strategy == "host_sparse":
+                runners[strategy] = _SparseHostRunner(*staged)
+            else:       # user chunks sized for the widest dense event type
+                it_pad_max = max([n_items_p] + [
+                    _pad128(nt) for nt, plan in plans.items()
+                    if "dense" in plan])
+                runners[strategy] = _DenseRunner(*staged, it_pad_max, mesh)
+        return runners[strategy]
 
     pending: List[Tuple[str, object]] = []
     results: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
     for name, au, ai, n_items_t in others:
         excl = (name == exclude_self_for)
         t_k, t_llr = per_type.get(name, (top_k, llr_threshold))
-        self_pair = au is p_user and ai is p_item
-        if sparse_runner is not None:
-            d = sparse_runner.dispatch(au, ai, n_items_t, t_k, t_llr, excl,
-                                       self_pair=self_pair)
-            if d is not None:
+        for strategy in plans[n_items_t]:
+            if strategy in ("host_sparse", "dense"):
+                # strict identity only: anything weaker (shape/overlap
+                # heuristics) could silently alias two distinct event types
+                d = runner(strategy).dispatch(
+                    au, ai, n_items_t, t_k, t_llr, excl,
+                    self_pair=au is p_user and ai is p_item)
+                if d is None:       # host_sparse's budgets: use the device
+                    continue
                 pending.append((name, d))
-                continue
-        if dense_names and name in dense_names:
-            pending.append((name, dense_runner().dispatch(
-                au, ai, n_items_t, t_k, t_llr, excl,
-                self_pair=self_pair)))
-        else:
-            results[name] = cco_indicators_coo(
-                p_user, p_item, au, ai, n_users, n_items_p, n_items_t,
-                top_k=t_k, llr_threshold=t_llr,
-                user_block=user_block, item_tile=item_tile,
-                mesh=mesh, exclude_self=excl,
-            )
+            elif strategy == "resident":
+                results[name] = _cco_resident(
+                    p_user, p_item, au, ai, n_users, n_items_p, n_items_t,
+                    t_k, t_llr, item_tile, excl)
+            else:
+                with span("layout") as rec:
+                    p = block_interactions(p_user, p_item, n_users, n_items_p,
+                                           user_block=user_block)
+                    a = block_interactions(au, ai, n_users, n_items_t,
+                                           user_block=user_block)
+                    slots = p.local_u.size + a.local_u.size
+                    rec["attrs"] = {
+                        "user_blocks": p.n_blocks, "slots": slots,
+                        "pad_slots": (slots - int(p.count.sum())
+                                      - int(a.count.sum()))}
+                results[name] = _cco_chunked(
+                    p, a, n_users, top_k=t_k, llr_threshold=t_llr,
+                    item_tile=item_tile, mesh=mesh, exclude_self=excl)
+            break
     for name, d in pending:
         results[name] = _DenseRunner.collect(d)
     return results
-
-
-def _cco_indicators_dense_coo(
-    pu: np.ndarray, pi: np.ndarray,
-    au: np.ndarray, ai: np.ndarray,
-    n_users: int, n_items_p: int, n_items_t: int,
-    top_k: int,
-    llr_threshold: float,
-    mesh: Optional[Mesh],
-    exclude_self: bool,
-    n_total_users: Optional[int] = None,
-) -> Tuple[np.ndarray, np.ndarray]:
-    # strict identity only: anything weaker (shape/overlap heuristics) could
-    # silently alias two distinct event types
-    self_pair = au is pu and ai is pi
-    if mesh is None and _sparse_path_ok():
-        sr = _SparseHostRunner(pu, pi, n_users, n_items_p,
-                               n_total_users=n_total_users)
-        d = sr.dispatch(au, ai, n_items_t, top_k, llr_threshold, exclude_self,
-                        self_pair=self_pair)
-        if d is not None:
-            return _SparseHostRunner.collect(d)
-    it_pad = max(((n_items_t + 127) // 128) * 128, 128)
-    runner = _DenseRunner(pu, pi, n_users, n_items_p,
-                          max(it_pad, n_items_p), mesh,
-                          n_total_users=n_total_users)
-    d = runner.dispatch(au, ai, n_items_t, top_k, llr_threshold, exclude_self,
-                        self_pair=self_pair)
-    return _DenseRunner.collect(d)
 
 
 def cco_indicators_coo(
@@ -1429,37 +1396,19 @@ def cco_indicators_coo(
     item_tile: int = 4096,
     mesh: Optional[Mesh] = None,
     exclude_self: bool = False,
-    primary_deduped: bool = False,
-    other_deduped: bool = False,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """``cco_indicators`` from raw (user, item) COO pairs — single event
-    type.  Training should prefer ``cco_train_indicators`` (stages the
-    primary once across event types).  ``primary_deduped``/``other_deduped``
-    are accepted for compatibility and ignored: neither device path needs
-    pre-dedup'd pairs anymore.
-    """
-    del primary_deduped, other_deduped  # device scatter-max dedups
-    if _dense_path_ok(n_items_p, n_items_t):
-        return _cco_indicators_dense_coo(
-            p_user, p_item, a_user, a_item, n_users, n_items_p, n_items_t,
-            top_k, llr_threshold, mesh, exclude_self,
-        )
-    with span("layout") as rec:
-        p = block_interactions(p_user, p_item, n_users, n_items_p,
-                               user_block=user_block)
-        a = block_interactions(a_user, a_item, n_users, n_items_t,
-                               user_block=user_block)
-        slots = p.local_u.size + a.local_u.size
-        rec["attrs"] = {
-            "user_blocks": p.n_blocks, "slots": slots,
-            "pad_slots": slots - int(p.count.sum()) - int(a.count.sum())}
-    return cco_indicators(
-        p, a, n_users, top_k=top_k, llr_threshold=llr_threshold,
-        item_tile=item_tile, mesh=mesh, exclude_self=exclude_self,
-    )
+    """``cco_train_indicators`` for a single event type: ``(scores,
+    indices)`` of ``a`` against the primary ``p``.  ``exclude_self=True``
+    masks the diagonal (self-similarity) when both are the same event
+    type."""
+    return cco_train_indicators(
+        p_user, p_item, [("a", a_user, a_item, n_items_t)], n_users,
+        n_items_p, top_k=top_k, llr_threshold=llr_threshold, mesh=mesh,
+        exclude_self_for="a" if exclude_self else None,
+        user_block=user_block, item_tile=item_tile)["a"]
 
 
-def cco_indicators(
+def _cco_chunked(
     primary: BlockedInteractions,
     other: BlockedInteractions,
     n_total_users: int,
@@ -1469,51 +1418,16 @@ def cco_indicators(
     mesh: Optional[Mesh] = None,
     exclude_self: bool = False,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Compute per-primary-item indicator lists against ``other``'s items.
-
-    Returns ``(scores [I_p, top_k], indices [I_p, top_k])``; entries with
-    score == -inf are padding (fewer than top_k significant correlators).
-    ``exclude_self=True`` masks the diagonal (self-similarity) when primary
-    and other are the same event type.
-
-    Two device strategies, selected by memory (override: PIO_CCO_DENSE):
-    - **dense** (default when the full I_p×I_t 32-bit count matrix fits):
-      scan user chunks sized to HBM, densify each chunk to 0/1 and run
-      one MXU matmul per chunk (exact int32 counts), marginals as column
-      sums; then one fused LLR+top-k over the full count matrix.
-    - **tiled** (huge item catalogs): an item-tile loop that never
-      materializes the full count matrix and merges a running top-k,
-      with the densified primary resident where its plan fits
-      (``_resident_p_ok``) and re-densified per user block and tile
-      where it does not; marginals accumulate in the same scan.
-
-    Every strategy derives the LLR marginals from the interactions
-    themselves ON DEVICE (densified matrices are dedup'd by construction).
-    """
+    """The chunked tiled strategy over two blocked layouts: an item-tile
+    loop that never materializes the full count matrix and merges a
+    running top-k, the primary re-densified per user block and tile,
+    marginals accumulated in the same scan.  One compiled program on one
+    device; on a mesh one sharded step a tile, counts ``psum``'d over
+    ``dp``."""
     if n_total_users <= 0:
         raise ValueError(f"n_total_users must be positive, got {n_total_users}")
-    if _dense_path_ok(primary.n_items, other.n_items):
-        if primary.n_users != other.n_users:
-            raise ValueError("primary/other must share the user space")
-        with span("layout"):
-            pu, pi = _flatten_blocked(primary)
-            au, ai = (pu, pi) if other is primary else _flatten_blocked(other)
-        return _cco_indicators_dense_coo(
-            pu, pi, au, ai, primary.n_users, primary.n_items, other.n_items,
-            top_k, llr_threshold, mesh, exclude_self,
-            n_total_users=n_total_users,
-        )
     if primary.n_blocks != other.n_blocks or primary.user_block != other.user_block:
         raise ValueError("primary/other must be blocked with the same user layout")
-    if mesh is None and _resident_p_ok(
-            primary.n_users, primary.n_items,
-            min(item_tile, max(other.n_items, 1))):
-        # tiled over items but with the densified primary RESIDENT in HBM:
-        # avoids re-densifying P for every tile (n_tiles × the work)
-        return _cco_indicators_resident(
-            primary, other, n_total_users, top_k, llr_threshold,
-            item_tile, exclude_self,
-        )
     n_items_p, n_items_t = primary.n_items, other.n_items
     tile = min(item_tile, max(n_items_t, 1))
     n_tiles = math.ceil(n_items_t / tile)

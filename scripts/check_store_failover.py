@@ -53,9 +53,8 @@ def writer_script(root: str, tag: str, n: int, shards: int = SHARDS,
     """A real OS-process writer into the replicated store: each event id
     (``<tag>-<k>``) is printed only AFTER the insert returned — i.e.
     after the semi-sync replication barrier acknowledged it on both
-    nodes.  The ONE copy of the kill-a-primary drill's writer — the
-    bench ``store_failover`` phase and
-    test_multiworker_ingest.py's replicated SIGKILL test import it, so
+    nodes.  The ONE copy of the kill-a-primary drill's writer —
+    test_multiworker_ingest.py's replicated SIGKILL test imports it, so
     ack-contract or layout changes happen in one place."""
     return textwrap.dedent(f"""
         import os
@@ -253,8 +252,8 @@ def phase_partition_mid_scan(root: str, acked_ids: set,
 
 
 def main() -> int:
-    # env mutations live HERE, not at module level: bench.py and the
-    # tests import writer_script without inheriting PIO_FSYNC=always
+    # env mutations live HERE, not at module level: the tests import
+    # writer_script without inheriting PIO_FSYNC=always
     os.environ["PIO_FSYNC"] = "always"
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     problems: list = []

@@ -6,7 +6,8 @@ import pytest
 
 from predictionio_tpu.ops.cco import (
     block_interactions,
-    cco_indicators,
+    cco_indicators_coo,
+    dedup_pairs,
     llr_score,
 )
 from predictionio_tpu.parallel.mesh import MeshSpec, create_mesh
@@ -88,13 +89,15 @@ def test_cco_matches_naive(monkeypatch, user_block, item_tile, program):
     # dedup for the naive side
     C, llr = naive_cco(pu, pi, ou, oi, n_users, n_ip, n_it)
 
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=user_block, dedup=True)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=user_block, dedup=True)
+    p = block_interactions(*dedup_pairs(pu, pi, n_ip), n_users, n_ip,
+                           user_block=user_block)
     # distinct-user counts from dedup'd blocked data
     assert np.array_equal(np.bincount(p.item[p.mask], minlength=n_ip),
                           _dense(pu, pi, n_users, n_ip).sum(0))
 
-    scores, idx = cco_indicators(p, o, n_users, top_k=n_it, item_tile=item_tile)
+    scores, idx = cco_indicators_coo(
+        pu, pi, ou, oi, n_users, n_ip, n_it, top_k=n_it,
+        user_block=user_block, item_tile=item_tile)
     for i in range(n_ip):
         got = {int(j): float(s) for s, j in zip(scores[i], idx[i]) if j >= 0}
         want = {j: llr[i, j] for j in range(n_it) if np.isfinite(llr[i, j]) and llr[i, j] >= 0}
@@ -113,23 +116,23 @@ def test_cco_top_k_and_threshold():
     n_users, n_ip, n_it = 40, 10, 12
     pu, pi = random_interactions(n_users, n_ip, 200, 3)
     ou, oi = random_interactions(n_users, n_it, 250, 4)
-    p = block_interactions(pu, pi, n_users, n_ip)
-    o = block_interactions(ou, oi, n_users, n_it)
-    scores, idx = cco_indicators(p, o, n_users, top_k=3)
+    scores, idx = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                     top_k=3)
     assert scores.shape == (n_ip, 3)
     # scores sorted descending per row
     finite = np.where(np.isfinite(scores), scores, -1e30)
     assert (np.diff(finite, axis=1) <= 1e-6).all()
     # high threshold kills everything
-    s2, i2 = cco_indicators(p, o, n_users, top_k=3, llr_threshold=1e9)
+    s2, i2 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=3, llr_threshold=1e9)
     assert (i2 == -1).all()
 
 
 def test_cco_exclude_self():
     n_users, n_items = 30, 8
     u, i = random_interactions(n_users, n_items, 150, 5)
-    b = block_interactions(u, i, n_users, n_items, dedup=True)
-    scores, idx = cco_indicators(b, b, n_users, top_k=4, exclude_self=True)
+    scores, idx = cco_indicators_coo(u, i, u, i, n_users, n_items, n_items,
+                                     top_k=4, exclude_self=True)
     for row in range(n_items):
         assert row not in idx[row][idx[row] >= 0]
 
@@ -138,11 +141,11 @@ def test_cco_mesh_matches_single():
     n_users, n_ip, n_it = 64, 12, 10
     pu, pi = random_interactions(n_users, n_ip, 300, 6)
     ou, oi = random_interactions(n_users, n_it, 300, 7)
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=8)
-    s1, i1 = cco_indicators(p, o, n_users, top_k=5)
+    s1, i1 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=5, user_block=8)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
-    s8, i8 = cco_indicators(p, o, n_users, top_k=5, mesh=mesh)
+    s8, i8 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=5, user_block=8, mesh=mesh)
     assert np.allclose(np.where(np.isfinite(s1), s1, -1), np.where(np.isfinite(s8), s8, -1), atol=1e-3)
     assert (i1 == i8).all()
 
@@ -154,18 +157,17 @@ def test_tiled_mesh_matches_single(monkeypatch, kernels):
     budget — equals one device; also with the Pallas LLR and top-k
     kernels traced INSIDE the shard_map step (interpreted here), which
     is how that step runs on TPUs."""
-    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    _take_program(monkeypatch, "resident")
     if kernels == "pallas":
         monkeypatch.setenv("PIO_PALLAS", "interpret")
     n_users, n_ip, n_it = 64, 12, 10
     pu, pi = random_interactions(n_users, n_ip, 300, 6)
     ou, oi = random_interactions(n_users, n_it, 300, 7)
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=8)
-    s1, i1 = cco_indicators(p, o, n_users, top_k=5, item_tile=4)
+    s1, i1 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=5, user_block=8, item_tile=4)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
-    s8, i8 = cco_indicators(p, o, n_users, top_k=5, item_tile=4,
-                            mesh=mesh)
+    s8, i8 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=5, user_block=8, item_tile=4, mesh=mesh)
     np.testing.assert_allclose(s1, s8, rtol=1e-5)
     for r in range(n_ip):   # tie order aside, the same correlators
         assert set(i1[r][s1[r] > -np.inf]) == set(i8[r][s8[r] > -np.inf])
@@ -176,13 +178,15 @@ def test_dense_matches_tiled(monkeypatch):
     n_users, n_ip, n_it = 60, 12, 17
     pu, pi = random_interactions(n_users, n_ip, 300, 11)
     ou, oi = random_interactions(n_users, n_it, 500, 12)
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=16, dedup=True)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=16, dedup=True)
 
-    monkeypatch.setenv("PIO_CCO_DENSE", "1")
-    sd, idd = cco_indicators(p, o, n_users, top_k=6, item_tile=8)
-    monkeypatch.setenv("PIO_CCO_DENSE", "0")
-    st, idt = cco_indicators(p, o, n_users, top_k=6, item_tile=8)
+    def run():
+        return cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                  top_k=6, user_block=16, item_tile=8)
+
+    _take_program(monkeypatch, "dense")
+    sd, idd = run()
+    _take_program(monkeypatch, "resident")
+    st, idt = run()
     np.testing.assert_allclose(sd, st, rtol=1e-5)
     # indices may tie-break differently only where scores tie; require
     # identical index sets per row for non-padding entries
@@ -197,22 +201,21 @@ def test_dense_mesh_matches_single(monkeypatch):
     n_users, n_ip, n_it = 64, 10, 10
     pu, pi = random_interactions(n_users, n_ip, 240, 21)
     ou, oi = random_interactions(n_users, n_it, 400, 22)
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=8)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=8)
-    s1, i1 = cco_indicators(p, o, n_users, top_k=5)
+    s1, i1 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=5)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
-    s8, i8 = cco_indicators(p, o, n_users, top_k=5, mesh=mesh)
+    s8, i8 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
+                                top_k=5, mesh=mesh)
     np.testing.assert_allclose(s1, s8, rtol=1e-5, atol=1e-5)
 
 
 def test_dense_exclude_self_and_topk_overflow(monkeypatch):
-    monkeypatch.setenv("PIO_CCO_DENSE", "1")
+    _take_program(monkeypatch, "dense")
     n_users, n_items = 40, 6
     u, i = random_interactions(n_users, n_items, 200, 31)
-    b = block_interactions(u, i, n_users, n_items, dedup=True)
     # top_k wider than the (padded) item space still returns [I, top_k]
-    scores, idx = cco_indicators(b, b, n_users,
-                                 top_k=300, exclude_self=True)
+    scores, idx = cco_indicators_coo(u, i, u, i, n_users, n_items, n_items,
+                                     top_k=300, exclude_self=True)
     assert scores.shape == (n_items, 300) and idx.shape == (n_items, 300)
     for r in range(n_items):
         assert r not in set(idx[r][idx[r] >= 0])
@@ -223,14 +226,16 @@ def test_dense_matches_tiled_exclude_self(monkeypatch):
     per row and identical scores either way."""
     n_users, n_items = 60, 14
     u, i = random_interactions(n_users, n_items, 400, 41)
-    b = block_interactions(u, i, n_users, n_items, user_block=16, dedup=True)
 
-    monkeypatch.setenv("PIO_CCO_DENSE", "1")
-    sd, idd = cco_indicators(b, b, n_users, top_k=5,
-                             item_tile=8, exclude_self=True)
-    monkeypatch.setenv("PIO_CCO_DENSE", "0")
-    st, idt = cco_indicators(b, b, n_users, top_k=5,
-                             item_tile=8, exclude_self=True)
+    def run():
+        return cco_indicators_coo(u, i, u, i, n_users, n_items, n_items,
+                                  top_k=5, user_block=16, item_tile=8,
+                                  exclude_self=True)
+
+    _take_program(monkeypatch, "dense")
+    sd, idd = run()
+    _take_program(monkeypatch, "resident")
+    st, idt = run()
     np.testing.assert_allclose(sd, st, rtol=1e-5)
     for r in range(n_items):
         assert r not in set(idd[r][idd[r] >= 0])
@@ -242,15 +247,13 @@ def test_duplicates_collapse_without_host_dedup(monkeypatch):
     """Raw pairs with heavy duplication give the same indicators as
     pre-dedup'd pairs on BOTH device strategies — the scatter-max densify
     is the dedup, and marginals derive from it on device."""
-    from predictionio_tpu.ops.cco import cco_indicators_coo, dedup_pairs
-
     n_users, n_ip, n_it = 40, 9, 11
     pu, pi = random_interactions(n_users, n_ip, 500, 51)  # ~500 raw, many dups
     ou, oi = random_interactions(n_users, n_it, 700, 52)
     pu_d, pi_d = dedup_pairs(pu, pi, n_ip)
     ou_d, oi_d = dedup_pairs(ou, oi, n_it)
-    for dense in ("1", "0"):
-        monkeypatch.setenv("PIO_CCO_DENSE", dense)
+    for program in ("dense", "resident"):
+        _take_program(monkeypatch, program)
         s_raw, i_raw = cco_indicators_coo(
             pu, pi, ou, oi, n_users, n_ip, n_it, top_k=4, item_tile=8)
         s_ded, i_ded = cco_indicators_coo(
@@ -263,7 +266,7 @@ def test_duplicates_collapse_without_host_dedup(monkeypatch):
 def test_cco_train_indicators_matches_per_call(monkeypatch):
     """The staged multi-event-type entry returns exactly what independent
     cco_indicators_coo calls return (self + cross)."""
-    from predictionio_tpu.ops.cco import cco_indicators_coo, cco_train_indicators
+    from predictionio_tpu.ops.cco import cco_train_indicators
 
     monkeypatch.setenv("PIO_CCO_DENSE", "1")
     n_users, n_ip, n_view = 50, 12, 18
@@ -293,11 +296,11 @@ def test_cco_train_indicators_tiled_fallback(monkeypatch):
     n_users, n_ip, n_view = 30, 8, 10
     pu, pi = random_interactions(n_users, n_ip, 200, 71)
     vu, vi = random_interactions(n_users, n_view, 300, 72)
-    monkeypatch.setenv("PIO_CCO_DENSE", "1")
+    _take_program(monkeypatch, "dense")
     dense = cco_train_indicators(
         pu, pi, [("buy", pu, pi, n_ip), ("view", vu, vi, n_view)],
         n_users, n_ip, top_k=4, exclude_self_for="buy")
-    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    _take_program(monkeypatch, "resident")
     tiled = cco_train_indicators(
         pu, pi, [("buy", pu, pi, n_ip), ("view", vu, vi, n_view)],
         n_users, n_ip, top_k=4, exclude_self_for="buy", item_tile=8, user_block=8)
@@ -328,7 +331,7 @@ def test_block_interactions_stream_matches_batch():
     """The streaming host-staging layout yields identical indicators to the
     one-shot layout (same data, batched arbitrarily)."""
     from predictionio_tpu.ops.cco import (
-        block_interactions, block_interactions_stream, cco_indicators)
+        _cco_chunked, block_interactions_stream)
 
     n_users, n_items = 48, 12
     u, i = random_interactions(n_users, n_items, 400, 91)
@@ -336,10 +339,10 @@ def test_block_interactions_stream_matches_batch():
     streamed = block_interactions_stream(
         ((u[s:s + 37], i[s:s + 37]) for s in range(0, 400, 37)),
         n_users, n_items, user_block=16)
-    s1, i1 = cco_indicators(whole, whole, n_users, top_k=5,
-                            item_tile=8, exclude_self=True)
-    s2, i2 = cco_indicators(streamed, streamed, n_users, top_k=5,
-                            item_tile=8, exclude_self=True)
+    s1, i1 = _cco_chunked(whole, whole, n_users, top_k=5,
+                          item_tile=8, exclude_self=True)
+    s2, i2 = _cco_chunked(streamed, streamed, n_users, top_k=5,
+                          item_tile=8, exclude_self=True)
     np.testing.assert_allclose(s1, s2, rtol=1e-5)
     for r in range(n_items):
         assert set(i1[r][s1[r] > -np.inf]) == set(i2[r][s2[r] > -np.inf])
@@ -355,7 +358,6 @@ def test_resident_tiled_matches_chunked_tiled(monkeypatch, n_users, n_ip, n_it,
     tile), the chunked tiled path (re-densified per user block and tile)
     and the dense path return the same kept cells with the same scores."""
     from predictionio_tpu.ops import cco as cco_mod
-    from predictionio_tpu.ops.cco import cco_indicators_coo
 
     pu, pi = random_interactions(n_users, n_ip, 400, 101)
     ou, oi = random_interactions(n_users, n_it, 600, 102)
@@ -367,11 +369,12 @@ def test_resident_tiled_matches_chunked_tiled(monkeypatch, n_users, n_ip, n_it,
     _take_program(monkeypatch, "dense")
     sd, idd = run()
     _take_program(monkeypatch, "resident")
-    assert cco_mod._resident_p_ok(n_users, n_ip)      # P easily fits
+    plan = (n_users, n_ip, n_it, None, item_tile)
+    assert cco_mod._plan(*plan) == ("resident",)      # P easily fits
     sr, idr = run()
     # the chunked tiled path: no budget for a resident primary
     _take_program(monkeypatch, "chunked")
-    assert not cco_mod._resident_p_ok(n_users, n_ip)
+    assert cco_mod._plan(*plan) == ("chunked",)
     st, idt = run()
     np.testing.assert_allclose(sd, sr, rtol=1e-4)
     np.testing.assert_allclose(sr, st, rtol=1e-4)
@@ -381,16 +384,35 @@ def test_resident_tiled_matches_chunked_tiled(monkeypatch, n_users, n_ip, n_it,
         assert kept == set(idt[r][st[r] > -np.inf])
 
 
-def test_resident_tiled_self_pair(monkeypatch):
-    from predictionio_tpu.ops import cco as cco_mod
-    from predictionio_tpu.ops.cco import cco_indicators_coo
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_only_the_chunked_program_blocks_by_user(monkeypatch, program):
+    """The strategy is planned before any layout: the pairs are blocked by
+    user once, inside the chunked strategy, and nowhere else."""
+    from predictionio_tpu.obs.spans import SpanCollector
 
+    _take_program(monkeypatch, program)
+    n_users, n_ip, n_it = 70, 14, 19
+    pu, pi = random_interactions(n_users, n_ip, 400, 121)
+    ou, oi = random_interactions(n_users, n_it, 600, 122)
+    with SpanCollector().activate() as collector:
+        cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it, top_k=5,
+                           user_block=16, item_tile=8)
+    spans = collector.spans()
+    assert any(s["name"] == "layout" for s in spans)
+    blocked = [s["attrs"] for s in spans if s["name"] == "layout"
+               and "user_blocks" in s.get("attrs", {})]
+    assert len(blocked) == (program == "chunked")
+    if blocked:
+        assert blocked[0]["user_blocks"] == 5       # 70 users in blocks of 16
+
+
+def test_resident_tiled_self_pair(monkeypatch):
     n_users, n_items = 50, 12
     u, i = random_interactions(n_users, n_items, 300, 111)
-    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    _take_program(monkeypatch, "resident")
     s1, i1 = cco_indicators_coo(u, i, u, i, n_users, n_items, n_items,
                                 top_k=4, item_tile=8, exclude_self=True)
-    monkeypatch.setattr(cco_mod, "_TILED_P_BYTES", 1)
+    _take_program(monkeypatch, "chunked")
     s2, i2 = cco_indicators_coo(u, i, u, i, n_users, n_items, n_items,
                                 top_k=4, item_tile=8, exclude_self=True)
     np.testing.assert_allclose(s1, s2, rtol=1e-4)
@@ -459,7 +481,7 @@ def test_sparse_host_self_pair_and_train_indicators(monkeypatch):
         assert r not in set(idx[idx >= 0])
 
 
-def test_sparse_host_tail_matches_device_tail(monkeypatch):
+def test_sparse_host_tail_matches_device_tail():
     """The sparse host LLR/top-k tail (scores only nonzero cells, lexsort
     top-k) must be bit-identical to the dense device tail at both forced
     settings, including the COO fast path and exclude_self."""
@@ -467,22 +489,18 @@ def test_sparse_host_tail_matches_device_tail(monkeypatch):
 
     n_users, n_items = 300, 64
     u, i = random_interactions(n_users, n_items, 900, 71)
-    monkeypatch.setenv("PIO_CCO_SPARSE", "1")
 
-    def run():
+    def run(tail):
         r = cco_ops._SparseHostRunner(u, i, n_users, n_items)
-        d = r.dispatch(u, i, n_items, 5, 1.0, True, self_pair=True)
-        return r.collect(d)
+        d = r.dispatch(u, i, n_items, 5, 1.0, True, self_pair=True, tail=tail)
+        return cco_ops._DenseRunner.collect(d)
 
-    monkeypatch.setenv("PIO_CCO_SPARSE_TAIL", "device")
-    ds, di = run()
-    monkeypatch.setenv("PIO_CCO_SPARSE_TAIL", "host")
-    hs, hi = run()
+    ds, di = run("device")
+    hs, hi = run("host")
     np.testing.assert_array_equal(hs, ds)
     np.testing.assert_array_equal(hi, di)
-    # auto at this tiny shape picks SOME tail; result must match either way
-    monkeypatch.setenv("PIO_CCO_SPARSE_TAIL", "auto")
-    as_, ai_ = run()
+    # the density rule picks SOME tail here; result must match either way
+    as_, ai_ = run(None)
     np.testing.assert_array_equal(as_, ds)
     np.testing.assert_array_equal(ai_, di)
     # rows with fewer than top_k surviving cells pad with -inf / -1
@@ -514,7 +532,7 @@ def test_sparse_counts_coo_touched_path():
     s_dev, i_dev = cco_ops._llr_topk_dense(
         jnp.asarray(C), jnp.asarray(p.col_counts), jnp.asarray(a.col_counts),
         float(n_users), 0.0, top_k=6, exclude_self=False,
-        pallas=pallas_mode(), topk="lax")
+        pallas=pallas_mode())
     s_dev, i_dev = cco_ops._finalize_topk(s_dev, i_dev, n_it)
     np.testing.assert_array_equal(s_host, s_dev)
     np.testing.assert_array_equal(i_host, i_dev)
@@ -576,7 +594,7 @@ def test_pure_coo_counts_chunked_merge():
     np.testing.assert_array_equal(C, C_ref)
 
 
-def test_huge_catalog_coo_dispatch_matches_dense(monkeypatch):
+def test_huge_catalog_coo_dispatch_matches_dense():
     """When the dense host count matrix is over budget the runner must
     take the pure-COO dispatch (counts + row-scoped sparse tail, no
     [I_p, I_t] array anywhere) and return bit-identical results —
@@ -585,14 +603,13 @@ def test_huge_catalog_coo_dispatch_matches_dense(monkeypatch):
 
     n_users, n_items = 300, 120
     u, i = random_interactions(n_users, n_items, 2500, 104)
-    monkeypatch.setenv("PIO_CCO_SPARSE", "1")
-    monkeypatch.setenv("PIO_CCO_SPARSE_TAIL", "host")
 
     def run():
         r = cco_ops._SparseHostRunner(u, i, n_users, n_items)
-        d = r.dispatch(u, i, n_items, 6, 0.5, True, self_pair=True)
+        d = r.dispatch(u, i, n_items, 6, 0.5, True, self_pair=True,
+                       tail="host")
         assert d is not None
-        return r.collect(d)
+        return cco_ops._DenseRunner.collect(d)
 
     s_ref, i_ref = run()
     saved = cco_ops._SPARSE_C_BYTES

@@ -36,8 +36,8 @@ def test_rule_puts_each_ur_configuration_on_its_side(monkeypatch, config,
     monkeypatch.setenv("PIO_CCO_MM_DTYPE", mm)
     p = UR_CONFIGS[config]["data"]["params"]
     tile = UR_CONFIGS[config]["engine"]["algorithms"][0]["params"]["itemTile"]
-    assert cco._resident_p_ok(p["n_users"], p["n_items"], tile) is resident
-    assert not cco._dense_path_ok(p["n_items"], p["n_items"])
+    assert cco._plan(p["n_users"], p["n_items"], p["n_items"], None, tile)[-1] \
+        == ("resident" if resident else "chunked")
 
 
 def test_rule_counts_the_plan_the_compiler_makes(monkeypatch):
@@ -52,13 +52,17 @@ def test_rule_counts_the_plan_the_compiler_makes(monkeypatch):
     assert plan == pytest.approx(10.10e9, rel=0.002)
     assert cco._TILED_P_BYTES == 0.75 * 16e9
     monkeypatch.setattr(cco, "_TILED_P_BYTES", plan)
-    assert cco._resident_p_ok(users, items, tile)
+
+    def device_strategy(n_users):
+        return cco._plan(n_users, items, items, None, tile)[-1]
+
+    assert device_strategy(users) == "resident"
     monkeypatch.setattr(cco, "_TILED_P_BYTES", plan - 1)
-    assert not cco._resident_p_ok(users, items, tile)
+    assert device_strategy(users) == "chunked"
     # the padded user rows are what is planned, not the users
     monkeypatch.setattr(cco, "_TILED_P_BYTES", plan)
-    assert cco._resident_p_ok(users - 100, items, tile)
-    assert not cco._resident_p_ok(users + 1, items, tile)
+    assert device_strategy(users - 100) == "resident"
+    assert device_strategy(users + 1) == "chunked"
 
 
 def test_blocks_carry_a_count_and_no_mask_array():
@@ -116,9 +120,9 @@ def test_engine_through_the_chunked_program_agrees_with_the_reference(
     monkeypatch.setattr(cco, "_TILED_P_BYTES", cco._TILED_P_BYTES // 100_000)
     monkeypatch.setattr(cco, "_DENSE_C_BYTES", cco._DENSE_C_BYTES // 100_000)
     users, items = SHAPE["n_users"], SHAPE["n_items"]
-    assert not cco._dense_path_ok(items, items)
-    assert not cco._resident_p_ok(users, items, TILE)
-    assert cco._resident_p_ok(64, 40, 32)       # a shop that still fits
+    assert cco._plan(users, items, items, None, TILE) == ("chunked",)
+    # a shop that still fits
+    assert cco._plan(64, 60, 60, None, 32) == ("resident",)
 
     data = _bench_module("data", "commerce").generate(SHAPE, seed)
     app_id = mem_storage.apps.insert(App(0, "chunked"))

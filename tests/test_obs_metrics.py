@@ -146,30 +146,44 @@ def test_label_escape_roundtrip_hostile_values():
     assert parsed == set(nasty)
 
 
-def test_stale_worker_snapshot_zeroes_gauges_keeps_counters(tmp_path):
+@pytest.mark.parametrize("age_s,evicted", [(60.0, False), (660.0, True)])
+def test_stale_worker_snapshot_zeroes_gauges_keeps_counters(
+        tmp_path, monkeypatch, age_s, evicted):
+    """A sibling file past the stale rule (ten flush intervals, at least
+    15 s) and short of PIO_OBS_SIBLING_STALE_S is a dead worker: its
+    counters still aggregate, its gauges read 0.  Past
+    PIO_OBS_SIBLING_STALE_S the file is evicted and adds nothing."""
     import os
 
     from predictionio_tpu.obs import metrics as obs_metrics
 
+    monkeypatch.setenv("PIO_METRICS_FLUSH_S", "1.0")
+    monkeypatch.setenv("PIO_OBS_SIBLING_STALE_S", "600")
+    counter, gauge = ("pio_storage_events_appended_total",
+                      "pio_http_requests_in_flight")
     reg = obs_metrics.get_registry()
+
+    def total(snap, name):
+        return sum(snap.get(name, {"series": {}})["series"].values())
+
     try:
         obs_metrics.start_worker_flusher(str(tmp_path), tag="live-w")
-        # fake a dead sibling: stale mtime, nonzero gauge + counter
+        # fake a dead sibling: old mtime, nonzero gauge + counter
         dead = MetricsRegistry()
-        dead.gauge("pio_http_requests_in_flight", "x").set(3)
-        dead.counter("pio_storage_events_appended_total", "x").inc(7)
-        import json as _json
-
+        dead.gauge(gauge, "x").set(3)
+        dead.counter(counter, "x").inc(7)
         p = tmp_path / "dead-w.json"
-        p.write_text(_json.dumps(dead.snapshot()))
-        os.utime(p, (0, 0))   # ancient mtime → stale
+        p.write_text(json.dumps(dead.snapshot()))
+        then = time.time() - age_s
+        os.utime(p, (then, then))
+        own = reg.snapshot()
+        evictions = obs_metrics.STALE_SIBLINGS.value(kind="metrics")
         snap = obs_metrics.aggregate_snapshot(reg)
-        # dead worker's counters still aggregate; its gauges read 0
-        assert sum(
-            snap["pio_storage_events_appended_total"]["series"].values()) >= 7
-        inflight = snap["pio_http_requests_in_flight"]["series"]
-        assert sum(inflight.values()) == reg.gauge(
-            "pio_http_requests_in_flight", "x").value()
+        assert total(snap, counter) == total(own, counter) + (0 if evicted else 7)
+        assert total(snap, gauge) == total(own, gauge)
+        assert p.exists() is not evicted
+        assert obs_metrics.STALE_SIBLINGS.value(kind="metrics") == (
+            evictions + evicted)
     finally:
         obs_metrics.stop_worker_flusher()
 

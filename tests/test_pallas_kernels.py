@@ -75,7 +75,7 @@ def test_llr_masked_scores_matches_reference():
 
 
 def test_cco_indicators_pallas_matches_xla(monkeypatch):
-    from predictionio_tpu.ops.cco import block_interactions, cco_indicators
+    from predictionio_tpu.ops.cco import cco_indicators_coo
 
     rng = np.random.default_rng(3)
     n_users, n_ip, n_it = 60, 25, 40
@@ -83,13 +83,16 @@ def test_cco_indicators_pallas_matches_xla(monkeypatch):
     pi = rng.integers(0, n_ip, 400)
     ou = rng.integers(0, n_users, 800)
     oi = rng.integers(0, n_it, 800)
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=16)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=16)
+
+    def run():
+        return cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it, top_k=5,
+                                  llr_threshold=1.0, user_block=16,
+                                  item_tile=16)
 
     monkeypatch.setenv("PIO_PALLAS", "0")
-    s1, i1 = cco_indicators(p, o, n_users, top_k=5, llr_threshold=1.0, item_tile=16)
+    s1, i1 = run()
     monkeypatch.setenv("PIO_PALLAS", "interpret")
-    s2, i2 = cco_indicators(p, o, n_users, top_k=5, llr_threshold=1.0, item_tile=16)
+    s2, i2 = run()
 
     finite = np.isfinite(s1)
     assert (np.isfinite(s2) == finite).all()

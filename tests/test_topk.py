@@ -109,6 +109,7 @@ def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
     ou = rng.integers(0, n_users, 900)
     oi = rng.integers(0, n_it, 900)
 
+    monkeypatch.setenv("PIO_CCO_SPARSE", "0")
     if strategy == "dense":
         monkeypatch.setenv("PIO_CCO_DENSE", "1")
     else:
@@ -134,16 +135,8 @@ def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
     np.testing.assert_allclose(
         np.sort(s1, axis=1), np.sort(s2, axis=1), rtol=1e-5, atol=1e-5)
     if strategy == "dense":
-        # the whole-row top-k stays lax.top_k on every backend; the
-        # tournament over a whole row is reachable from here alone
+        # the whole-row top-k stays lax.top_k on every backend
         assert "topk" not in d2["_llr_topk_dense"]
-        C = jnp.asarray(rng.integers(0, 6, (n_ip, n_it)).astype(np.int32))
-        rc = jnp.full((n_ip,), 9, jnp.int32)
-        cc = jnp.full((n_it,), 9, jnp.int32)
-        got = {impl: cco_ops._llr_topk_dense(
-            C, rc, cc, float(n_users), 0.5, top_k=7, exclude_self=False,
-            pallas="off", topk=impl) for impl in ("lax", "pallas")}
-        np.testing.assert_array_equal(got["lax"][0], got["pallas"][0])
     else:
         program = f"_cco_{strategy}_all_tiles"
         assert d1[program]["topk"] == "lax"
@@ -213,22 +206,21 @@ def test_merge_ties_at_the_cut_over_a_partial_last_tile(monkeypatch):
     same multiset of scores as lax.top_k, only items that exist, and
     -1 / -inf elsewhere."""
     from predictionio_tpu.ops import cco as cco_ops
-    from predictionio_tpu.ops.cco import block_interactions, cco_indicators
 
+    monkeypatch.setenv("PIO_CCO_SPARSE", "0")
     monkeypatch.setenv("PIO_CCO_DENSE", "0")
     rng = np.random.default_rng(5)
     n_users, n_ip, n_it, groups, top_k = 96, 20, 150, 21, 50
     pu, pi = np.nonzero(rng.random((n_users, n_ip)) < 0.3)
     pattern = rng.random((n_users, groups)) < 0.35
     ou, oi = np.nonzero(pattern[:, np.arange(n_it) % groups])
-    p = block_interactions(pu, pi, n_users, n_ip, user_block=32)
-    o = block_interactions(ou, oi, n_users, n_it, user_block=32)
-    assert cco_ops._resident_p_ok(n_users, n_ip, 64)
+    assert cco_ops._plan(n_users, n_ip, n_it, None, 64) == ("resident",)
 
     def run(pallas):
         monkeypatch.setenv("PIO_PALLAS", pallas)
-        return cco_indicators(p, o, n_users, top_k=top_k,
-                              item_tile=64)
+        return cco_ops.cco_indicators_coo(
+            pu, pi, ou, oi, n_users, n_ip, n_it, top_k=top_k, user_block=32,
+            item_tile=64)
 
     s_lax, _ = run("off")
     s_pal, i_pal = run("interpret")
