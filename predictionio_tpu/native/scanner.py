@@ -14,6 +14,7 @@ import ctypes
 import logging
 import os
 import threading
+import time
 from pathlib import Path
 from typing import List, Optional, Sequence
 
@@ -40,52 +41,31 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
             lib = ctypes.CDLL(str(_native_build.build(_SRC, "libeventscan")))
             lib.scan_new.restype = ctypes.c_void_p
             lib.scan_add_file.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-            lib.scan_run.argtypes = [ctypes.c_void_p, ctypes.c_int]
+            lib.scan_run.argtypes = [ctypes.c_void_p, ctypes.c_int,
+                                     ctypes.c_int64]
             lib.scan_run.restype = ctypes.c_int64
-            lib.scan_rows.argtypes = [ctypes.c_void_p]
-            lib.scan_rows.restype = ctypes.c_int64
             lib.scan_error.argtypes = [ctypes.c_void_p]
             lib.scan_error.restype = ctypes.c_char_p
-            for name, typ in [
-                ("scan_col_event", ctypes.POINTER(ctypes.c_int32)),
-                ("scan_col_entity_type", ctypes.POINTER(ctypes.c_int32)),
-                ("scan_col_entity", ctypes.POINTER(ctypes.c_int32)),
-                ("scan_col_target", ctypes.POINTER(ctypes.c_int32)),
-                ("scan_col_time", ctypes.POINTER(ctypes.c_int64)),
-                ("scan_col_rating", ctypes.POINTER(ctypes.c_float)),
-            ]:
-                fn = getattr(lib, name)
-                fn.argtypes = [ctypes.c_void_p]
-                fn.restype = typ
+            lib.scan_fill.argtypes = [ctypes.c_void_p] + [ctypes.c_void_p] * 6
+            lib.scan_fill.restype = None
+            lib.scan_stats.argtypes = [ctypes.c_void_p,
+                                       ctypes.POINTER(ctypes.c_double)]
+            lib.scan_stats.restype = None
             lib.scan_dict_size.argtypes = [ctypes.c_void_p, ctypes.c_int]
             lib.scan_dict_size.restype = ctypes.c_int64
-            lib.scan_dict_export.argtypes = [ctypes.c_void_p, ctypes.c_int]
-            lib.scan_dict_export.restype = ctypes.c_int64
-            lib.scan_dict_blob.argtypes = [ctypes.c_void_p]
+            lib.scan_dict_blob.argtypes = [ctypes.c_void_p, ctypes.c_int]
             lib.scan_dict_blob.restype = ctypes.POINTER(ctypes.c_char)
-            lib.scan_dict_offsets.argtypes = [ctypes.c_void_p]
+            lib.scan_dict_offsets.argtypes = [ctypes.c_void_p, ctypes.c_int]
             lib.scan_dict_offsets.restype = ctypes.POINTER(ctypes.c_int64)
             lib.scan_prop_count.argtypes = [ctypes.c_void_p]
             lib.scan_prop_count.restype = ctypes.c_int64
-            lib.scan_prop_key.argtypes = [ctypes.c_void_p, ctypes.c_int]
-            lib.scan_prop_key.restype = ctypes.POINTER(ctypes.c_char)
-            lib.scan_prop_key_len.argtypes = [ctypes.c_void_p, ctypes.c_int]
-            lib.scan_prop_key_len.restype = ctypes.c_int64
-            for name, typ in [
-                ("scan_prop_rows", ctypes.POINTER(ctypes.c_int64)),
-                ("scan_prop_kind", ctypes.POINTER(ctypes.c_int8)),
-                ("scan_prop_num", ctypes.POINTER(ctypes.c_double)),
-                ("scan_prop_stroffs", ctypes.POINTER(ctypes.c_int64)),
-                ("scan_prop_codes", ctypes.POINTER(ctypes.c_int32)),
-            ]:
-                fn = getattr(lib, name)
-                fn.argtypes = [ctypes.c_void_p, ctypes.c_int]
-                fn.restype = typ
-            for name in ("scan_prop_len", "scan_prop_codes_len",
-                         "scan_prop_dict_size", "scan_prop_dict_export"):
+            for name in ("scan_prop_len", "scan_prop_codes_len"):
                 fn = getattr(lib, name)
                 fn.argtypes = [ctypes.c_void_p, ctypes.c_int]
                 fn.restype = ctypes.c_int64
+            lib.scan_prop_bind.argtypes = (
+                [ctypes.c_void_p, ctypes.c_int] + [ctypes.c_void_p] * 5)
+            lib.scan_prop_bind.restype = None
             lib.layout_width.argtypes = [
                 ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
                 ctypes.c_int32, ctypes.c_int32, ctypes.c_int32]
@@ -109,19 +89,31 @@ def native_available() -> bool:
     return _build_and_load() is not None
 
 
+# which-codes of scan_dict_*: the four id dictionaries, the property keys,
+# then one dictionary a property column
+_DICT_PROP_KEYS = 4
+_STAT_NAMES = ("files", "bytes", "ranges", "threads", "rows", "parse_s",
+               "merge_s", "slow_strings", "slow_times")
+
+
 def _export_dict(lib, handle, which: int) -> List[str]:
+    """One merged dictionary's strings: the blob is decoded once, and an
+    ASCII blob (byte offsets are then character offsets) is only sliced."""
     n = lib.scan_dict_size(handle, which)
-    blob_len = lib.scan_dict_export(handle, which)
-    if n <= 0 or blob_len < 0:
+    if n <= 0:
         return []
-    offsets = np.ctypeslib.as_array(lib.scan_dict_offsets(handle), shape=(n + 1,)).copy()
-    blob = ctypes.string_at(lib.scan_dict_blob(handle), blob_len)
-    # surrogatepass: JSON may legally carry lone surrogates (Python's own
-    # json emits them); anything else malformed falls back to replacement
+    offsets = np.ctypeslib.as_array(
+        lib.scan_dict_offsets(handle, which), shape=(n + 1,)).tolist()
+    blob = ctypes.string_at(lib.scan_dict_blob(handle, which), offsets[-1])
+    if blob.isascii():
+        text = blob.decode("ascii")
+        return [text[offsets[i]:offsets[i + 1]] for i in range(n)]
     return [_decode(blob[offsets[i]:offsets[i + 1]]) for i in range(n)]
 
 
 def _decode(b: bytes) -> str:
+    # surrogatepass: JSON may legally carry lone surrogates (Python's own
+    # json emits them); anything else malformed falls back to replacement
     try:
         return b.decode("utf-8", "surrogatepass")
     except UnicodeDecodeError:
@@ -130,6 +122,13 @@ def _decode(b: bytes) -> str:
 
 def scan_segments(paths: Sequence[os.PathLike], n_threads: int = 0):
     """Parse JSONL event segments into an EventBatch (native path)."""
+    return _scan(paths, n_threads, 0)
+
+
+def _scan(paths: Sequence[os.PathLike], n_threads: int, range_bytes: int):
+    """``scan_segments`` with the size of the byte ranges the workers pull
+    (0: the scanner's own, a few MB); tests force it small."""
+    from predictionio_tpu.obs.spans import span
     from predictionio_tpu.store.columnar import EventBatch, IdDict, PropColumn
 
     lib = _build_and_load()
@@ -139,61 +138,53 @@ def scan_segments(paths: Sequence[os.PathLike], n_threads: int = 0):
         n_threads = min(os.cpu_count() or 4, 16)
     handle = lib.scan_new()
     try:
-        for p in paths:
-            lib.scan_add_file(handle, str(p).encode())
-        rows = lib.scan_run(handle, n_threads)
-        if rows < 0:
-            raise RuntimeError(lib.scan_error(handle).decode())
+        with span("native_scan") as rec:
+            t0 = time.perf_counter()
+            for p in paths:
+                lib.scan_add_file(handle, str(p).encode())
+            rows = lib.scan_run(handle, n_threads, range_bytes)
+            if rows < 0:
+                raise RuntimeError(lib.scan_error(handle).decode())
 
-        def col(fn, dtype):
-            if rows == 0:
-                return np.empty(0, dtype)
-            return np.ctypeslib.as_array(fn(handle), shape=(rows,)).astype(dtype, copy=True)
+            # the scanner writes its columns straight into these
+            cols = [np.empty(rows, dt) for dt in
+                    (np.int32, np.int32, np.int32, np.int32, np.int64,
+                     np.float32)]
+            props = []
+            for k in range(lib.scan_prop_count(handle)):
+                n = lib.scan_prop_len(handle, k)
+                col = PropColumn(
+                    rows=np.empty(n, np.int64), kind=np.empty(n, np.int8),
+                    num=np.empty(n, np.float64),
+                    str_offs=np.empty(n + 1, np.int64),
+                    codes=np.empty(lib.scan_prop_codes_len(handle, k),
+                                   np.int32),
+                    dict=None)
+                lib.scan_prop_bind(
+                    handle, k, col.rows.ctypes.data, col.kind.ctypes.data,
+                    col.num.ctypes.data, col.str_offs.ctypes.data,
+                    col.codes.ctypes.data)
+                props.append(col)
+            lib.scan_fill(handle, *(c.ctypes.data for c in cols))
 
-        def arr(ptr, n, dtype):
-            if n == 0:
-                return np.empty(0, dtype)
-            return np.ctypeslib.as_array(ptr, shape=(n,)).astype(dtype, copy=True)
-
-        props = {}
-        for k in range(lib.scan_prop_count(handle)):
-            key = ctypes.string_at(lib.scan_prop_key(handle, k),
-                                   lib.scan_prop_key_len(handle, k))
-            n = lib.scan_prop_len(handle, k)
-            nc = lib.scan_prop_codes_len(handle, k)
-            nd = lib.scan_prop_dict_size(handle, k)
-            blob_len = lib.scan_prop_dict_export(handle, k)
-            if nd > 0 and blob_len >= 0:
-                offsets = np.ctypeslib.as_array(
-                    lib.scan_dict_offsets(handle), shape=(nd + 1,)).copy()
-                blob = ctypes.string_at(lib.scan_dict_blob(handle), blob_len)
-                strings = [_decode(blob[offsets[i]:offsets[i + 1]]) for i in range(nd)]
-            else:
-                strings = []
-            props[_decode(key)] = PropColumn(
-                rows=arr(lib.scan_prop_rows(handle, k), n, np.int64),
-                kind=arr(lib.scan_prop_kind(handle, k), n, np.int8),
-                num=arr(lib.scan_prop_num(handle, k), n, np.float64),
-                str_offs=arr(lib.scan_prop_stroffs(handle, k),
-                             n + 1 if n else 0, np.int64)
-                if n else np.zeros(1, np.int64),
-                codes=arr(lib.scan_prop_codes(handle, k), nc, np.int32),
-                dict=IdDict.from_state(strings),
-            )
-
-        batch = EventBatch(
-            event_codes=col(lib.scan_col_event, np.int32),
-            entity_type_codes=col(lib.scan_col_entity_type, np.int32),
-            entity_ids=col(lib.scan_col_entity, np.int32),
-            target_ids=col(lib.scan_col_target, np.int32),
-            times_us=col(lib.scan_col_time, np.int64),
-            ratings=col(lib.scan_col_rating, np.float32),
-            event_dict=IdDict.from_state(_export_dict(lib, handle, 0)),
-            entity_type_dict=IdDict.from_state(_export_dict(lib, handle, 1)),
-            entity_dict=IdDict.from_state(_export_dict(lib, handle, 2)),
-            target_dict=IdDict.from_state(_export_dict(lib, handle, 3)),
-            prop_columns=props,
-        )
+            dicts = [IdDict.from_state(_export_dict(lib, handle, which))
+                     for which in range(_DICT_PROP_KEYS)]
+            keys = _export_dict(lib, handle, _DICT_PROP_KEYS)
+            for k, col in enumerate(props):
+                col.dict = IdDict.from_state(
+                    _export_dict(lib, handle, _DICT_PROP_KEYS + 1 + k))
+            batch = EventBatch(
+                *cols, *dicts,
+                prop_columns=dict(zip(keys, props)))
+            stats = (ctypes.c_double * len(_STAT_NAMES))()
+            lib.scan_stats(handle, stats)
+            attrs = {name: (v if name.endswith("_s") else int(v))
+                     for name, v in zip(_STAT_NAMES, stats)}
+            # the rest of the span: allocating the arrays, decoding the
+            # dictionaries
+            attrs["export_s"] = max(time.perf_counter() - t0
+                                    - attrs["parse_s"] - attrs["merge_s"], 0.0)
+            rec["attrs"] = attrs
         return batch
     finally:
         lib.scan_free(handle)

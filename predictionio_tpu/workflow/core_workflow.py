@@ -138,9 +138,13 @@ def run_train(
                             _M_TRAIN_STAGED.inc(v, mode=mode)
                     with journal.span("save_models"):
                         persistence.save_models(storage, instance_id, models)
-                    instance.status = "COMPLETED"
-                    instance.end_time = _now()
-                    storage.engine_instances.update(instance)
+                        # COMPLETED is what makes the stored model the one a
+                        # deploy loads: the commit of persisting it, and a
+                        # rewrite of the whole instance document that no
+                        # span named before
+                        instance.status = "COMPLETED"
+                        instance.end_time = _now()
+                        storage.engine_instances.update(instance)
                     root["attrs"].update(
                         compile=_device.compile_stats(),
                         peak_memory_bytes=_device.peak_memory_bytes())

@@ -360,3 +360,132 @@ def test_native_layout_perf_sanity():
     dt = time.perf_counter() - t0
     assert out is not None and out[2].sum() == n
     assert dt < 2.0, f"native layout too slow: {dt:.2f}s for {n} events"
+
+
+# -- byte ranges: the scan against the Python path, across range boundaries --
+
+
+def _range_log(tmp_chan):
+    """Three segments whose lines are longer than the forced ranges: ids with
+    escapes and `\\u` surrogate pairs, `Z` and `+05:30` times with and
+    without fractions, every property kind, ids first seen in the last
+    lines of the last segment.  Event times rise with the line, so `find`'s
+    time order is the file order."""
+    ids = ["u0", "u1", 'u"quoted\\slash', "naïve—\U0001F600", "tab\tid", "u2"]
+    props = [
+        {"rating": 4.5},
+        {"price": 3, "inStock": True, "category": "books"},
+        {"tags": ["a", "b", 3, 2.5, True, None, {"x": 1}, [1]], "note": None},
+        {"nested": {"x": [1, {"y": "z\"q"}]}, "category": "mußic"},
+        {},
+        {"rating": 2, "tags": [], "category": "books"},
+    ]
+    t0 = dt.datetime(2026, 1, 2, tzinfo=dt.timezone.utc)
+    ist = dt.timezone(dt.timedelta(hours=5, minutes=30))
+    lines = []
+    for k in range(45):
+        t = t0 + dt.timedelta(minutes=7 * k, microseconds=123456 * (k % 2))
+        if k % 4 < 2:
+            when = t.strftime("%Y-%m-%dT%H:%M:%S") + (
+                f".{t.microsecond:06d}" if t.microsecond else "") + "Z"
+        else:
+            when = t.astimezone(ist).isoformat()
+        d = {"eventId": f"{k:032x}", "creationTime": t0.isoformat(),
+             "event": ("rate", "view", "$set")[k % 3], "entityType": "user",
+             "entityId": ids[k % len(ids)] if k < 43 else f"late-user-{k}",
+             "eventTime": when}
+        if k % 3 != 2:
+            d["targetEntityType"] = "item"
+            d["targetEntityId"] = (f"i{k % 5}" if k < 43 else f"late-item-{k}")
+        if k % 7:
+            d["properties"] = props[k % len(props)]
+        lines.append(json.dumps(d, separators=(",", ":"), sort_keys=True))
+    # a key given twice keeps its last value, as Python's json reads it
+    lines[20] = '{"entityId":"given-twice",' + lines[20][1:]
+    assert any("\\ud83d\\ude00" in ln for ln in lines)     # a surrogate pair
+    assert any("+05:30" in ln for ln in lines)
+    tmp_chan.mkdir(parents=True, exist_ok=True)
+    paths = [tmp_chan / f"seg-{s:05d}.jsonl" for s in range(3)]
+    for s, path in enumerate(paths):
+        path.write_text("".join(ln + "\n" for ln in lines[15 * s:15 * s + 15]))
+    return paths
+
+
+def _expected_value(v):
+    """What `PropColumn.value_at` gives for a property the writer stored:
+    list elements as strings, nulls and containers inside a list dropped."""
+    if isinstance(v, list):
+        return [("true" if e else "false") if isinstance(e, bool)
+                else e if isinstance(e, str) else "%.17g" % e
+                for e in v if not isinstance(e, (dict, list, type(None)))]
+    return v
+
+
+@pytest.mark.parametrize("n_threads,range_bytes",
+                         [(1, 64), (3, 257), (16, 1000), (3, 0)])
+def test_ranges_match_python_path(fs_storage, n_threads, range_bytes):
+    """The scan over byte ranges is the Python path's batch: equal columns,
+    every dictionary in the same ORDER, equal property columns, whatever
+    the ranges and the threads; malformed and torn lines leave nothing."""
+    from predictionio_tpu.native.scanner import _scan
+    from predictionio_tpu.store.columnar import EventBatch
+
+    app_id = fs_storage.apps.insert(App(0, "rangeapp"))
+    fs_storage.l_events.init(app_id)
+    chan = fs_storage.p_events._chan_dir(app_id, None)
+    paths = _range_log(chan)
+    assert fs_storage.p_events.segment_paths(app_id, None) == paths
+    events = list(fs_storage.l_events.find(app_id))
+    want = EventBatch.from_events(events)
+    assert len(want) == 45
+
+    # what the Python path cannot read and the scan drops: a malformed line
+    # and an empty verb inside a segment, a torn last line on two of them
+    body = paths[1].read_text().splitlines(keepends=True)
+    body[7:7] = ["this is not json\n",
+                 '{"event":"","entityType":"user","entityId":"ghost"}\n',
+                 '{"event":"x","entityId":"ghost","eventTime":"garbage"}\n']
+    paths[1].write_text("".join(body)
+                        + '{"event":"buy","entityType":"user","entityId":"torn')
+    with open(paths[2], "a") as f:
+        f.write('{"event":"buy","entityType":"user","entityId":"torn2"}')
+
+    got = _scan(paths, n_threads, range_bytes)
+    for name in ("event_codes", "entity_type_codes", "entity_ids",
+                 "target_ids", "times_us"):
+        assert np.array_equal(getattr(got, name), getattr(want, name)), name
+        assert getattr(got, name).dtype == getattr(want, name).dtype
+    assert np.array_equal(got.ratings, want.ratings, equal_nan=True)
+    for name in ("event_dict", "entity_type_dict", "entity_dict",
+                 "target_dict"):
+        assert getattr(got, name).strings() == getattr(want, name).strings()
+    assert got.entity_dict.strings()[-2:] == ["late-user-43", "late-user-44"]
+    assert got.target_dict.strings()[-1] == "late-item-43"
+
+    # property columns: one entry a (row, key) in row order, each value the
+    # one the writer stored, each dictionary in first-appearance order
+    keys, entries, strings = [], {}, {}
+    for row, e in enumerate(events):
+        for key, v in e.properties.items():
+            if key not in entries:
+                keys.append(key)
+            entries.setdefault(key, []).append((row, _expected_value(v)))
+            for s in (v if isinstance(v, list) else [v]):
+                if isinstance(s, str) and s not in strings.setdefault(key, []):
+                    strings[key].append(s)
+    assert list(got.prop_columns) == keys
+    assert {"rating", "price", "inStock", "category", "tags", "note",
+            "nested"} == set(keys)
+    for key, col in got.prop_columns.items():
+        assert col.rows.tolist() == [row for row, _ in entries[key]]
+        assert [col.value_at(j) for j in range(len(col))] == [
+            v for _, v in entries[key]], key
+        assert col.str_offs[0] == 0 and col.str_offs[-1] == len(col.codes)
+        if key in ("category", "note"):
+            assert col.dict.strings() == strings.get(key, [])
+    assert got.prop_columns["tags"].dict.strings() == [
+        "a", "b", "3", "2.5", "true"]
+    assert set(got.prop_columns["rating"].kind.tolist()) == {0}
+    assert got.prop_columns["inStock"].kind.tolist()[0] == 1
+    assert set(got.prop_columns["note"].kind.tolist()) == {4}
+    assert set(got.prop_columns["nested"].kind.tolist()) == {5}

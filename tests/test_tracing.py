@@ -682,6 +682,80 @@ def test_host_sparse_strategy_is_one_host_compute_span(fs_storage,
     assert {by_id[s["parent"]]["name"] for s in host} == {"algo_train"}
 
 
+def _bench_job(config_name):
+    """A job of one benchmark configuration at its rehearsal size: the
+    benchmark's own generator, through the ingest path its driver takes."""
+    import importlib.util
+
+    def load(kind, name):
+        spec = importlib.util.spec_from_file_location(
+            f"span_test_{kind}_{name}",
+            REPO / "benchmark" / kind / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    def make(storage, monkeypatch, strategy=None):
+        from predictionio_tpu.workflow import create_workflow
+
+        config = json.loads(
+            (REPO / "benchmark" / "configs" / f"{config_name}.json")
+            .read_text())
+        small = config["rehearsal"]
+        for key, value in small.get("env", {}).items():
+            monkeypatch.setenv(key, value)
+        data = load("data", config["data"]["generator"]).generate(
+            {**config["data"]["params"], **small["data"]["params"]}, 2 ** 31 + 5)
+        wire_events = load("drivers", "train_jobs").wire_events
+        make.app_id = app_id = storage.apps.insert(App(0, "bench"))
+        make.n_events = 0
+        for block in data["blocks"]:
+            results = storage.l_events.insert_json_batch(
+                list(wire_events(block)), app_id)
+            assert all(r["status"] == 201 for r in results)
+            make.n_events += len(results)
+        variant = json.loads(json.dumps(
+            {**config["engine"], **small.get("engine", {})})
+            .replace("$app", "bench").replace('"$seed"', "5"))
+        for algo in variant["algorithms"]:
+            algo["params"]["meshDp"] = 1    # the suite's CPU shows 8 devices
+        _, engine, ep = create_workflow.engine_from_variant(variant)
+        return engine, ep
+
+    return make
+
+
+@pytest.mark.parametrize("config_name", ["als-ml1m", "ur-ecom-100k"])
+def test_native_scan_span_on_a_train_journal(config_name, fs_storage,
+                                             monkeypatch):
+    """`scan_segments` opens `native_scan` inside `read_training`, with what
+    the scan did: every event of the store a row, and on the lines the
+    benchmark's generators write no value off the fast paths."""
+    from predictionio_tpu.native import native_available
+
+    if not native_available():
+        pytest.skip("g++ unavailable; native scanner not built")
+    monkeypatch.setenv("PIO_DELTA_STAGING", "off")    # as the train-jobs mix
+    make = _bench_job(config_name)
+    spans = _run_train(make, None, fs_storage, monkeypatch)
+    by_id = {s["id"]: s for s in spans}
+    scans = [s for s in spans if s["name"] == "native_scan"]
+    assert len(scans) == 1
+    assert by_id[scans[0]["parent"]]["name"] == "read_training"
+    attrs = scans[0]["attrs"]
+    assert set(attrs) == {"files", "bytes", "ranges", "threads", "rows",
+                          "parse_s", "merge_s", "export_s", "slow_strings",
+                          "slow_times"}
+    assert attrs["rows"] == make.n_events > 1000
+    assert attrs["slow_strings"] == 0 and attrs["slow_times"] == 0
+    segments = fs_storage.p_events.segment_paths(make.app_id, None)
+    assert attrs["files"] == len(segments) >= 1
+    assert attrs["bytes"] == sum(p.stat().st_size for p in segments)
+    assert 1 <= attrs["threads"] <= attrs["ranges"]
+    parts = attrs["parse_s"] + attrs["merge_s"] + attrs["export_s"]
+    assert 0 < parts <= scans[0]["duration_s"] + 1e-3
+
+
 @pytest.mark.parametrize("make,strategy", [TRAIN_JOBS[1], TRAIN_JOBS[3]],
                          ids=["ur", "als"])
 def test_spans_lie_on_the_profilers_clock(make, strategy, fs_storage,
