@@ -21,6 +21,8 @@ import gc
 import json
 import time
 
+import numpy as np
+
 ANNOTATION = "bench:train_job"
 EVENT_TIME = "2026-01-01T00:00:00+00:00"
 
@@ -35,14 +37,23 @@ def _fill(obj, values: dict):
 
 
 def wire_events(block: dict):
-    """One generator block as the wire dicts the event server takes."""
+    """One generator block as the wire dicts the event server takes.
+
+    A block may hold `times` (int64 microseconds after `EVENT_TIME`, one an
+    event); without the key every event carries `EVENT_TIME`."""
     name = block["event"]
     ratings = block.get("ratings")
     users, items = block["users"].tolist(), block["items"].tolist()
+    times = block.get("times")
+    if times is not None:
+        at = np.datetime64(EVENT_TIME[:19], "us") + np.asarray(
+            times, np.int64).astype("timedelta64[us]")
+        times = [t + EVENT_TIME[19:]
+                 for t in np.datetime_as_string(at, unit="us").tolist()]
     for k in range(len(users)):
         d = {"event": name, "entityType": "user", "entityId": f"u{users[k]}",
              "targetEntityType": "item", "targetEntityId": f"i{items[k]}",
-             "eventTime": EVENT_TIME}
+             "eventTime": EVENT_TIME if times is None else times[k]}
         if ratings is not None:
             d["properties"] = {"rating": float(ratings[k])}
         yield d

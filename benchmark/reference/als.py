@@ -113,3 +113,52 @@ def check(model, data: dict, variant: dict, limits: dict, seed: int) -> list:
                   int(params["seed"]))
     return [{"name": k, "value": got[k], "limit": limits[k],
              "ok": got[k] <= limits[k]} for k in limits]
+
+
+def _zeroed(y: np.ndarray, item: int) -> np.ndarray:
+    """One answer altered: a copy of the item factors with one item's zeroed."""
+    y = np.array(y)
+    y[item] = 0.0
+    return y
+
+
+def alter(model, seed: int) -> None:
+    """The fault "one answer altered where it is produced", on the model as
+    it is about to be persisted: one item's factors zeroed, the item drawn by
+    the seed (a model holds no counts to find the most rated item by)."""
+    model.item_factors = _zeroed(model.item_factors,
+                                 seed % len(model.item_factors))
+
+
+def readings(config: dict, data: dict, seed: int, half) -> dict:
+    """What `compare` reads with, in the program's place: the reference
+    itself, the control (bfloat16), half of the ratings left out
+    (`half(data)`), and the most rated item's factors zeroed.  `control.py`
+    prints them."""
+    import ml_dtypes
+
+    a = config["engine"]["algorithms"][0]["params"]
+    rank, reg, sweeps = int(a["rank"]), float(a["lambda"]), int(
+        a["numIterations"])
+    first = seed % (2 ** 31 - 1)
+    nu, ni = data["n_users"], data["n_items"]
+    ids = (np.arange(nu), np.arange(ni))
+    b = data["blocks"][0]
+    y0 = start(first, ni, rank)
+
+    def held(x, y):
+        got = compare(x, y, *ids, data, rank, reg, sweeps, first)
+        return {k: got[k] for k in ("pred_gap_rms", "rmse_gap")}
+
+    x, y = factorize(b["users"], b["items"], b["ratings"], nu, ni, y0,
+                     reg, sweeps)
+    out = {"reference": held(x, y)}
+    out["control_bfloat16"] = held(*factorize(
+        b["users"], b["items"], b["ratings"], nu, ni, y0, reg, sweeps,
+        ml_dtypes.bfloat16))
+    h = half(data)["blocks"][0]
+    out["fault_half_left_out"] = held(*factorize(
+        h["users"], h["items"], h["ratings"], nu, ni, y0, reg, sweeps))
+    out["fault_answer_altered"] = held(x, _zeroed(y, int(np.argmax(
+        np.bincount(b["items"])))))
+    return out

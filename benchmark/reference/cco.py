@@ -179,3 +179,46 @@ def control_tables(data: dict, top_k: int, threshold: float,
                         np.where(ref["top_cols"] >= 0, ref["top"], 0.0))
     ids = np.arange(n, dtype=np.int64)
     return tables, ids, {name: ids for name in blocks}
+
+
+def _moved(idx: np.ndarray, seed: int, n_items: int) -> np.ndarray:
+    """One answer altered: a copy of a table's kept columns with the first
+    kept cell of one row, drawn by the seed among the rows that keep two or
+    more, moved to another column."""
+    rows = np.flatnonzero((idx >= 0).sum(1) >= 2)
+    row = int(rows[seed % min(97, len(rows))])
+    idx = idx.copy()
+    idx[row, 0] = (idx[row, 0] + 1 + row) % n_items
+    return idx
+
+
+def alter(model, seed: int) -> None:
+    """The fault "one answer altered where it is produced", on the model as
+    it is about to be persisted: one kept cell of the primary table moved."""
+    model.indicator_idx[model.primary_event] = _moved(
+        model.indicator_idx[model.primary_event], seed, len(model.item_dict))
+
+
+def readings(config: dict, data: dict, seed: int, half) -> dict:
+    """What `compare` reads with, in the program's place: the reference
+    itself, the control (bfloat16), half of the events left out
+    (`half(data)`), and one kept cell moved.  `control.py` prints them."""
+    import ml_dtypes
+
+    algo = config["engine"]["algorithms"][0]["params"]
+    k, thr = int(algo["maxCorrelatorsPerItem"]), float(algo.get("minLlr", 0))
+    primary = config["engine"]["datasource"]["params"]["eventNames"][0]
+
+    def held(tables, rows, cols):
+        return compare(tables, rows, cols, data, k, thr, primary)
+
+    tables, rows, cols = control_tables(data, k, thr, primary, np.float64)
+    out = {"reference": held(tables, rows, cols)}
+    out["control_bfloat16"] = held(*control_tables(
+        data, k, thr, primary, ml_dtypes.bfloat16))
+    out["fault_half_left_out"] = held(*control_tables(
+        half(data), k, thr, primary, np.float64))
+    idx, llr = tables[primary]
+    tables[primary] = (_moved(idx, seed, data["n_items"]), llr)
+    out["fault_answer_altered"] = held(tables, rows, cols)
+    return out

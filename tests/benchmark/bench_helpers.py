@@ -14,11 +14,13 @@ if str(ROOT) not in sys.path:        # the program, wherever pytest started
     sys.path.insert(0, str(ROOT))
 
 
-def load_harness():
-    """`benchmark/run.py` as a module (it is a script, not a package)."""
+def load_harness(script: str = "run.py"):
+    """`benchmark/run.py` (or `control.py`) as a module: they are scripts,
+    not a package."""
     import importlib.util
 
-    spec = importlib.util.spec_from_file_location("bench_run", BENCH / "run.py")
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + script[:-3], BENCH / script)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -52,3 +54,48 @@ def rehearsal_result(stdout: str) -> dict:
     line = last_line(stdout)
     assert line.startswith("REHEARSAL "), line[:200]
     return json.loads(line[len("REHEARSAL "):])
+
+
+def rehearsal_config(harness, cell: str) -> dict:
+    """A cell's configuration at its `rehearsal` sizes, as a rehearsal
+    merges it."""
+    config = harness.find_cell(harness.load_manifest(), cell)[1]
+    return harness.merged(config, {k: v for k, v in
+                                   config.get("rehearsal", {}).items()
+                                   if k != "env"})
+
+
+def run_control(cell: str, seeds: str, root=ROOT) -> list:
+    """`control.py --rehearsal` of the tree at `root` as a child; its lines."""
+    p = subprocess.run(
+        [sys.executable, str(root / "benchmark" / "control.py"), "--workload",
+         cell, "--seeds", seeds, "--rehearsal"],
+        cwd=str(root), env=clean_env(), capture_output=True, text=True,
+        timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return [json.loads(ln) for ln in p.stdout.splitlines() if ln.strip()]
+
+
+def run_faulty(fault: str, cell: str, root=ROOT) -> dict:
+    """`faulty_run.py` of the tree at `root` as a child: a rehearsal with
+    the timed path broken underneath; its result line."""
+    p = subprocess.run(
+        [sys.executable, str(root / "tests" / "benchmark" / "faulty_run.py"),
+         fault, "--workload", cell, "--seed", "2147483999", "--seconds", "1",
+         "--trace", "0", "--rehearsal"],
+        cwd=str(root), env=clean_env(), capture_output=True, text=True,
+        timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return rehearsal_result(p.stdout)
+
+
+def assert_caught(got: dict, fault: str, config: dict) -> None:
+    """Not `correct`, and by a check that is this fault's to catch: a state
+    left unchanged by the driver's own, half of the events left out and an
+    answer altered by one the configuration's limits name."""
+    assert got["correct"] is False
+    failed = {c["name"] for c in got["checks"]
+              if not (c["value"] <= c["limit"])}
+    caught_by = ({"model_not_the_last_jobs"} if fault == "unchanged"
+                 else set(config["reference"]["limits"]))
+    assert failed & caught_by, got["checks"]

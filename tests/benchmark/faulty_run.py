@@ -9,10 +9,16 @@ so the window drives the broken path and `correct` has to come out false:
 
   unchanged   a step that returns its state unchanged: run_train persists
               nothing and hands back the instance it already had
-  half        half of the batch left out: the data source reads every
-              second event only
-  altered     an answer altered where it is produced: one cell of the model
-              changed as it is persisted
+  half        half of the batch left out: the store's columnar reads
+              (`PEventStore.batch`, `PEventStore.native_batch`), beneath the
+              DataSource of every template that trains from events of a
+              user on an item, give every second event only
+  altered     an answer altered where it is produced: `alter(model, seed)`
+              of the configuration's reference module changes the model
+              as it is persisted
+
+This file names no field of a model or of an engine's training data: what a
+model holds is its reference module's to know.
 """
 
 import sys
@@ -26,47 +32,32 @@ import run as harness   # noqa: E402
 
 
 def plant(fault: str, session) -> None:
-    import numpy as np
-
+    from predictionio_tpu.store.event_store import PEventStore
     from predictionio_tpu.workflow import core_workflow, persistence
 
     if fault == "unchanged":
         warm = session.storage.engine_instances.get(session.warm_instance)
         core_workflow.run_train = lambda *a, **k: warm
     elif fault == "half":
-        ds_cls = type(session.engine.make_components(session.params)[0])
-        read = ds_cls.read_training
+        import numpy as np
 
-        def half(self):
-            td = read(self)
-            if hasattr(td, "interactions"):          # UR: per-type COO
-                td.interactions = {
-                    n: (u[::2], i[::2], d, t[::2])
-                    for n, (u, i, d, t) in td.interactions.items()}
-                return td
-            import dataclasses                       # ALS: an EventBatch
+        def halved(read):
+            def every_second(*a, **k):
+                batch = read(*a, **k)
+                if batch is None:          # no columnar read: passes through
+                    return None
+                return batch.subset(np.arange(len(batch)) % 2 == 0)
+            return staticmethod(every_second)
 
-            keep = np.arange(0, len(td.entity_ids), 2)
-            return dataclasses.replace(td, **{
-                f.name: getattr(td, f.name)[keep]
-                for f in dataclasses.fields(td)
-                if isinstance(getattr(td, f.name), np.ndarray)
-                and len(getattr(td, f.name)) == len(td.entity_ids)})
-
-        ds_cls.read_training = half
+        for name in ("batch", "native_batch"):
+            setattr(PEventStore, name, halved(getattr(PEventStore, name)))
     elif fault == "altered":
+        reference = session.ctx["load_module"](
+            "reference", session.config["reference"]["module"])
         save = persistence.save_models
 
         def altered(storage, instance_id, models):
-            m = models[0]
-            if hasattr(m, "indicator_idx"):
-                idx = m.indicator_idx[m.primary_event].copy()
-                row = int(np.flatnonzero((idx >= 0).sum(1) >= 1)[0])
-                idx[row, 0] = (idx[row, 0] + 1) % len(m.item_dict)
-                m.indicator_idx[m.primary_event] = idx
-            else:
-                m.item_factors = np.array(m.item_factors)
-                m.item_factors[0] = 0.0
+            reference.alter(models[0], session.ctx["seed"])
             return save(storage, instance_id, models)
 
         persistence.save_models = altered

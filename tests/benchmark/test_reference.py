@@ -6,7 +6,8 @@ import json
 import numpy as np
 import pytest
 
-from bench_helpers import BENCH, load_harness
+from bench_helpers import (BENCH, load_harness, rehearsal_config,
+                           run_control)
 
 H = load_harness()
 CCO = H.load_module("reference", "cco")
@@ -155,3 +156,97 @@ def test_generators_are_seeded_and_cover_every_id(name, params):
         pairs = r["users"] * params["n_items"] + r["items"]
         assert len(np.unique(pairs)) == params["n_ratings"]
         assert set(np.unique(r["ratings"]).tolist()) <= {1, 2, 3, 4, 5}
+
+
+# -- what a reference module says of its own engine: `alter`, `readings` ------
+
+
+_half = load_harness("control.py")._half     # what `control.py` hands in
+
+
+def test_cco_alter_moves_the_kept_cell_that_readings_moves():
+    from types import SimpleNamespace
+
+    data = _commerce(7)
+    tables, rows, cols = CCO.control_tables(data, 10, 0.0, "buy", np.float64)
+    model = SimpleNamespace(
+        primary_event="buy", item_dict=list(range(data["n_items"])),
+        indicator_idx={n: idx for n, (idx, _) in tables.items()})
+    before = {n: idx.copy() for n, idx in model.indicator_idx.items()}
+    CCO.alter(model, 7)
+    assert np.array_equal(model.indicator_idx["view"], before["view"])
+    assert (model.indicator_idx["buy"] != before["buy"]).sum() == 1
+    assert np.array_equal(tables["buy"][0], before["buy"])    # a copy moved
+    assert np.array_equal(model.indicator_idx["buy"],
+                          CCO._moved(before["buy"], 7, data["n_items"]))
+    got = CCO.compare({n: (model.indicator_idx[n], llr)
+                       for n, (_, llr) in tables.items()},
+                      rows, cols, data, 10, 0.0, "buy")
+    assert max(got.values()) > max(UR_LIMITS.values())
+
+
+def test_cco_moved_draws_its_row_by_the_seed_from_however_few():
+    idx = np.array([[4, -1, -1], [2, 5, -1], [1, 3, 0], [-1, -1, -1]])
+    was = idx.copy()
+    for seed, row in ((0, 1), (1, 2), (2147483777, 2), (4000000006, 1)):
+        got = CCO._moved(idx, seed, 6)
+        assert (got != was).nonzero() == ([row], [0]), (seed, got)
+        assert 0 <= got[row, 0] < 6 and np.array_equal(idx, was)
+
+
+@pytest.mark.parametrize("seed", [7, 4000000007])
+def test_als_alter_zeroes_one_items_factors(seed):
+    from types import SimpleNamespace
+
+    data = _ratings(7)
+    b = data["blocks"][0]
+    nu, ni = data["n_users"], data["n_items"]
+    x, y = ALS.factorize(b["users"], b["items"], b["ratings"], nu, ni,
+                         ALS.start(7, ni, 10), 0.01, 6)
+    model = SimpleNamespace(user_factors=x, item_factors=y)
+    ALS.alter(model, seed)
+    assert model.item_factors is not y and y.any(1).all()     # a copy zeroed
+    assert (model.item_factors != y).any(1).nonzero()[0].tolist() == [
+        seed % ni]
+    got = ALS.compare(x, model.item_factors, np.arange(nu), np.arange(ni),
+                      data, 10, 0.01, 6, 7)
+    assert got["pred_gap_rms"] > ALS_LIMITS["pred_gap_rms"]
+
+
+@pytest.mark.parametrize("cell", ["ur-ecom-100k.train", "als-ml1m.train",
+                                  "ur-ecom-100k-u131k.train"])
+def test_readings_are_the_four_and_only_the_reference_reads_nought(cell):
+    """`readings(config, data, seed, half)` of the configuration's reference
+    module, at rehearsal size: what `control.py` prints."""
+    config = rehearsal_config(H, cell)
+    module = H.load_module("reference", config["reference"]["module"])
+    data = H.load_module("data", config["data"]["generator"]).generate(
+        config["data"]["params"], 4000000007)
+    got = module.readings(config, data, 4000000007, _half)
+    assert list(got) == ["reference", "control_bfloat16",
+                         "fault_half_left_out", "fault_answer_altered"]
+    limits = config["reference"]["limits"]
+    assert all(abs(v) < 1e-9 for v in got["reference"].values())
+    for reading in list(got)[1:]:
+        assert set(limits) <= set(got[reading])
+    # bfloat16 keeps 8 bits; whether that passes a limit is for the cell's
+    # own size to say (PERF.md section 2), a fault fails one at any size
+    assert max(got["control_bfloat16"].values()) > 1e-3, got
+    for fault in ("fault_half_left_out", "fault_answer_altered"):
+        assert any(got[fault][k] > limits[k] for k in limits), got
+
+
+@pytest.mark.parametrize("line", [json.loads(ln) for ln in (
+    BENCH.parent / "tests" / "benchmark" / "data" /
+    "control_rehearsal_pr29.jsonl").read_text().splitlines()],
+    ids=lambda ln: ln["workload"])
+def test_control_prints_what_it_printed_before_the_readings_moved(line):
+    """`control.py --rehearsal` on seed 2147483777, as PR 29's tree printed
+    it when the readings were functions of `control.py` itself."""
+    got, = run_control(line["workload"], str(line["seed"]))
+    assert list(got) == list(line)
+    for reading, numbers in line.items():
+        if isinstance(numbers, dict):
+            assert got[reading] == pytest.approx(numbers, rel=1e-9, abs=1e-15)
+        else:
+            assert got[reading] == numbers

@@ -1,6 +1,7 @@
 """The CPU rehearsal of each cell's command, end to end, in a process of its
 own; what the command does without a chip; and the faults a train cell can
-have, planted under the timed path."""
+have, planted under the timed path.  (A cell of a third engine, added with
+files and entries alone, its faults and its control: test_third_engine.py.)"""
 
 import json
 import shutil
@@ -8,8 +9,8 @@ import sys
 
 import pytest
 
-from bench_helpers import (BENCH, ROOT, clean_env, last_line,
-                           rehearsal_result, run_cell)
+from bench_helpers import (BENCH, ROOT, assert_caught, last_line,
+                           rehearsal_result, run_cell, run_faulty)
 
 CELLS = [w["name"] for w in
          json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
@@ -85,26 +86,10 @@ def test_outside_the_repo_the_command_fails(tmp_path):
 
 
 @pytest.mark.parametrize("cell", CELLS)
-@pytest.mark.parametrize("fault,caught_by", [
-    ("unchanged", {"model_not_the_last_jobs"}),
-    ("half", {"score_gap_max", "topk_gap_max", "pred_gap_rms", "rmse_gap"}),
-    ("altered", {"score_gap_max", "topk_gap_max", "pred_gap_rms", "rmse_gap"}),
-])
-def test_fault_under_the_timed_path_reads_not_correct(cell, fault, caught_by):
-    import subprocess
-
-    p = subprocess.run(
-        [sys.executable, str(ROOT / "tests" / "benchmark" / "faulty_run.py"),
-         fault, "--workload", cell, "--seed", "2147483999", "--seconds", "1",
-         "--trace", "0", "--rehearsal"],
-        cwd=str(ROOT), env=clean_env(), capture_output=True, text=True,
-        timeout=600)
-    assert p.returncode == 0, p.stderr[-3000:]
-    got = rehearsal_result(p.stdout)
-    assert got["correct"] is False
-    failed = {c["name"] for c in got["checks"]
-              if not (c["value"] <= c["limit"])}
-    assert failed & caught_by, got["checks"]
+@pytest.mark.parametrize("fault", ["unchanged", "half", "altered"])
+def test_fault_under_the_timed_path_reads_not_correct(cell, fault, harness):
+    config = harness.find_cell(MANIFEST, cell)[1]
+    assert_caught(run_faulty(fault, cell), fault, config)
 
 
 def test_a_cell_is_added_with_files_and_entries_alone(tmp_path):
