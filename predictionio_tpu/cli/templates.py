@@ -82,12 +82,15 @@ TEMPLATE_VARIANTS: Dict[str, Dict] = {
         "id": "my-complementary-purchase",
         "description": "shopping-basket rules: cart -> complementary items",
         "engineFactory": ENGINE_FACTORIES["complementary_purchase"],
-        "datasource": {"params": {"appName": "MyApp", "eventName": "buy",
-                                  "basketWindow": "1 hour"}},
+        "datasource": {"params": {"appName": "MyApp"}},
         "algorithms": [
+            # the template's own keys; its published cuts (minSupport 0.1,
+            # minConfidence 0.6) are fitted to a shop, so start low
             {"name": "rules",
-             "params": {"minSupport": 0.001, "minConfidence": 0.1,
-                        "maxRulesPerItem": 20}},
+             "params": {"basketWindow": 3600, "maxRuleLength": 2,
+                        "minSupport": 0.001, "minConfidence": 0.1,
+                        "minLift": 1.0, "minBasketSize": 2,
+                        "maxNumRulesPerCond": 20}},
         ],
     },
     "product_ranking": {

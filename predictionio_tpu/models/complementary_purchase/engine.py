@@ -1,21 +1,51 @@
 """Complementary Purchase engine template (shopping-basket rules).
 
-Capability parity with the reference Complementary Purchase template
-(PredictionIO 0.9.x gallery — DataSource.scala groups a user's ``buy``
-events into baskets by time window; the algorithm mines frequent itemsets
-with FP-Growth on Spark and emits rules filtered by minSupport /
-minConfidence, ranked by lift; query = current cart → complementary
-items).
+The template is ``apache/predictionio-template-complementary-purchase``;
+its published ``engine.json`` loads here key for key: ``appName`` on the
+data source, and on the one algorithm ``basketWindow`` (seconds),
+``maxRuleLength``, ``minSupport``, ``minConfidence``, ``minLift``,
+``minBasketSize``, ``maxNumRulesPerCond``.  Semantics, as the template's
+documentation describes them:
 
-TPU-first redesign, not a translation: FP-Growth's tree mining is a
-sequential pointer-chasing algorithm with no MXU mapping.  The dominant
-rule mass is pairwise, and pair counts over all item pairs at once are
-exactly one basket×item scatter-densify plus one MXU matmul (BᵀB) —
-``ops.cco.basket_rules`` computes every support/confidence/lift in a
-single compiled program and keeps the per-item top-k by lift.  Larger
-antecedent carts are served by aggregating the single-item rules over the
-cart on device (same gather+scatter scorer the similar-product template
-uses), which is the cross-occurrence analogue of set rules.
+- a basket is one user's ``buy`` events, each within ``basketWindow``
+  seconds of the one before; a basket of fewer than ``minBasketSize``
+  distinct items is dropped; N is the number of baskets kept;
+- for items i ≠ j: support = c_ij / N, confidence(i → j) = c_ij / c_i,
+  lift = confidence / (c_j / N); a rule is kept at support ≥
+  ``minSupport``, confidence ≥ ``minConfidence`` and lift ≥ ``minLift``;
+  each condition item keeps its ``maxNumRulesPerCond`` best by lift.
+
+TPU-first redesign, not a translation: the template mines frequent
+itemsets (FP-Growth on Spark), a sequential pointer-chasing algorithm with
+no MXU mapping.  At ``maxRuleLength`` 2 its rules are exactly the pair
+rules, and pair counts over all item pairs at once are one basket×item
+scatter-densify plus one MXU matmul (BᵀB): ``ops.cco.basket_rules``
+computes every support/confidence/lift in one compiled program and keeps
+the per-item top-k by lift.  Carts of several items are served by
+aggregating the single-item rules over the cart on device (the
+gather+scatter scorer the similar-product template uses).
+
+Departures from the template, each also under ``assumed`` of
+``benchmark/configs/cp-ecom-100k.json``:
+
+- ``maxRuleLength`` above 2 is refused: rules with two or more condition
+  items are not computed, and nothing stands in for them;
+- N counts the baskets kept after the ``minBasketSize`` drop (the
+  documentation, as remembered, does not say whether dropped baskets
+  count towards support);
+- a gap of exactly ``basketWindow`` stays in the basket (``>`` splits);
+- the cuts are compared as float32 products of counts (c ≥ minSupport·N,
+  c ≥ minConfidence·c_i, c·N ≥ minLift·c_i·c_j), exact while the products
+  fit float32's 24 bits; the benchmark's reference forgives a rule whose
+  float64 value lies within 1e-6 of a cut;
+- rules of equal lift are kept in the order the merge finds them;
+- the defaults of ``CPAlgorithmParams`` are this repo's (no cut, baskets
+  of one item kept, 20 rules, a window of one hour), not the published
+  file's values: an engine.json states its own.
+
+Two older spellings still load: ``maxRulesPerItem`` (=
+``maxNumRulesPerCond``), and ``basketWindow`` as a duration string on
+the data source, which feeds the same ``basket_window_seconds``.
 
 Wire format (reference template):
   query    {"items": ["i1", "i2"], "num": 3}
@@ -25,7 +55,7 @@ Wire format (reference template):
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +75,7 @@ from predictionio_tpu.models.common import CategoryRulesMixin
 from predictionio_tpu.models.recommendation.engine import ItemScore, PredictedResult
 from predictionio_tpu.ops.als import indicator_scatter_scores as _indicator_scatter_scores
 from predictionio_tpu.ops import als as als_ops
+from predictionio_tpu.obs.spans import span
 from predictionio_tpu.ops import cco as cco_ops
 from predictionio_tpu.store.columnar import IdDict
 from predictionio_tpu.store.event_store import PEventStore
@@ -66,23 +97,23 @@ class CPQuery:
 class CPDataSourceParams(Params):
     app_name: str = "default"
     event_name: str = "buy"
-    # events of one user closer together than this belong to one basket
-    # (reference DataSource basketWindow)
-    basket_window: str = "1 hour"
+    # the older place of the basket window, a duration string ("10
+    # minutes"); the template has it on the algorithm, in seconds
+    basket_window: Optional[str] = None
 
 
 @dataclasses.dataclass
 class CPTrainingData:
-    basket_idx: np.ndarray    # int32 per event
-    item_idx: np.ndarray
-    n_baskets: int
+    user_idx: np.ndarray      # int32 per buy event
+    item_idx: np.ndarray      # int32 per buy event
+    times_us: np.ndarray      # int64 per buy event
     item_dict: IdDict
+    basket_window: Optional[str] = None   # the data source's, if it set one
 
 
 class CPDataSource(DataSource):
-    """Reads buy events and sessionizes them into baskets: one columnar
-    read, then a vectorized (user, time)-sort with baskets split on user
-    change or a time gap beyond basket_window."""
+    """Reads the buy events in one columnar read: who, what, when.  The
+    baskets are the algorithm's to form (its ``basketWindow``)."""
 
     params_class = CPDataSourceParams
 
@@ -90,29 +121,17 @@ class CPDataSource(DataSource):
         batch = PEventStore.batch(
             self.params.app_name, event_names=[self.params.event_name])
         has_t = batch.target_ids >= 0
-        users = batch.entity_ids[has_t]
         t_codes = batch.target_ids[has_t]
-        times = batch.times_us[has_t].astype(np.int64)
         uniq = np.unique(t_codes)
         item_dict = IdDict([batch.target_dict.str(int(c)) for c in uniq])
         t_map = np.full(max(len(batch.target_dict), 1), -1, np.int32)
         t_map[uniq] = np.arange(len(uniq), dtype=np.int32)
-        items = t_map[t_codes]
-        if len(users) == 0:
-            return CPTrainingData(np.empty(0, np.int32), np.empty(0, np.int32),
-                                  0, item_dict)
-        order = np.lexsort((times, users))
-        users, items, times = users[order], items[order], times[order]
-        window_us = int(parse_duration(self.params.basket_window) * 1e6)
-        new_basket = np.ones(len(users), bool)
-        new_basket[1:] = (users[1:] != users[:-1]) | (
-            (times[1:] - times[:-1]) > window_us)
-        basket_idx = (np.cumsum(new_basket) - 1).astype(np.int32)
         return CPTrainingData(
-            basket_idx=basket_idx,
-            item_idx=items.astype(np.int32),
-            n_baskets=int(basket_idx[-1]) + 1,
+            user_idx=batch.entity_ids[has_t].astype(np.int32),
+            item_idx=t_map[t_codes],
+            times_us=batch.times_us[has_t].astype(np.int64),
             item_dict=item_dict,
+            basket_window=self.params.basket_window,
         )
 
 
@@ -121,13 +140,53 @@ class CPPreparator(Preparator):
         return td
 
 
+_OLDER_KEYS = {"maxRulesPerItem": "maxNumRulesPerCond",
+               "max_rules_per_item": "max_num_rules_per_cond"}
+
+
 @dataclasses.dataclass
 class CPAlgorithmParams(Params):
-    # reference Complementary Purchase: minSupport / minConfidence cuts,
-    # rules ranked by lift
+    """The template's algorithm parameters, under its own names."""
+
+    basket_window: Optional[float] = None    # seconds
+    max_rule_length: int = 2
     min_support: float = 0.0
     min_confidence: float = 0.0
-    max_rules_per_item: int = 20
+    min_lift: float = 0.0
+    min_basket_size: int = 1
+    max_num_rules_per_cond: int = 20
+    # not the template's: the program's item tile, as UR's ``itemTile``
+    item_tile: int = 4096
+
+    def __post_init__(self):
+        if self.max_rule_length != 2:
+            raise ValueError(
+                f"maxRuleLength {self.max_rule_length}: only pair rules "
+                "(one condition item -> one item, maxRuleLength 2) are "
+                "computed; rules over larger itemsets cannot run and are "
+                "not approximated")
+
+    @classmethod
+    def from_json(cls, data):
+        if isinstance(data, dict):
+            data = {_OLDER_KEYS.get(k, k): v for k, v in data.items()}
+        return super().from_json(data)
+
+
+def basket_window_seconds(params: CPAlgorithmParams,
+                          td: CPTrainingData) -> float:
+    """The one place that decides the basket window: the algorithm's
+    ``basketWindow`` (seconds), else the data source's older duration
+    string, else one hour; two that disagree are an error."""
+    older = (None if td.basket_window is None
+             else float(parse_duration(td.basket_window)))
+    if params.basket_window is None:
+        return 3600.0 if older is None else older
+    if older is not None and older != float(params.basket_window):
+        raise ValueError(
+            f"basketWindow is {params.basket_window} s on the algorithm and "
+            f"{td.basket_window!r} on the data source: set one")
+    return float(params.basket_window)
 
 
 class CPModel(CategoryRulesMixin, PersistentModel):
@@ -171,17 +230,24 @@ class CPAlgorithm(Algorithm):
     params_class = CPAlgorithmParams
 
     def train(self, td: CPTrainingData) -> CPModel:
+        p = self.params
         n_items = len(td.item_dict)
-        if n_items == 0 or td.n_baskets == 0:
-            k = max(self.params.max_rules_per_item, 1)
+        window_us = int(basket_window_seconds(p, td) * 1e6)
+        with span("layout", events=len(td.user_idx)) as rec:
+            basket_idx, item_idx, n_baskets = cco_ops.session_baskets(
+                td.user_idx, td.item_idx, td.times_us, window_us)
+            rec["attrs"]["baskets_formed"] = n_baskets
+        if n_items == 0 or n_baskets == 0:
+            k = max(p.max_num_rules_per_cond, 1)
             return CPModel(td.item_dict,
                            np.full((n_items, k), -1, np.int32),
                            np.full((n_items, k), -np.inf, np.float32))
         lift, idx, _conf = cco_ops.basket_rules(
-            td.basket_idx, td.item_idx, td.n_baskets, n_items,
-            top_k=self.params.max_rules_per_item,
-            min_support=self.params.min_support,
-            min_confidence=self.params.min_confidence)
+            basket_idx, item_idx, n_baskets, n_items,
+            top_k=p.max_num_rules_per_cond,
+            min_support=p.min_support, min_confidence=p.min_confidence,
+            min_lift=p.min_lift, min_basket_size=p.min_basket_size,
+            item_tile=p.item_tile)
         return CPModel(td.item_dict, idx, lift)
 
     def warm(self, model: CPModel) -> None:

@@ -16,8 +16,10 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import logging
+import os
 import shutil
 import subprocess
+import tempfile
 from pathlib import Path
 from typing import Optional
 
@@ -49,7 +51,14 @@ def artifact_path(src: Path, stem: str) -> Path:
 def build(src: Path, stem: str, timeout: int = 300) -> Path:
     """Compile ``src`` into its content-keyed artifact (no-op when the
     artifact already exists).  Raises on any build failure — callers
-    that want graceful degradation wrap this (``load``)."""
+    that want graceful degradation wrap this (``load``).
+
+    Safe under concurrent first use (six test workers on a fresh
+    checkout): each builder compiles into a temporary file of its own
+    and renames it onto the artifact, so whoever finishes first wins and
+    the others replace it with identical bytes or, where their own
+    compile fails, load the winner's file.  Only artifacts of OTHER keys
+    (older sources) are removed, and only after this key's is in place."""
     so = artifact_path(src, stem)
     if so.exists():
         return so
@@ -57,15 +66,23 @@ def build(src: Path, stem: str, timeout: int = 300) -> Path:
     if cxx is None:
         raise RuntimeError("no C++ compiler on PATH")
     BUILD_DIR.mkdir(exist_ok=True)
-    for old in BUILD_DIR.glob(f"{stem}-*.so"):
-        old.unlink(missing_ok=True)
-    tmp = so.with_suffix(".so.tmp")
+    fd, tmp_name = tempfile.mkstemp(prefix=f"{so.name}.", suffix=".tmp",
+                                    dir=BUILD_DIR)
+    os.close(fd)
+    tmp = Path(tmp_name)
     cmd = [cxx, "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            str(src), "-o", str(tmp)]
-    subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
-    # rename-into-place: a concurrent builder (two processes racing the
-    # first use) never loads a half-written .so
-    tmp.replace(so)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
+        # rename-into-place: nobody ever loads a half-written .so
+        tmp.replace(so)
+    except Exception:
+        tmp.unlink(missing_ok=True)
+        if not so.exists():     # a sibling may have finished meanwhile
+            raise
+    for old in BUILD_DIR.glob(f"{stem}-*.so"):
+        if old != so:
+            old.unlink(missing_ok=True)
     return so
 
 

@@ -1502,123 +1502,113 @@ def _cco_chunked(
 # basket association rules (Complementary Purchase template)
 # ---------------------------------------------------------------------------
 
-
-# Dense [I, I] rule matrix up to here (int32 counts + fused f32 score
-# pass ≈ 2 GB at the cap); past it the item-tiled variant runs — no
-# catalog-size cliff (the reference's FP-Growth scales by distributing
-# frequent-pair mining; here the tile loop plays that role)
-_BASKET_RULES_DENSE_MAX_ITEMS = 16_384
-_BASKET_CHUNK = 8192          # basket rows densified per scan step
-_BASKET_CHUNK_BYTES = 512 << 20   # per-chunk densified-B budget (tiled)
-_BASKET_TILE_BYTES = 2 << 30      # per-tile [I, tile] working-set budget
-
-# Exactness: pair counts accumulate as int32 — exact to 2³¹, and
-# c_ij ≤ n_baskets so overflow is impossible below the guard in
-# basket_rules.  Ratio math (support/confidence/lift) runs in f32, so
-# counts above 2²⁴ lose ULP-level precision there: rule RANKING can
-# perturb only among near-ties; the counts themselves stay exact.
+# The count program of the tiled strategies with baskets for users and the
+# items themselves for the other event type (C = BᵀB), and lift under the
+# template's three cuts in LLR's place.  Pair counts accumulate as int32:
+# exact to 2³¹, and c_ij ≤ n_baskets, which basket_rules guards.  The
+# cuts and the lift are float32 products of counts (see _basket_scores).
 
 
-def _basket_scores(c, ci_row, ci_col, n, min_support, min_confidence):
-    """Fused per-cell rule scoring: lift where support/confidence cuts
-    pass, else -inf.  All intermediates are elementwise expressions XLA
-    fuses into one pass — nothing beyond the scores is materialized (the
-    old path take_along_axis'd a full confidence matrix)."""
-    support = c / n
-    confidence = c / jnp.maximum(ci_row, 1.0)
-    lift = confidence / jnp.maximum(ci_col / n, 1e-9)
-    ok = (support >= min_support) & (confidence >= min_confidence) & (c > 0)
+def session_baskets(user: np.ndarray, item: np.ndarray, time_us: np.ndarray,
+                    window_us: int) -> Tuple[np.ndarray, np.ndarray, int]:
+    """One user's events, each within ``window_us`` of the one before, are
+    one basket: ``(basket id an event, its item, baskets)`` in (user, time)
+    order.  A gap of exactly the window stays in the basket."""
+    user, time_us = np.asarray(user), np.asarray(time_us, np.int64)
+    if not len(user):
+        return np.empty(0, np.int32), np.empty(0, np.int32), 0
+    order = np.lexsort((time_us, user))
+    user, time_us = user[order], time_us[order]
+    new = np.ones(len(user), bool)
+    new[1:] = (user[1:] != user[:-1]) | (time_us[1:] - time_us[:-1] > window_us)
+    basket = (np.cumsum(new) - 1).astype(np.int32)
+    return basket, np.asarray(item, np.int32)[order], int(basket[-1]) + 1
+
+
+def _basket_plan(n_baskets: int, n_items: int,
+                 item_tile: int) -> Tuple[int, int, int, int]:
+    """``(tile, n_tiles, chunk, n_chunks)`` of the basket program, by
+    ``_plan``'s accounting against the same ``_TILED_P_BYTES``: what the
+    compiler holds for ``_basket_rules_tiled`` is, per tile, the carried
+    int32 count tile and the float32 scores made from it (2 × I × tile ×
+    4), and the densified chunk of baskets [chunk, I] in the count
+    matmul's input type three times over (the zero fill, the flat
+    scatter's result, and its copy re-laid as a matrix).  The tile is UR's
+    (``item_tile`` against the catalogue); the chunk is the largest power
+    of two of baskets that leaves, since the chunk is the count matmul's
+    contraction (13,312 ran the matmul 14% slower than 8,192 on a v5e:
+    chip run, PR 31), or all the baskets where they are fewer.  At 65,536
+    × 100,000, tile 4,096, bf16: 8 chunks of 8,192 × 25 tiles, 3 × 1.68 +
+    2 × 1.68 = 8.39 GB, which is the TPU compiler's own plan [AOT, PR 31]
+    (the chip's peak read 5.05 GB: chip run, PR 31)."""
+    tile = min(item_tile, max(n_items, 1))
+    n_tiles = math.ceil(max(n_items, 1) / tile)
+    width = n_tiles * tile
+    per_basket = 3 * width * (1 if _matmul_dtype() == "int8" else 2)
+    room = _TILED_P_BYTES - 2 * width * tile * 4
+    chunk = 1 << max(room // per_basket, 256).bit_length() - 1
+    chunk = min(chunk, max(math.ceil(n_baskets / 256) * 256, 256))
+    return tile, n_tiles, chunk, max(math.ceil(n_baskets / chunk), 1)
+
+
+def _basket_scores(c, ci_row, ci_col, n, min_support, min_confidence,
+                   min_lift):
+    """Per-cell rule scoring, one fused elementwise pass: lift =
+    c·N / (c_i·c_j) where support c/N, confidence c/c_i and lift pass
+    their cuts, else -inf.  Each cut is compared as a product of counts
+    (c ≥ minSupport·N, c ≥ minConfidence·c_i, c·N ≥ minLift·c_i·c_j), not
+    as a rounded ratio: a product of integers that float32 holds (24
+    bits, or a power of two times fewer) is exact, so a rule that sits ON
+    a cut (lift exactly 1.0 at N a power of two) is kept as float64 keeps
+    it; past 24 bits each side rounds once."""
+    pair = ci_row * ci_col
+    lift = c * n / jnp.maximum(pair, 1.0)
+    ok = ((c > 0) & (c >= min_support * n) & (c >= min_confidence * ci_row)
+          & (c * n >= min_lift * pair))
     return jnp.where(ok, lift, -jnp.inf)
 
 
-@partial(jax.jit, static_argnames=("n_chunks", "n_items", "top_k"))
-def _basket_rules(gb, gi, valid, n_baskets, n_chunks: int, n_items: int,
-                  top_k: int, min_support, min_confidence):
-    """Pairwise association rules from basket×item co-occurrence (dense).
-
-    Baskets are densified in fixed chunks (lax.scan) and pair counts
-    accumulate as exact int32 — ``C += int32(Bcᵀ Bc)`` with each chunk's
-    f32 product < 2²⁴ by construction, the same exactness recipe as
-    ``_count_matmul``'s chunked callers — and HBM holds one chunk + the
-    [I, I] counts.  Then per (i, j):
-
-      support_ij    = c_ij / N            confidence_i→j = c_ij / c_i
-      lift_i→j      = confidence / (c_j / N)
-
-    Rules failing min_support/min_confidence are -inf; per-row top-k by
-    LIFT (the reference Complementary Purchase template also ranks rules
-    by lift after support/confidence cuts — its FP-Growth mines item-SET
-    antecedents, which serving approximates by aggregating single-item
-    rules over the cart).  Self-pairs are excluded.  See the exactness
-    note above _basket_scores.
-    """
-    mm = _matmul_dtype()
-
-    def body(c_acc, chunk_start):
-        in_chunk = valid & (gb >= chunk_start) & (gb < chunk_start + _BASKET_CHUNK)
-        B = _densify(jnp.where(in_chunk, gb - chunk_start, 0), gi,
-                     in_chunk.astype(jnp.float32), _BASKET_CHUNK, n_items,
-                     _mm_in_dtype())
-        return c_acc + _count_matmul(B, B, mm), None
-
-    starts = jnp.arange(n_chunks, dtype=jnp.int32) * _BASKET_CHUNK
-    c, _ = jax.lax.scan(body, jnp.zeros((n_items, n_items), jnp.int32), starts)
-    c = c.astype(jnp.float32)
-    ci = jnp.diagonal(c)                             # per-item basket counts
-    n = jnp.maximum(n_baskets.astype(jnp.float32), 1.0)
-    scores = _basket_scores(c, ci[:, None], ci[None, :], n,
-                            min_support, min_confidence)
-    eye = jnp.eye(n_items, dtype=bool)
-    scores = jnp.where(eye, -jnp.inf, scores)
-    st, si = jax.lax.top_k(scores, top_k)
-    return st, si.astype(jnp.int32)
-
-
 @partial(jax.jit, static_argnames=(
-    "n_chunks", "chunk", "n_items", "n_tiles", "tile", "top_k", "topk"))
+    "chunk", "n_tiles", "tile", "top_k", "topk", "mm"))
 def _basket_rules_tiled(
-    gb, gi, valid, n_baskets, ci,
-    n_chunks: int, chunk: int, n_items: int, n_tiles: int, tile: int,
-    top_k: int, min_support, min_confidence, topk: str,
+    lu, it, cnt, n_baskets, ci,
+    chunk: int, n_tiles: int, tile: int, top_k: int,
+    min_support, min_confidence, min_lift, topk: str, mm: str,
 ):
-    """Item-tiled basket rules: the [I, I] matrix never materializes —
-    per tile, C_tile [I, tile] accumulates over basket chunks on the MXU
-    and merges into a running top-k (_merge_topk, same lax/pallas switch
-    as the UR tiled path).  ``ci`` is the exact per-item basket count
-    computed on host from deduped pairs (== the dense path's diagonal)."""
-    mm = _matmul_dtype()
-    n = jnp.maximum(n_baskets.astype(jnp.float32), 1.0)
-    ci_f = ci.astype(jnp.float32)
+    """Every item tile of the basket rules in one compiled program
+    (_scan_tiles): per tile, C_tile [I, tile] accumulates over the basket
+    chunks on the MXU — each chunk densified from its own slots of the
+    chunk-grouped log (``lu``/``it``/``cnt``: block_interactions' layout),
+    the tile's slab a slice of it — then scores and merges into the
+    running top-k (_merge_topk).  I is the catalogue padded to whole
+    tiles; ``ci`` [I] float32 is the exact per-item basket count from the
+    host."""
+    width = n_tiles * tile
+    slots = lu.shape[1]
+    in_dtype = jnp.int8 if mm == "int8" else jnp.bfloat16
 
-    def tile_step(bs, bi_, tile_start):
-        def body(c_acc, chunk_start):
-            in_chunk = valid & (gb >= chunk_start) & (gb < chunk_start + chunk)
-            B = _densify(jnp.where(in_chunk, gb - chunk_start, 0), gi,
-                         in_chunk.astype(jnp.float32), chunk, n_items,
-                         _mm_in_dtype())
-            a_local = gi - tile_start
-            in_tile = in_chunk & (a_local >= 0) & (a_local < tile)
-            Bt = _densify(jnp.where(in_tile, gb - chunk_start, 0),
-                          jnp.where(in_tile, a_local, 0),
-                          in_tile.astype(jnp.float32), chunk, tile,
-                          _mm_in_dtype())
-            return c_acc + _count_matmul(B, Bt, mm), None
+    def tile_step(bs, bi, tile_start):
+        def body(c_acc, xs):
+            blu, bit, bcnt = xs
+            with jax.named_scope("basket.densify"):
+                valid = jax.lax.iota(jnp.int32, slots) < bcnt
+                B = _densify(blu, bit, valid, chunk, width, in_dtype)
+                Bt = jax.lax.dynamic_slice(B, (0, tile_start), (chunk, tile))
+            with jax.named_scope("basket.count_matmul"):
+                return c_acc + _count_matmul(B, Bt, mm), None
 
-        starts = jnp.arange(n_chunks, dtype=jnp.int32) * chunk
-        c, _ = jax.lax.scan(
-            body, jnp.zeros((n_items, tile), jnp.int32), starts)
-        tile_ids = tile_start + jnp.arange(tile, dtype=jnp.int32)
-        in_range = tile_ids < n_items
-        ci_col = ci_f[jnp.where(in_range, tile_ids, 0)]
-        scores = _basket_scores(
-            c.astype(jnp.float32), ci_f[:, None], ci_col[None, :], n,
-            min_support, min_confidence)
-        scores = jnp.where(in_range[None, :], scores, -jnp.inf)
+        c, _ = jax.lax.scan(body, jnp.zeros((width, tile), jnp.int32),
+                            (lu, it, cnt))
+        with jax.named_scope("basket.score"):
+            ci_col = jax.lax.dynamic_slice(ci, (tile_start,), (tile,))
+            scores = _basket_scores(
+                c.astype(jnp.float32), ci[:, None], ci_col[None, :],
+                n_baskets, min_support, min_confidence, min_lift)
         # exclude_self masks the diagonal inside the merge
-        return _merge_topk(bs, bi_, scores, tile_start, tile, top_k,
-                           n_items, exclude_self=True, impl=topk)
+        return _merge_topk(bs, bi, scores, tile_start, tile, top_k, width,
+                           exclude_self=True, impl=topk)
 
-    return _scan_tiles(tile_step, n_items, n_tiles, tile, top_k,
+    return _scan_tiles(tile_step, width, n_tiles, tile, top_k,
                        carry_k=_carry_width(top_k, topk))
 
 
@@ -1628,58 +1618,63 @@ def basket_rules(
     top_k: int = 20,
     min_support: float = 0.0,
     min_confidence: float = 0.0,
+    min_lift: float = 0.0,
+    min_basket_size: int = 1,
     item_tile: int = 4096,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host wrapper: (lift [I, K], complement ids [I, K], confidence
-    [I, K]) with -1 ids where no rule passed the cuts.
+    """Pair rules i → j from (basket, item) pairs with basket ids in
+    [0, n_baskets): (lift [I, K], complement ids [I, K], confidence
+    [I, K]), -1 ids where no rule passed the cuts.
 
-    Dense [I, I] strategy below _BASKET_RULES_DENSE_MAX_ITEMS, item-tiled
-    beyond — any catalog size works.  Confidence is derived from the
-    top-k lift (conf = lift·c_j/N), so no full confidence matrix is ever
-    materialized on either strategy.
+    A basket of fewer than ``min_basket_size`` distinct items is dropped
+    (so is one without any item); N is the number kept.  For i ≠ j over
+    the N baskets: support = c_ij / N, confidence = c_ij / c_i, lift =
+    confidence / (c_j / N); a rule passes at support ≥ min_support,
+    confidence ≥ min_confidence and lift ≥ min_lift, and each item keeps
+    its ``top_k`` best by lift.  One device program at every size
+    (_basket_rules_tiled, tile and chunk from _basket_plan); confidence
+    is derived from the kept lifts (conf = lift·c_j / N), so no
+    confidence matrix exists anywhere.
     """
     if n_baskets >= (1 << 31):
         raise ValueError(
             f"{n_baskets} baskets would overflow the int32 pair-count "
             "accumulator (exact to 2^31); shard the basket log first")
     k = min(max(top_k, 1), max(n_items, 1))
-    gb = jnp.asarray(basket_idx, jnp.int32)
-    gi = jnp.asarray(item_idx, jnp.int32)
-    valid = jnp.ones(len(basket_idx), bool)
-    # exact per-item basket counts from deduped pairs (== dense diagonal)
-    _, di = dedup_pairs(basket_idx, item_idx, n_items)
-    ci = np.bincount(di, minlength=n_items).astype(np.int64)
-    if n_items <= _BASKET_RULES_DENSE_MAX_ITEMS:
-        n_chunks = max(math.ceil(n_baskets / _BASKET_CHUNK), 1)
-        st, si = _basket_rules(
-            gb, gi, valid, jnp.int32(n_baskets), n_chunks, n_items, k,
-            jnp.float32(min_support), jnp.float32(min_confidence))
-    else:
-        bytes_per = 2 if _matmul_dtype() == "bf16" else 1
-        chunk = max(256, min(
-            _BASKET_CHUNK,
-            (_BASKET_CHUNK_BYTES // max(n_items * bytes_per, 1)) // 256 * 256,
-            math.ceil(max(n_baskets, 1) / 256) * 256))  # few baskets: no pad waste
-        n_chunks = max(math.ceil(n_baskets / chunk), 1)
-        # the per-tile working set ([I, tile] int32 counts + f32 scores +
-        # the top-k merge buffer ≈ 12 bytes/cell) scales with the CATALOG,
-        # so the tile auto-shrinks to the budget — no size cliff, just
-        # more tiles for very large catalogs
-        tile_cap = max((_BASKET_TILE_BYTES // max(n_items * 12, 1))
-                       // 128 * 128, 128)
-        tile = min(item_tile, tile_cap, max(n_items, 1))
-        n_tiles = math.ceil(n_items / tile)
-        st, si = _basket_rules_tiled(
-            gb, gi, valid, jnp.int32(n_baskets), jnp.asarray(ci, jnp.float32),
-            n_chunks, chunk, n_items, n_tiles, tile, k,
-            jnp.float32(min_support), jnp.float32(min_confidence),
-            topk_impl())
-    st, si = np.asarray(st)[:, :k], np.asarray(si)[:, :k]
-    dead = ~np.isfinite(st) | (si < 0) | (si >= n_items)
-    si = np.where(dead, -1, si).astype(np.int32)
-    st = np.where(dead, -np.inf, st)
+    with span("layout", events=len(basket_idx)) as rec:
+        # distinct (basket, item) pairs: the sizes, the drop and the exact
+        # per-item basket counts all come from them
+        gb, gi = dedup_pairs(basket_idx, item_idx, n_items)
+        sizes = np.bincount(gb, minlength=max(n_baskets, 1))
+        kept = sizes >= max(int(min_basket_size), 1)
+        n_kept = int(kept.sum())
+        pair_kept = kept[gb]
+        gb = (np.cumsum(kept) - 1).astype(np.int32)[gb[pair_kept]]
+        gi = gi[pair_kept]
+        ci = np.bincount(gi, minlength=n_items)
+        tile, n_tiles, chunk, n_chunks = _basket_plan(n_kept, n_items,
+                                                      item_tile)
+        blocks = block_interactions(gb, gi, n_chunks * chunk, n_items,
+                                    user_block=chunk)
+        ci_pad = np.zeros(n_tiles * tile, np.float32)
+        ci_pad[:n_items] = ci
+        rec["attrs"].update(baskets=n_kept, baskets_dropped=int(
+            (sizes > 0).sum()) - n_kept)
+    host_args = (blocks.local_u, blocks.item, blocks.count, ci_pad)
+    with span("h2d", bytes=sum(a.nbytes for a in host_args)):
+        lu, it, cnt, ci_dev = (jnp.asarray(a) for a in host_args)
+    topk = topk_impl()
+    with span("dispatch", program="_basket_rules_tiled", topk=topk,
+              tiles=n_tiles, chunks=n_chunks, steps=n_tiles * n_chunks):
+        best_scores, best_idx = _basket_rules_tiled(
+            lu, it, cnt, jnp.float32(max(n_kept, 1)), ci_dev,
+            chunk=chunk, n_tiles=n_tiles, tile=tile, top_k=k,
+            min_support=jnp.float32(min_support),
+            min_confidence=jnp.float32(min_confidence),
+            min_lift=jnp.float32(min_lift), topk=topk, mm=_matmul_dtype())
+    st, si = _finalize_topk(best_scores, best_idx, n_items, k)
+    st, si = st[:n_items], si[:n_items].astype(np.int32)
     # conf = lift·c_j/N, from the exact int64 host counts (-inf lifts are
     # zeroed before the multiply so no NaN transient appears)
-    n = max(float(n_baskets), 1.0)
-    conf = np.where(dead, 0.0, st) * ci[np.maximum(si, 0)] / n
+    conf = np.where(si < 0, 0.0, st) * ci[np.maximum(si, 0)] / max(n_kept, 1)
     return st, si, conf

@@ -16,7 +16,8 @@ from predictionio_tpu.models.complementary_purchase.engine import (
     CPAlgorithmParams,
     CPDataSourceParams,
 )
-from predictionio_tpu.ops.cco import basket_rules
+from predictionio_tpu.ops import cco as cco_ops
+from predictionio_tpu.ops.cco import basket_rules, session_baskets
 from predictionio_tpu.storage import App
 
 APP = "cpapp"
@@ -58,10 +59,13 @@ def test_basket_sessionization(cp_app):
     engine = ComplementaryPurchaseEngine.apply()
     ds = engine.make_components(make_ep())[0]
     td = ds.read_training()
+    # the baskets are the algorithm's to form, by its window (an hour here)
+    basket_idx, _, n_baskets = session_baskets(
+        td.user_idx, td.item_idx, td.times_us, 3600 * 10**6)
     # 60 users x 3 days = 180 baskets
-    assert td.n_baskets == 180
+    assert n_baskets == 180
     # every basket holds 2 or 3 items
-    sizes = np.bincount(td.basket_idx)
+    sizes = np.bincount(basket_idx)
     assert set(sizes.tolist()) <= {2, 3}
 
 
@@ -123,15 +127,47 @@ def test_model_roundtrip(cp_app):
             == engine.predictor(ep, restored)(q).to_json())
 
 
+def _small_plan(monkeypatch, chunk: int):
+    """Basket chunks of ``chunk`` rows: the plan's budget shrunk to what two
+    count tiles and three such chunks take (see _basket_plan)."""
+    real = cco_ops._basket_plan
+
+    def plan(n_baskets, n_items, item_tile):
+        tile, n_tiles, _, _ = real(n_baskets, n_items, item_tile)
+        width = n_tiles * tile
+        monkeypatch.setattr(cco_ops, "_TILED_P_BYTES",
+                            2 * width * tile * 4 + chunk * 3 * width * 2)
+        return real(n_baskets, n_items, item_tile)
+
+    monkeypatch.setattr(cco_ops, "_basket_plan", plan)
+
+
+def test_the_plan_of_the_basket_program_at_the_cells_size(monkeypatch):
+    """65,536 baskets x 100,000 items: 25 tiles of 4,096 and 8 chunks of
+    8,192, planned at 8.39 GB of the 12 GB the rule has; a shop of few
+    baskets is one chunk, a chip half the size halves the chunk."""
+    monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
+    assert cco_ops._basket_plan(65_536, 100_000, 4096) == (4096, 25, 8192, 8)
+    width = 25 * 4096
+    assert 3 * 8192 * width * 2 + 2 * width * 4096 * 4 == pytest.approx(
+        8.39e9, rel=1e-3)
+    assert cco_ops._basket_plan(900, 100_000, 4096) == (4096, 25, 1024, 1)
+    assert cco_ops._basket_plan(5, 3, 4096) == (3, 1, 256, 1)
+    monkeypatch.setattr(cco_ops, "_TILED_P_BYTES", cco_ops._TILED_P_BYTES // 2)
+    assert cco_ops._basket_plan(65_536, 100_000, 4096) == (4096, 25, 4096, 16)
+    monkeypatch.setenv("PIO_CCO_MM_DTYPE", "int8")
+    assert cco_ops._basket_plan(65_536, 100_000, 4096) == (4096, 25, 8192, 8)
+
+
 def test_basket_rules_chunked_exact(monkeypatch):
     """Counts stay exact when baskets span many scan chunks."""
-    from predictionio_tpu.ops import cco
-
-    monkeypatch.setattr(cco, "_BASKET_CHUNK", 4)
+    _small_plan(monkeypatch, 256)
     rng = np.random.default_rng(1)
-    n_baskets, n_items = 50, 8
-    b = rng.integers(0, n_baskets, 400).astype(np.int32)
-    i = rng.integers(0, n_items, 400).astype(np.int32)
+    n_baskets, n_items = 1000, 8
+    b = np.concatenate([np.arange(n_baskets),      # none empty: N is 1000
+                        rng.integers(0, n_baskets, 7000)]).astype(np.int32)
+    i = rng.integers(0, n_items, 8000).astype(np.int32)
+    assert cco_ops._basket_plan(n_baskets, n_items, 4096)[2:] == (256, 4)
     lift, idx, conf = basket_rules(b, i, n_baskets, n_items, top_k=n_items)
     # dense numpy reference
     B = np.zeros((n_baskets, n_items))
@@ -178,17 +214,17 @@ def _host_reference_rules(gb, gi, n_baskets, n_items, top_k,
 
 
 def test_basket_rules_tiled_matches_dense(monkeypatch):
-    """Forcing the tiled strategy at a dense-feasible size: identical
-    lift/ids/confidence (modulo tie order) to the dense path."""
-    from predictionio_tpu.ops import cco as cco_ops
-
+    """Several ragged tiles and basket chunks against one tile and one
+    chunk (the whole count matrix at once): identical lift/ids/confidence
+    (modulo tie order)."""
     rng = np.random.default_rng(8)
     n_baskets, n_items = 300, 90
     gb = rng.integers(0, n_baskets, 2_000).astype(np.int32)
     gi = rng.integers(0, n_items, 2_000).astype(np.int32)
     dense = basket_rules(gb, gi, n_baskets, n_items, top_k=6,
                          min_support=0.004, min_confidence=0.1)
-    monkeypatch.setattr(cco_ops, "_BASKET_RULES_DENSE_MAX_ITEMS", 8)
+    _small_plan(monkeypatch, 256)
+    assert cco_ops._basket_plan(n_baskets, n_items, 32) == (32, 3, 256, 2)
     tiled = basket_rules(gb, gi, n_baskets, n_items, top_k=6,
                          min_support=0.004, min_confidence=0.1,
                          item_tile=32)
@@ -249,3 +285,290 @@ def test_cp_serve_batch_matches_serial(cp_app):
         s_i = [(r.item, round(r.score, 4)) for r in s.item_scores]
         b_i = [(r.item, round(r.score, 4)) for r in b.item_scores]
         assert s_i == b_i, (q, s_i, b_i)
+
+
+# ---------------------------------------------------------------------------
+# the template's own engine.json, and the engine against the plain reference
+# (benchmark/reference/basket_pair_rules.py, numpy/scipy float64)
+# ---------------------------------------------------------------------------
+
+import importlib.util   # noqa: E402
+import json   # noqa: E402
+from pathlib import Path   # noqa: E402
+
+from predictionio_tpu.models.complementary_purchase.engine import (  # noqa: E402
+    CPAlgorithm, CPTrainingData)
+from predictionio_tpu.store.columnar import IdDict   # noqa: E402
+
+ROOT = Path(__file__).resolve().parents[1]
+BENCH = ROOT / "benchmark"
+CP_CONFIG = json.loads((BENCH / "configs" / "cp-ecom-100k.json").read_text())
+LIMITS = CP_CONFIG["reference"]["limits"]
+# apache/predictionio-template-complementary-purchase, engine.json, key for key
+PUBLISHED = {
+    "id": "default",
+    "description": "Default settings",
+    "engineFactory": "predictionio_tpu.models.complementary_purchase."
+                     "ComplementaryPurchaseEngine",
+    "datasource": {"params": {"appName": APP}},
+    "algorithms": [{"name": "algo", "params": {
+        "basketWindow": 120, "maxRuleLength": 2, "minSupport": 0.1,
+        "minConfidence": 0.6, "minLift": 1.0, "minBasketSize": 2,
+        "maxNumRulesPerCond": 5}}],
+}
+
+
+def _bench_module(kind, name):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name}", BENCH / kind / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _bench_module("reference", "basket_pair_rules")
+
+
+def _published(**algo):
+    variant = json.loads(json.dumps(PUBLISHED))
+    variant["algorithms"][0]["name"] = "rules"
+    variant["algorithms"][0]["params"].update(algo)
+    return variant
+
+
+def test_published_engine_json_loads_and_trains(cp_app):
+    """The template's file as published: every key binds, under its own
+    name, and the engine trains and answers from it (coffee -> filter in
+    half of the baskets: support 0.5, confidence 1, lift 2)."""
+    from predictionio_tpu.workflow import create_workflow
+
+    _, engine, ep = create_workflow.engine_from_variant(_published())
+    algo = ep.algorithm_params_list[0][1]
+    assert (algo.basket_window, algo.max_rule_length, algo.min_support,
+            algo.min_confidence, algo.min_lift, algo.min_basket_size,
+            algo.max_num_rules_per_cond) == (120, 2, 0.1, 0.6, 1.0, 2, 5)
+    assert ep.data_source_params.app_name == APP
+    models = engine.train(ep)
+    res = engine.predictor(ep, models)(CPQuery(items=["coffee"], num=5))
+    assert [(s.item, round(s.score, 4)) for s in res.item_scores] == [
+        ("filter", 2.0)]
+    # bread (in 30% of the baskets, at random) passes no 0.6 confidence
+    assert engine.predictor(ep, models)(
+        CPQuery(items=["bread"], num=5)).item_scores == []
+
+
+@pytest.mark.parametrize("length", [1, 3, 4])
+def test_a_rule_length_other_than_two_is_refused(length):
+    from predictionio_tpu.workflow import create_workflow
+
+    with pytest.raises(ValueError, match="maxRuleLength.*only pair rules"):
+        CPAlgorithmParams.from_json({"maxRuleLength": length})
+    with pytest.raises(ValueError, match="cannot run"):
+        create_workflow.engine_from_variant(_published(maxRuleLength=length))
+
+
+@pytest.mark.parametrize("older,now", [
+    ({"maxRulesPerItem": 7}, {"maxNumRulesPerCond": 7}),
+    ({"max_rules_per_item": 7}, {"max_num_rules_per_cond": 7}),
+])
+def test_the_older_spelling_of_the_rule_count_still_loads(older, now):
+    assert CPAlgorithmParams.from_json(older) == CPAlgorithmParams.from_json(now)
+    assert CPAlgorithmParams.from_json(older).max_num_rules_per_cond == 7
+
+
+def test_one_place_decides_the_basket_window():
+    from predictionio_tpu.models.complementary_purchase.engine import (
+        basket_window_seconds)
+
+    def td(window):
+        return CPTrainingData(np.empty(0, np.int32), np.empty(0, np.int32),
+                              np.empty(0, np.int64), IdDict([]), window)
+
+    assert basket_window_seconds(CPAlgorithmParams(), td(None)) == 3600.0
+    assert basket_window_seconds(CPAlgorithmParams(), td("10 minutes")) == 600.0
+    assert basket_window_seconds(
+        CPAlgorithmParams(basket_window=120), td(None)) == 120.0
+    assert basket_window_seconds(
+        CPAlgorithmParams(basket_window=600), td("10 minutes")) == 600.0
+    with pytest.raises(ValueError, match="set one"):
+        basket_window_seconds(CPAlgorithmParams(basket_window=120),
+                              td("10 minutes"))
+
+
+SHOP = dict(n_users=150, n_items=700, n_kept=900, n_single=220, mean_items=6,
+            max_items=12, zipf=1.3, complements=4, within_s=60, between_s=3600,
+            floors={"rules": 300, "condition_items": 100})
+
+
+@pytest.mark.parametrize("seed", [3, 4000000007])
+def test_engine_train_on_the_blocked_program_against_the_reference(
+        mem_storage, monkeypatch, seed):
+    """cp-ecom-100k's engine.json at a small size: 900 kept baskets in
+    chunks of 256 (the last holds 132), 700 items in tiles of 256 (the
+    last holds 188), 220 one-item visits dropped.  `Engine.train` from the
+    engine variant, the Pallas tournament interpreted, every row of the
+    persisted table held against the plain reference by the
+    configuration's limits."""
+    from predictionio_tpu.obs.spans import SpanCollector
+    from predictionio_tpu.workflow import create_workflow
+
+    monkeypatch.setenv("PIO_PALLAS", "interpret")
+    _small_plan(monkeypatch, 256)
+    data = _bench_module("data", "shop_visits").generate(SHOP, seed)
+    assert cco_ops._basket_plan(data["n_baskets"], data["n_items"], 256) == (
+        256, 3, 256, 4)
+    app_id = mem_storage.apps.insert(App(0, "blocked"))
+    wire = _bench_module("drivers", "train_jobs").wire_events
+    for r in mem_storage.l_events.insert_json_batch(
+            list(wire(data["blocks"][0])), app_id):
+        assert r["status"] == 201
+    variant = json.loads(json.dumps(CP_CONFIG["engine"]).replace(
+        "$app", "blocked"))
+    variant["algorithms"][0]["params"].update(minSupport=0.002, itemTile=256)
+    _, engine, params = create_workflow.engine_from_variant(variant)
+    with SpanCollector().activate() as collector:
+        models = engine.train(params)
+
+    spans = collector.spans()
+    n_events = len(data["blocks"][0]["users"])
+    dispatched = [s["attrs"] for s in spans if s["name"] == "dispatch"]
+    assert dispatched == [{"program": "_basket_rules_tiled", "topk": "pallas",
+                           "tiles": 3, "chunks": 4, "steps": 12}]
+    formed, laid = [s["attrs"] for s in spans if s["name"] == "layout"]
+    assert formed == {"events": n_events, "baskets_formed": 900 + 220}
+    assert laid == {"events": n_events, "baskets": 900,
+                    "baskets_dropped": 220}
+    (h2d,) = [s["attrs"]["bytes"] for s in spans if s["name"] == "h2d"]
+    # the chunk-grouped log (row, item: four chunks padded to the fullest,
+    # by eights), a count a chunk, and the per-item counts of three tiles
+    slots, odd = divmod(h2d // 4 - 4 - 3 * 256, 2)
+    assert odd == 0 and slots % (4 * 8) == 0
+    assert n_events - 220 <= slots < 2 * n_events
+    (wait,) = [s["attrs"]["bytes"] for s in spans if s["name"] == "device_wait"]
+    assert wait == 2 * 4 * 768 * 8       # the carry: block_width(5) wide
+    names = [s["name"] for s in spans]
+    assert names.index("layout") < names.index("h2d") < names.index(
+        "dispatch") < names.index("device_wait")
+
+    checks = REF.check(models[0], data, variant, LIMITS, seed)
+    assert {c["name"] for c in checks} == set(LIMITS)
+    for c in checks:
+        assert c["ok"], checks
+    assert (models[0].comp_idx >= 0).sum() > 300
+
+
+def _hand_log():
+    """Three shoppers; buys 50 s apart inside a visit, but for u0's second
+    visit, whose two buys lie exactly 100 s apart; visits a day apart."""
+    day = 86_400
+    visits = [  # (user, start s, gap s, items)
+        (0, 0, 50, [0, 1, 2]), (0, day, 100, [0, 1]), (0, 2 * day, 50, [3]),
+        (1, 0, 50, [0, 1]), (1, day, 50, [1, 2]), (1, 2 * day, 50, [0, 3]),
+        (2, 0, 50, [2, 3]), (2, day, 50, [0, 1, 3]), (2, 2 * day, 50, [4]),
+    ]
+    users, items, times = [], [], []
+    for u, start, gap, its in visits:
+        for k, it in enumerate(its):
+            users.append(u), items.append(it)
+            times.append((start + k * gap) * 10**6)
+    return (np.array(users, np.int32), np.array(items, np.int32),
+            np.array(times, np.int64))
+
+
+BASE = dict(basketWindow=120, maxRuleLength=2, minSupport=0.0,
+            minConfidence=0.0, minLift=0.0, minBasketSize=1,
+            maxNumRulesPerCond=4)
+
+
+def _train_and_hold(algo: dict):
+    """CPAlgorithm.train on the hand log, held to the reference under the
+    same parameters; the model's (idx, lift) and the baskets it counted."""
+    users, items, times = _hand_log()
+    td = CPTrainingData(users, items, times, IdDict([f"i{k}" for k in range(5)]))
+    model = CPAlgorithm(CPAlgorithmParams.from_json(algo)).train(td)
+    p = REF.params_of({"algorithms": [{"params": algo}]})
+    block = {"users": users, "items": items, "times": times}
+    n = REF.baskets(block, 5, p["window_us"], p["min_size"]).shape[0]
+    data = {"n_items": 5, "n_baskets": n, "blocks": [block]}
+    got = REF.compare(model.comp_idx, model.comp_lift, np.arange(5), data, p)
+    assert got["lift_gap_max"] <= LIMITS["lift_gap_max"], got
+    assert got["topk_gap_max"] <= LIMITS["topk_gap_max"], got
+    assert got["baskets_gap"] == 0, got
+    return model.comp_idx, model.comp_lift, n
+
+
+@pytest.mark.parametrize("change,baskets", [
+    ({"minLift": 1.0}, 9),              # lifts under 1 go
+    ({"minBasketSize": 2}, 7),          # the two one-item visits go: N 9 -> 7
+    ({"basketWindow": 99}, 10),         # just under the 100 s gap: it splits
+    ({"basketWindow": 100}, 9),         # exactly the gap: it stays
+    ({"basketWindow": 101}, 9),         # just over
+    ({"maxNumRulesPerCond": 1}, 9),     # one rule an item
+    ({"minSupport": 0.3}, 9),
+    ({"minConfidence": 0.7}, 9),
+], ids=lambda v: "-".join(f"{k}{x}" for k, x in v.items())
+   if isinstance(v, dict) else None)
+def test_each_parameter_acts_as_the_reference_says(change, baskets):
+    base_idx, base_lift, n = _train_and_hold(BASE)
+    assert n == 9
+    idx, lift, n = _train_and_hold({**BASE, **change})
+    assert n == baskets
+    same = (idx.shape == base_idx.shape and (idx == base_idx).all()
+            and np.array_equal(lift, base_lift))
+    assert same == (change in ({"basketWindow": 100}, {"basketWindow": 101}))
+    kept = np.isfinite(lift)
+    if "minLift" in change:
+        assert (lift[kept] >= 1.0).all() and (base_lift[np.isfinite(
+            base_lift)] < 1.0).any()
+    if "maxNumRulesPerCond" in change:
+        assert idx.shape[1] == 1
+        np.testing.assert_array_equal(lift[:, 0], base_lift[:, 0])
+
+
+def test_a_rule_on_a_cut_does_not_decide_correct():
+    """64 baskets; item 0 in 24, item 1 in 8, both in 3: lift(0 -> 1) =
+    3 * 64 / (24 * 8) is exactly 1.0 = minLift.  The program keeps it, as
+    float64 does; the reference reads no gap whether the program's table
+    holds the rule or not, and reads a missing rule off the cut as
+    missing."""
+    rows = ([[0, 1]] * 3 + [[0, 2]] * 20 + [[0, 3]] + [[1, 3]] * 5
+            + [[2, 3]] * 30 + [[4, 5]] * 5)
+    b = np.repeat(np.arange(64), 2).astype(np.int32)
+    i = np.array(rows, np.int32).ravel()
+    lift, idx, _ = basket_rules(b, i, 64, 6, top_k=3, min_lift=1.0,
+                                min_basket_size=2)
+    (at,) = np.flatnonzero(idx[0] == 1)
+    assert lift[0, at] == 1.0
+    times = (b.astype(np.int64) * 86_400 + np.tile([0, 1], 64)) * 10**6
+    data = {"n_items": 6, "n_baskets": 64, "blocks": [
+        {"users": np.zeros(128, np.int64), "items": i, "times": times}]}
+    p = {"window_us": 120 * 10**6, "min_size": 2, "cuts": (0.0, 0.0, 1.0),
+         "k": 3}
+    ref = REF.cells(REF.baskets(data["blocks"][0], 6, p["window_us"], 2),
+                    p["cuts"])
+    assert sorted(zip(ref["rows"][ref["edge"]], ref["cols"][ref["edge"]])
+                  ) == [(0, 1), (1, 0)]
+
+    def held(idx, lift):
+        got = REF.compare(idx, lift, np.arange(6), data, p)
+        return got["lift_gap_max"], got["topk_gap_max"], got["baskets_gap"]
+
+    def without(row, col):
+        idx_, lift_ = idx.copy(), lift.copy()
+        (k,) = np.flatnonzero(idx[row] == col)
+        idx_[row, k:] = np.append(idx[row, k + 1:], -1)
+        lift_[row, k:] = np.append(lift[row, k + 1:], -np.inf)
+        return idx_, lift_
+
+    sound = held(idx, lift)
+    assert sound[0] < 1e-6 and sound[1:] == (0.0, 0.0)
+    assert held(*without(0, 1)) == sound          # on the cut: free
+    # a rule well above its cuts (4 -> 5: lift 5 * 64 / (5 * 5)) is not
+    assert idx[4].tolist() == [5, -1, -1] and abs(lift[4, 0] - 12.8) < 1e-5
+    assert held(*without(4, 5))[1] == REF.BIG
+    # nor is one the reference cuts (1 -> 3: lift 5 * 64 / (8 * 36) = 1.11
+    # is kept; 0 -> 3, lift 64 / (24 * 36), is not): an extra rule
+    extra = idx.copy(), lift.copy()
+    assert extra[0][0, 2] == -1
+    extra[0][0, 2], extra[1][0, 2] = 3, 0.074
+    assert held(*extra)[0] == REF.BIG
