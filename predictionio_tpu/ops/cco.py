@@ -205,8 +205,9 @@ def topk_impl() -> str:
 
     Why: in ``ur-ecom-100k.train`` the lax merge's tile-wide sort and the
     index gather after it took 16.31 + 4.46 s of a 32.0 s job (ledger,
-    PR 24); the tournament takes 7.64 s of an 18.8 s job, the sort and
-    the gather gone (chip run, PR 25: PERF.md section 6)."""
+    PR 24); the tournament took 7.64 s of a job while its network ran
+    along the lanes (PR 25) and takes 0.7 s since it runs across whole
+    vregs (14.5 ms a tile, chip run, PR 32: PERF.md section 6)."""
     from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
     return "lax" if pallas_mode() == "off" else "pallas"
@@ -221,6 +222,20 @@ def _carry_width(top_k: int, impl: str) -> int:
     return top_k
 
 
+def _topk_attrs(impl: str, tile: int, top_k: int) -> dict:
+    """What a tiled program's ``dispatch`` span says of its selection:
+    which merge, and for the tournament the network the kernel is traced
+    with at this (tile, carry) — ``pallas_kernels.topk_plan``."""
+    if impl != "pallas":
+        return {"topk": impl}
+    from predictionio_tpu.ops.pallas_kernels import topk_plan
+
+    plan = topk_plan(tile, _carry_width(top_k, impl))
+    return {"topk": impl, "topk_block": plan.block,
+            "topk_slab_stages": plan.slab_stages,
+            "topk_lane_stages": plan.lane_stages}
+
+
 def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
                 top_k: int, n_items_p: int, exclude_self: bool,
                 impl: str = "lax"):
@@ -233,8 +248,9 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
     reference.
     impl='pallas': one in-VMEM bitonic pass selects the tile's top block
     (pallas_kernels.tile_topk_desc), then a log2(b)-stage sorted merge
-    with the carry on [I, 2b] — the tile-wide sort never happens: 153 ms
-    a tile for the kernel, ~2 ms for the merge (chip run, PR 25).  The
+    with the carry on [I, 2b] — the tile-wide sort never happens: 14.5 ms
+    a tile for the kernel at a carry of 64 and 4.7 ms at 8 (chip run,
+    PR 32; 153 ms before it), ~2 ms for the merge (chip run, PR 25).  The
     carry is then [I, block_width(top_k)], sorted desc; _finalize_topk
     slices back to top_k.
     """
@@ -470,7 +486,8 @@ def _cco_resident(
     from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
     topk = topk_impl()
-    with span("dispatch", program="_cco_resident_all_tiles", topk=topk):
+    with span("dispatch", program="_cco_resident_all_tiles",
+              **_topk_attrs(topk, tile, top_k)):
         best_scores, best_idx = _cco_resident_all_tiles(
             P, rc, a_gu, a_gi, a_valid, float(n_users),
             n_tiles=n_tiles, tile=tile, top_k=top_k,
@@ -674,9 +691,9 @@ def _llr_topk_dense(
     top_k: int, exclude_self: bool, pallas: str,
 ):
     """LLR + whole-row top-k over the full count matrix: ``lax.top_k`` on
-    every backend.  A row here is the whole target catalogue, which the
-    tiled merge's ``tile_topk_desc`` would pad to a power of two and
-    unroll over."""
+    every backend.  A row here is the whole target catalogue; the tiled
+    merge's ``tile_topk_desc`` has been measured at the tile's width
+    alone."""
     scores = _llr_mask_scores(
         C.astype(jnp.float32), rc.astype(jnp.float32), cc.astype(jnp.float32),
         n_total, llr_threshold, pallas)
@@ -1446,8 +1463,9 @@ def _cco_chunked(
     if mesh is None:
         with span("h2d", bytes=sum(a.nbytes for a in host_args)):
             args = tuple(jnp.asarray(a) for a in host_args)
-        with span("dispatch", program="_cco_chunked_all_tiles", topk=topk,
-                  tiles=n_tiles, block_steps=n_tiles * primary.n_blocks):
+        with span("dispatch", program="_cco_chunked_all_tiles",
+                  tiles=n_tiles, block_steps=n_tiles * primary.n_blocks,
+                  **_topk_attrs(topk, tile, top_k)):
             best_scores, best_idx = _cco_chunked_all_tiles(
                 *args, float(n_total_users),
                 n_tiles=n_tiles, block=primary.user_block,
@@ -1664,8 +1682,9 @@ def basket_rules(
     with span("h2d", bytes=sum(a.nbytes for a in host_args)):
         lu, it, cnt, ci_dev = (jnp.asarray(a) for a in host_args)
     topk = topk_impl()
-    with span("dispatch", program="_basket_rules_tiled", topk=topk,
-              tiles=n_tiles, chunks=n_chunks, steps=n_tiles * n_chunks):
+    with span("dispatch", program="_basket_rules_tiled",
+              tiles=n_tiles, chunks=n_chunks, steps=n_tiles * n_chunks,
+              **_topk_attrs(topk, tile, k)):
         best_scores, best_idx = _basket_rules_tiled(
             lu, it, cnt, jnp.float32(max(n_kept, 1)), ci_dev,
             chunk=chunk, n_tiles=n_tiles, tile=tile, top_k=k,

@@ -16,8 +16,8 @@ beyond what XLA does automatically:
   fused into one VPU pass over each count tile.
 
 - ``tile_topk_desc`` — exact per-row top-k of a score tile as an in-VMEM
-  bitonic tournament (the tiled-CCO merge's per-tile selection wherever
-  these kernels run: ``ops.cco.topk_impl``).
+  bitonic tournament across whole vregs (the tiled-CCO merge's per-tile
+  selection wherever these kernels run: ``ops.cco.topk_impl``).
 
 Control: ``PIO_PALLAS`` env var — ``auto`` (default: compiled on TPU, off
 otherwise), ``1``/``compiled``, ``interpret``, ``0``/``off``.  A kernel is
@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -256,131 +256,207 @@ def llr_masked_scores(
 
 
 # ---------------------------------------------------------------------------
-# in-VMEM bitonic top-k over score tiles (the tiled-CCO merge bottleneck)
+# in-VMEM bitonic top-k over score tiles (the tiled-CCO merge's selection)
 # ---------------------------------------------------------------------------
 
+_SUBLANES = 8        # a float32 vreg: 8 sublanes x 128 lanes
+_LANES = 128
+_TOPK_ROWS = _SUBLANES * _LANES    # rows a grid step: one vreg a column
+_TOPK_GROUP = _LANES               # columns a grid step, where b allows
 
-def _roll_stage(s, i, d: int, kmask: int, w: int):
-    """One bitonic compare-exchange stage at XOR-distance ``d``, as lane
-    rolls + VPU selects.  Direction: descending where ``col & kmask == 0``
-    (the natural alternating pattern).  The cyclic wrap can never pair
-    wrong elements because positions whose bit_d is 0 always have i+d in
-    range and the rest use i-d.  Ties break toward the lower position so
-    (score, idx) pairs move as a permutation — no index duplicated/lost.
+
+class TopkPlan(NamedTuple):
+    """The selection network for one ``(w, b)``, as data (``topk_plan``)."""
+    block: int          # slabs a sorted block: the carry b, never wider
+    group: int          # slabs (columns) a chunk, one grid step's
+    chunks: int         # chunks a row: w pads to chunks * group columns
+    chunk: tuple        # stages: a chunk's slabs -> one block, sorted asc
+    merge: tuple        # stages: carry (desc) + that block -> carry (desc)
+    slab_stages: float  # full-width equivalents of whole-vreg exchanges
+    lane_stages: float  # ... of stages that move data inside a vreg: none
+
+
+def topk_plan(w: int, b: int) -> TopkPlan:
+    """The stage list of the exact top-``b`` network over ``w`` columns: a
+    pure function of the two static shapes, read by the kernel, by its
+    cost estimate, by the ``dispatch`` spans and by the tests' numpy
+    executor.
+
+    Layout.  The kernel turns a [1024, group] piece of the tile so that a
+    *slab* is ONE column over 1024 rows (a whole vreg: the rows on its
+    sublanes and lanes).  A row's elements then lie along the slab index
+    alone, every index bit of the network is a slab bit, and every stage
+    is an elementwise exchange between whole vregs in a direction that is
+    static per slab: no rotation, no iota, no mask.
+
+    Stages (``j`` indexes the current list of slabs):
+    ``("cx", d, k, flip)``  compare-exchange slabs ``j`` and ``j | d`` for
+        every ``j & d == 0``; the max goes to ``j`` where ``j & k == 0``
+        (``k`` 0: everywhere) and to ``j | d`` elsewhere; ``flip`` swaps
+        the two.
+    ``("fold", b)``  halve the list: of each pair of adjacent ``b``-slab
+        blocks, sorted in opposite directions, keep the elementwise max:
+        the pair's top ``b`` as a bitonic block (half-cleaner theorem).
+
+    ``chunk`` bitonic-sorts every block of ``b`` slabs, directions
+    alternating (log2(b)·(log2(b)+1)/2 stages), then folds and cleans up
+    (log2(b) stages) until one block is left, ascending; ``merge`` folds
+    it into the descending carry.  Chunks merge one after the other, so a
+    row's need not number a power of two.  At (4096, 64) that is 28
+    full-width stages and at (4096, 8) 10, where the network along the
+    lanes ran 36 at either (PR 25).
     """
-    from jax.experimental.pallas import tpu as pltpu
+    if b < 1 or b & (b - 1):
+        raise ValueError(f"top-k block must be a power of two, got {b}")
+    kb = b.bit_length() - 1
+    group = max(b, _TOPK_GROUP)
+    chunk = [("cx", 1 << j, 1 << kbit, True)
+             for kbit in range(1, kb + 1) for j in reversed(range(kbit))]
+    n, stages = group, kb * (kb + 1) / 2
+    while n > b:
+        chunk += [("fold", b)]
+        chunk += [("cx", b >> t, b, True) for t in range(1, kb + 1)]
+        n //= 2
+        stages += (1 + kb) * n / group
+    merge = (("fold", b),
+             *(("cx", b >> t, 0, False) for t in range(1, kb + 1)))
+    stages += (1 + kb) * b / group
+    return TopkPlan(b, group, pl.cdiv(w, group), tuple(chunk), merge,
+                    round(stages, 3), 0.0)
 
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    is_lower = (col & d) == 0
-    dir_desc = (col & kmask) == 0
-    # cyclic roll by w-d ≡ roll by -d (pltpu.roll wants shift ≥ 0)
-    ps = jnp.where(is_lower, pltpu.roll(s, w - d, 1), pltpu.roll(s, d, 1))
-    pi = jnp.where(is_lower, pltpu.roll(i, w - d, 1), pltpu.roll(i, d, 1))
-    self_is_max = (s > ps) | ((s == ps) & is_lower)
-    keep_self = (dir_desc == is_lower) == self_is_max
-    return jnp.where(keep_self, s, ps), jnp.where(keep_self, i, pi)
 
+def _run_stages(s, i, stages):
+    """Apply ``stages`` (see ``topk_plan``) to the stacked slabs ``s``
+    [n * 8, 128] and their columns ``i``: slab ``j`` is rows 8j..8j+7, so
+    a stage is a reshape of the leading dimension (whole vregs, no data
+    moves) and five elementwise operations.  Ties go toward the lower
+    slab, so (score, column) pairs are permuted and halved, never
+    duplicated."""
+    lanes = s.shape[1]
+    for op, *arg in stages:
+        n = s.shape[0] // _SUBLANES
+        if op == "fold":
+            shape = (n // (2 * arg[0]), 2, arg[0] * _SUBLANES, lanes)
+            s, i = s.reshape(shape), i.reshape(shape)
+            ge = s[:, 0] >= s[:, 1]
+            s = jnp.maximum(s[:, 0], s[:, 1])
+            i = jnp.where(ge, i[:, 0], i[:, 1])
+        else:
+            d, k, flip = arg
+            one_way = k == 0 or k >= n
+            # [.., bit k of j, .., bit d of j, the rows of d slabs, lanes]
+            shape = ((1, 1, n // (2 * d)) if one_way
+                     else (n // (2 * k), 2, k // (2 * d)))
+            shape += (2, d * _SUBLANES, lanes)
+            s, i = s.reshape(shape), i.reshape(shape)
+            lo_s, hi_s = s[:, :, :, 0], s[:, :, :, 1]
+            lo_i, hi_i = i[:, :, :, 0], i[:, :, :, 1]
+            ge = lo_s >= hi_s
+            mx_s, mn_s = jnp.maximum(lo_s, hi_s), jnp.minimum(lo_s, hi_s)
+            mx_i, mn_i = jnp.where(ge, lo_i, hi_i), jnp.where(ge, hi_i, lo_i)
+            if flip:
+                mx_s, mn_s, mx_i, mn_i = mn_s, mx_s, mn_i, mx_i
 
-def _tournament_topb(s, i, w: int, bk: int):
-    """Exact top-``bk`` of each row (sorted descending), INSIDE a Pallas
-    kernel: every stage is a VPU select chain over VMEM-resident arrays,
-    so the whole network costs ONE HBM read of the tile.  (The same
-    network as pure XLA ops materializes every stage to HBM — measured
-    19× slower than lax.top_k on CPU; as a kernel it is compute-bound.)
+            def by_k(p, q):     # p where bit k of j is 0, q where it is 1
+                if one_way:
+                    return p
+                return jnp.concatenate([p[:, :1], q[:, 1:]], axis=1)
 
-    Schedule (strictly less work than a full bitonic sort):
-    1. bitonic-sort every bk-wide block, directions alternating
-       (desc, asc, …) — O(log²bk) full-width stages;
-    2. tournament rounds: each adjacent (desc, asc) pair is bitonic, so
-       an elementwise max of its halves keeps exactly the top-bk multiset
-       (half-cleaner theorem); log2(bk) cleanup stages restore the
-       alternating order.  Width halves per round, so rounds cost
-       O(w·log bk) total.  ~78 → ~36 full-width-equivalent stages at the
-       production tile (w=4096, bk=128).
-    """
-    r = s.shape[0]
-    kbit = 1
-    while (1 << kbit) <= bk:
-        for j in reversed(range(kbit)):
-            s, i = _roll_stage(s, i, 1 << j, 1 << kbit, w)
-        kbit += 1
-    while w > bk:
-        g = w // (2 * bk)
-        s4 = s.reshape(r, g, 2, bk)
-        i4 = i.reshape(r, g, 2, bk)
-        ls, us = s4[:, :, 0], s4[:, :, 1]
-        li, ui = i4[:, :, 0], i4[:, :, 1]
-        l_is_max = ls >= us
-        w //= 2
-        s = jnp.maximum(ls, us).reshape(r, w)
-        i = jnp.where(l_is_max, li, ui).reshape(r, w)
-        d = bk // 2
-        while d >= 1:
-            s, i = _roll_stage(s, i, d, bk, w)
-            d //= 2
+            s = jnp.stack([by_k(mx_s, mn_s), by_k(mn_s, mx_s)], axis=3)
+            i = jnp.stack([by_k(mx_i, mn_i), by_k(mn_i, mx_i)], axis=3)
+        s = s.reshape(-1, lanes)
+        i = i.reshape(-1, lanes)
     return s, i
 
 
-def _topk_sort_kernel(s_ref, out_s_ref, out_i_ref, *, w: int, b: int, bk: int):
-    s = s_ref[:]
-    i = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s, i = _tournament_topb(s, i, w, bk)
-    out_s_ref[:] = s[:, :b]
-    out_i_ref[:] = i[:, :b]
+def _topk_slab_kernel(x_ref, out_s_ref, out_i_ref, acc_s_ref, acc_i_ref, *,
+                      plan: TopkPlan):
+    c = pl.program_id(1)
+    # [1024 rows, group columns] -> slab j = column j, row 128a + r at
+    # (sublane a, lane r): 128-wide transposes put the rows on the lanes,
+    # swapping the two leading dimensions then gathers a column's eight
+    # row groups into one vreg.
+    t = jnp.stack([x_ref[a * _LANES:(a + 1) * _LANES, :].T
+                   for a in range(_SUBLANES)])          # [a, column, r]
+    s = jnp.transpose(t, (1, 0, 2)).reshape(plan.group * _SUBLANES, _LANES)
+    i = c * plan.group + jax.lax.broadcasted_iota(     # slab j's column
+        jnp.int32, (plan.group, _SUBLANES, _LANES), 0).reshape(s.shape)
+    s, i = _run_stages(s, i, plan.chunk)
+
+    @pl.when(c == 0)
+    def _():
+        acc_s_ref[...] = jnp.full(acc_s_ref.shape, NEG_INF, jnp.float32)
+        acc_i_ref[...] = jnp.zeros(acc_i_ref.shape, jnp.int32)
+
+    s, i = _run_stages(jnp.concatenate([acc_s_ref[...], s]),
+                       jnp.concatenate([acc_i_ref[...], i]), plan.merge)
+    acc_s_ref[...] = s
+    acc_i_ref[...] = i
+
+    @pl.when(c == plan.chunks - 1)
+    def _():
+        out_s_ref[...] = s.reshape(out_s_ref.shape)
+        out_i_ref[...] = i.reshape(out_i_ref.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("b", "block_r", "interpret"))
-def _tile_topk_padded(scores, b: int, block_r: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("b", "interpret"))
+def _tile_topk_padded(scores, b: int, interpret: bool):
+    from jax.experimental.pallas import tpu as pltpu
+
     r, w = scores.shape
-    rp = _round_up(r, block_r)
-    wp = max(b, 128)
-    while wp < w:
-        wp *= 2
+    plan = topk_plan(w, b)
+    # rows past the last whole block are the grid's own edge; columns pad
+    # to whole chunks with -inf (no copy at the production tile)
+    rp, wp = max(r, _TOPK_ROWS), plan.chunks * plan.group
     if (rp, wp) != (r, w):
         scores = jnp.full((rp, wp), NEG_INF, jnp.float32).at[:r, :w].set(scores)
-    grid = (rp // block_r,)
-    bk = max(b, 128)   # tournament block ≥ one 128-lane group
+    # rank t of row 1024g + 128a + l lands at out[t, 8g + a, l]
+    out_block = pl.BlockSpec((b, _SUBLANES, _LANES), lambda g, c: (0, g, 0))
+    out_rows = pl.cdiv(rp, _LANES)
     out_s, out_i = pl.pallas_call(
-        functools.partial(_topk_sort_kernel, w=wp, b=b, bk=bk),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_r, wp), lambda g: (g, 0))],
-        out_specs=(
-            pl.BlockSpec((block_r, b), lambda g: (g, 0)),
-            pl.BlockSpec((block_r, b), lambda g: (g, 0)),
-        ),
+        functools.partial(_topk_slab_kernel, plan=plan),
+        grid=(pl.cdiv(rp, _TOPK_ROWS), plan.chunks),
+        in_specs=[pl.BlockSpec((_TOPK_ROWS, plan.group), lambda g, c: (g, c))],
+        out_specs=(out_block, out_block),
         out_shape=(
-            _out_struct((rp, b), jnp.float32, scores),
-            _out_struct((rp, b), jnp.int32, scores),
+            _out_struct((b, out_rows, _LANES), jnp.float32, scores),
+            _out_struct((b, out_rows, _LANES), jnp.int32, scores),
         ),
+        scratch_shapes=[
+            pltpu.VMEM((b * _SUBLANES, _LANES), jnp.float32),
+            pltpu.VMEM((b * _SUBLANES, _LANES), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            # block sort log²(bk) full-width stages + tournament ~2·log(bk)
-            flops=10 * rp * wp * (bk.bit_length() ** 2 // 2 + bk.bit_length()),
+            # 2.5 VPU operations an element a full-width stage
+            flops=int(2.5 * rp * wp * (plan.slab_stages + plan.lane_stages)),
             bytes_accessed=4 * (rp * wp + 2 * rp * b),
             transcendentals=0,
         ),
         interpret=interpret,
     )(scores)
-    return out_s[:r], out_i[:r]
+    return out_s.reshape(b, -1)[:, :r].T, out_i.reshape(b, -1)[:, :r].T
 
 
-def tile_topk_desc(
-    scores: jnp.ndarray, b: int, block_r: int = 8,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def tile_topk_desc(scores: jnp.ndarray, b: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Exact top-``b`` of each row, sorted descending, as ONE Pallas pass.
 
     Replaces ``lax.top_k`` in the tiled-CCO running merge, where XLA's
     full variadic row sort and the gather after it took 326 + 89 ms a
-    [100000, 4096] tile (ledger, PR 24); this kernel takes 153 ms a tile
-    in the same job (chip run, PR 25: PERF.md section 6).
-    ``b`` must be a power of two (see ``ops.topk.block_width``); rows pad
-    to the block, width pads to the next power of two with -inf (padded
-    columns surface with -inf scores, which every caller already filters).
+    [100000, 4096] tile (ledger, PR 24).  The network (``topk_plan``)
+    runs across whole vregs, one column a vreg, and sorts no wider than
+    ``b``: 14.5 ms a tile at ``b`` 64 and 4.7 ms a [102400, 4096] tile at
+    ``b`` 8, of which 3.3 ms are the tile's read and its turn to slabs
+    (chip run, PR 32: PERF.md section 6); along the lanes, 128 wide, it
+    took 153 ms at either (PR 25).
 
-    ``block_r`` is one f32 sublane group: the ~100 unrolled stages keep
-    (s, i, partner s, partner i) live across the whole [block_r, W]
-    block, and Mosaic's compile time and scoped-VMEM stack both grow with
-    it — at [100k, 4096] block_r 8/16/32 compile in 2.5/12/40 s, and 128
-    takes ~9 min to then exceed the 16 MiB scoped-VMEM limit (v5e AOT
-    compile, PERF.md "Bring-up on TPU v5e").
+    ``b`` must be a power of two (see ``ops.topk.block_width``).  Width
+    pads to whole chunks of ``max(b, 128)`` columns with -inf; padded
+    columns, and the places of a row with fewer than ``b`` finite scores,
+    surface with -inf scores, which every caller already filters (their
+    columns mean nothing).  Ties keep any of the tied columns, each once.
+    A grid step is 1024 rows by one chunk; Mosaic compiles the kernel in
+    2.3 s at [100000, 4096], ``b`` 64 [AOT, PR 32].
     """
-    return _tile_topk_padded(scores, b, block_r, _interpret())
+    return _tile_topk_padded(scores, b, _interpret())

@@ -4,10 +4,17 @@ Why this exists: XLA lowers ``lax.top_k`` on TPU to a full variadic sort
 of each row.  In the tiled CCO path that sort — top_k(concat(best, tile))
 over a [I_p, top_k + 4096] buffer per tile — and the index gather after
 it took 16.31 + 4.46 s of a 32.0 s ``ur-ecom-100k.train`` job (ledger,
-PR 24: 65% of the job); the tournament as a Pallas kernel plus
-``merge_desc`` takes 7.64 + 0.1 s of the same job, now 18.8 s (chip run,
-PR 25: PERF.md section 6).  ``approx_max_k`` inside ``lax.scan`` was no
-escape: it exploded compile time (>40 min at [100k, 4096]).
+PR 24: 65% of the job).  The tournament as a Pallas kernel
+(``pallas_kernels.tile_topk_desc``) plus ``merge_desc`` took 7.64 + 0.1 s
+of the same job while its network ran along the lanes (PR 25), and takes
+0.73 + 0.1 s since the kernel runs it across whole vregs, one column a
+vreg (14.5 ms a tile, chip run, PR 32: PERF.md section 6).
+``approx_max_k`` inside ``lax.scan`` was no escape: it exploded compile
+time (>40 min at [100k, 4096]).
+
+This module is the network in pure JAX: the reference the kernel's tests
+compare with on any backend, and ``merge_desc``, the carry merge that the
+tiled programs run after the kernel.
 
 The tournament does strictly less work than a full sort and lowers to
 nothing but elementwise min/max/select chains plus static reshapes, which

@@ -433,7 +433,9 @@ def test_engine_train_on_the_blocked_program_against_the_reference(
     n_events = len(data["blocks"][0]["users"])
     dispatched = [s["attrs"] for s in spans if s["name"] == "dispatch"]
     assert dispatched == [{"program": "_basket_rules_tiled", "topk": "pallas",
-                           "tiles": 3, "chunks": 4, "steps": 12}]
+                           "tiles": 3, "chunks": 4, "steps": 12,
+                           "topk_block": 8, "topk_slab_stages": 10.0,
+                           "topk_lane_stages": 0.0}]
     formed, laid = [s["attrs"] for s in spans if s["name"] == "layout"]
     assert formed == {"events": n_events, "baskets_formed": 900 + 220}
     assert laid == {"events": n_events, "baskets": 900,

@@ -1,0 +1,45 @@
+"""The selection kernel compiled for a described TPU v5e at the shapes
+the benchmark's cells run, with no chip: Mosaic refuses here what it
+would refuse there (a layout it cannot turn, too much VMEM), which the
+interpreter never sees.  Nothing runs, so nothing here is a speed.
+
+The topology is described inside a fixture, never at import (only one
+process may load the TPU's library at a time; see the on-chip-measurement
+guide), and every test of this kind lives in this one file."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows,width,b", [
+    (100_000, 4096, 64),    # ur-ecom-100k, ur-ecom-100k-u131k: a tile, top 50
+    (102_400, 4096, 8),     # cp-ecom-100k: a tile, top 5
+    (1_000, 200, 16),       # fewer rows than a block, a padded width
+])
+def test_tile_topk_compiles_for_a_v5e(one_chip, rows, width, b):
+    from predictionio_tpu.ops import pallas_kernels as pk
+
+    scores = jax.ShapeDtypeStruct((rows, width), jnp.float32,
+                                  sharding=one_chip)
+    compiled = pk._tile_topk_padded.lower(scores, b, False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert [o.shape for o in jax.eval_shape(
+        lambda s: pk._tile_topk_padded(s, b, True), scores)] == [(rows, b)] * 2

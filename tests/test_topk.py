@@ -55,17 +55,101 @@ def test_running_merge_across_tiles_matches_global_topk():
     _check_topk(x, bs, bi, b)
 
 
-def test_pallas_tile_topk_desc_matches_lax(monkeypatch):
+@pytest.mark.parametrize("rows", [16, 13])
+@pytest.mark.parametrize("w", [128, 200, 520, 4096])
+@pytest.mark.parametrize("b", [8, 16, 64, 128])
+def test_pallas_tile_topk_desc_matches_lax(monkeypatch, b, w, rows):
+    """The slab tournament against lax.top_k at every carry, over one,
+    two, five and 32 column chunks (the last partly padding), row counts
+    that are and are not whole sublane groups, and the rows a network
+    gets wrong first: nothing finite, fewer finite scores than ``b``, a
+    run of equal scores across the cut, every score equal, and equal
+    scores in columns (slabs) of different chunks."""
     from predictionio_tpu.ops.pallas_kernels import tile_topk_desc
 
     monkeypatch.setenv("PIO_PALLAS", "interpret")
     rng = np.random.default_rng(2)
-    for (r, w, b) in [(9, 300, 64), (3, 64, 128), (5, 520, 16)]:
-        x = rng.standard_normal((r, w)).astype(np.float32)
-        x[x < 0] = -np.inf
-        x[0, : min(5, w)] = 2.0
-        s, i = tile_topk_desc(jnp.asarray(x), b)
-        _check_topk(x, s, i, min(b, w))
+    x = rng.standard_normal((rows, w)).astype(np.float32)
+    x[x < 0] = -np.inf
+    x[0] = -np.inf
+    x[1] = -np.inf
+    x[1, rng.choice(w, 3, replace=False)] = [0.5, 0.25, 0.5]
+    k = min(b, w)
+    x[2] = rng.random(w).astype(np.float32)             # all below 1
+    spread = rng.permutation(w)[:k + 4]
+    x[2, spread[:k - 3]] = 2.0 + np.arange(k - 3)       # k-3 above the run
+    x[2, spread[k - 3:]] = 1.5                          # 7 equal, 3 kept
+    x[3] = 0.75
+    x[4, [0, w // 2, w - 1]] = 9.0
+    s, i = tile_topk_desc(jnp.asarray(x), b)
+    assert s.shape == i.shape == (rows, b)
+    _check_topk(x, s, i, k)
+
+
+def _np_stages(s, i, stages):
+    """``topk_plan``'s stages on numpy arrays [slabs, rows]: the stage
+    list's meaning, written without the kernel's reshapes."""
+    for op, *arg in stages:
+        n = len(s)
+        if op == "fold":
+            s4, i4 = (a.reshape(n // (2 * arg[0]), 2, arg[0], -1) for a in (s, i))
+            ge = s4[:, 0] >= s4[:, 1]
+            s = np.where(ge, s4[:, 0], s4[:, 1]).reshape(n // 2, -1)
+            i = np.where(ge, i4[:, 0], i4[:, 1]).reshape(n // 2, -1)
+            continue
+        d, k, flip = arg
+        lo = np.array([j for j in range(n) if not j & d])
+        hi = lo | d
+        max_to_lo = (((lo & k) == 0) != flip)[:, None]
+        swap = np.where(max_to_lo, s[lo] < s[hi], s[lo] > s[hi])
+        s[lo], s[hi] = np.where(swap, s[hi], s[lo]), np.where(swap, s[lo], s[hi])
+        i[lo], i[hi] = np.where(swap, i[hi], i[lo]), np.where(swap, i[lo], i[hi])
+    return s, i
+
+
+@pytest.mark.parametrize("w,b,stages", [(4096, 64, 28.0), (4096, 8, 10.0),
+                                        (128, 8, 10.0), (256, 128, 36.0),
+                                        (300, 16, 15.0)])
+def test_topk_plan_sorts_and_selects_in_numpy(w, b, stages):
+    """The planner alone, no kernel: its stage list, run by ``_np_stages``
+    over a row's columns one chunk after the other, leaves exactly the
+    top ``b`` sorted descending, each with its own column; and it says
+    what it costs: no stage inside a vreg, and fewer full-width stages
+    than the 36 of the network along the lanes wherever the carry is
+    narrower than 128."""
+    from predictionio_tpu.ops.pallas_kernels import topk_plan
+
+    plan = topk_plan(w, b)
+    assert (plan.block, plan.group) == (b, max(b, 128))
+    assert plan.chunks * plan.group >= w > (plan.chunks - 1) * plan.group
+    assert (plan.slab_stages, plan.lane_stages) == (stages, 0.0)
+    assert b == 128 or plan.slab_stages + plan.lane_stages < 36
+    rows = 40
+    rng = np.random.default_rng(w + b)
+    x = np.full((rows, plan.chunks * plan.group), -np.inf, np.float32)
+    x[:, :w] = rng.integers(0, 3 * b, (rows, w))        # ties everywhere
+    x[0, :w] = rng.permutation(w)                       # and none
+    acc_s = np.full((b, rows), -np.inf, np.float32)
+    acc_i = np.zeros((b, rows), np.int64)
+    for c in range(plan.chunks):
+        cols = np.arange(c * plan.group, (c + 1) * plan.group)
+        s, i = _np_stages(x[:, cols].T.copy(),
+                          np.repeat(cols[:, None], rows, 1), plan.chunk)
+        assert s.shape == (b, rows) and (np.diff(s, axis=0) >= 0).all()
+        acc_s, acc_i = _np_stages(np.concatenate([acc_s, s]),
+                                  np.concatenate([acc_i, i]), plan.merge)
+    np.testing.assert_array_equal(acc_s.T, -np.sort(-x, axis=1)[:, :b])
+    fin = np.isfinite(acc_s.T)
+    assert (np.take_along_axis(x, acc_i.T, axis=1)[fin] == acc_s.T[fin]).all()
+    for r in range(rows):
+        assert len(set(acc_i.T[r][fin[r]].tolist())) == fin[r].sum()
+
+
+def test_topk_plan_refuses_a_block_that_is_no_power_of_two():
+    from predictionio_tpu.ops.pallas_kernels import topk_plan
+
+    with pytest.raises(ValueError, match="power of two"):
+        topk_plan(4096, 50)
 
 
 def test_pallas_kernels_never_interpret_silently(monkeypatch):
@@ -141,6 +225,49 @@ def test_cco_topk_pallas_matches_lax(monkeypatch, strategy):
         program = f"_cco_{strategy}_all_tiles"
         assert d1[program]["topk"] == "lax"
         assert d2[program]["topk"] == "pallas"
+
+
+@pytest.mark.parametrize("program", ["_cco_resident_all_tiles",
+                                     "_cco_chunked_all_tiles",
+                                     "_basket_rules_tiled"])
+def test_tiled_dispatch_spans_say_which_network_ran(monkeypatch, program):
+    """A journal says which selection network a job ran: under the
+    tournament the tiled programs' ``dispatch`` span carries the block
+    (the carry, not 128) and the planner's stage counts for the (tile,
+    carry) the kernel is traced with; under lax.top_k none of them."""
+    from predictionio_tpu.obs.spans import SpanCollector
+    from predictionio_tpu.ops import cco as cco_ops
+    from predictionio_tpu.ops.pallas_kernels import topk_plan
+
+    rng = np.random.default_rng(4)
+    n_users, n_items, top_k, tile = 60, 40, 5, 16
+    u = rng.integers(0, n_users, 400)
+    it = rng.integers(0, n_items, 400)
+    monkeypatch.setenv("PIO_CCO_SPARSE", "0")
+    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    if program == "_cco_chunked_all_tiles":
+        monkeypatch.setattr(cco_ops, "_TILED_P_BYTES", 0)
+
+    def attrs(pallas):
+        monkeypatch.setenv("PIO_PALLAS", pallas)
+        with SpanCollector().activate() as spans:
+            if program == "_basket_rules_tiled":
+                cco_ops.basket_rules(u, it, n_users, n_items, top_k=top_k,
+                                     item_tile=tile)
+            else:
+                cco_ops.cco_indicators_coo(
+                    u, it, u, it, n_users, n_items, n_items, top_k=top_k,
+                    user_block=32, item_tile=tile)
+        return _dispatch_attrs(spans)[program]
+
+    keys = {"topk_block", "topk_slab_stages", "topk_lane_stages"}
+    off = attrs("off")
+    assert off["topk"] == "lax" and not keys & set(off)
+    on = attrs("interpret")
+    plan = topk_plan(tile, 8)
+    assert on["topk"] == "pallas" and on["topk_block"] == 8
+    assert on["topk_slab_stages"] == plan.slab_stages == 10.0
+    assert on["topk_lane_stages"] == plan.lane_stages == 0.0
 
 
 def test_topk_impl_follows_pallas_mode(monkeypatch):
