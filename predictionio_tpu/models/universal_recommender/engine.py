@@ -1219,8 +1219,11 @@ class URAlgorithm(Algorithm):
         if unknown:
             raise ValueError(
                 f"blacklist_events {unknown} not in event_names {td.event_names}")
+        # a stated meshDp takes the first meshDp devices (and raises above
+        # the machine's count); unstated, every device the machine shows
         dp = self.params.mesh_dp or len(jax.devices())
-        mesh = create_mesh(MeshSpec(dp=dp, mp=1)) if dp > 1 else None
+        mesh = create_mesh(MeshSpec(dp=dp, mp=1),
+                           devices=jax.devices()[:dp]) if dp > 1 else None
         # one call for all event types: cco_train_indicators plans each
         # type's strategy, its dense and host-sparse runners stage the
         # primary once, and no host dedup runs on a device strategy (the

@@ -30,9 +30,16 @@ Recommender').  TPU-first re-expression (SURVEY.md §7.5):
   tiles, each tile's LLR scores merging into a running per-row top-k
   (concat + ``lax.top_k``), so the full I_p×I_t count matrix is never
   materialized.  Marginals accumulate on device inside the same scan.
-- Multi-device: user chunks are sharded over the mesh's ``dp`` axis; the
-  count matrix and marginals are ``psum``'d over ICI (counts are the only
-  cross-device quantity).
+- Multi-device: users are sharded over the mesh's ``dp`` axis, and counts
+  are the only quantity that crosses chips.  The dense strategy ``psum``s
+  its count matrix.  The P-resident tiled strategy is the one-device
+  program with an axis name: each chip keeps the densified primary of its
+  own contiguous range of users, multiplies locally, and every partial
+  count tile is reduce-scattered over the primary's item rows
+  (``psum_scatter``), so a chip scores, selects and carries only its own
+  rows (two programs an event type, as on one device).  The chunked tiled
+  strategy, for a primary that no chip's share keeps resident, still
+  dispatches one sharded step a tile and ``psum``s the whole tile.
 
 LLR is Dunning's G² exactly as Mahout's ``LogLikelihood.logLikelihoodRatio``
 computes it (determinant formulation; see ``llr_score``).
@@ -238,7 +245,7 @@ def _topk_attrs(impl: str, tile: int, top_k: int) -> dict:
 
 def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
                 top_k: int, n_items_p: int, exclude_self: bool,
-                impl: str = "lax"):
+                impl: str = "lax", row_offset=None):
     """Shared running top-k merge for the tiled strategies; masks self-pairs
     BEFORE the merge so every row still gets a full top_k correlators.
 
@@ -253,10 +260,16 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
     PR 32; 153 ms before it), ~2 ms for the merge (chip run, PR 25).  The
     carry is then [I, block_width(top_k)], sorted desc; _finalize_topk
     slices back to top_k.
+
+    ``row_offset``: the primary item of row 0 where the rows are one
+    chip's share of the primary's items (the sharded resident program);
+    only ``exclude_self`` reads it.
     """
     tile_idx = tile_start + jnp.arange(tile, dtype=jnp.int32)[None, :]
     if exclude_self:
         row_ids = jnp.arange(n_items_p, dtype=jnp.int32)[:, None]
+        if row_offset is not None:
+            row_ids = row_ids + row_offset
         scores = jnp.where(tile_idx == row_ids, -jnp.inf, scores)
     if impl == "pallas":
         from predictionio_tpu.ops.pallas_kernels import tile_topk_desc
@@ -275,16 +288,32 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
         return new_scores, jnp.take_along_axis(all_idx, pos, axis=1)
 
 
+def _fetch(a) -> np.ndarray:
+    """A device array on the host; a row-sharded one whose mesh spans
+    processes is gathered first (np.asarray reads only what this process
+    can address)."""
+    if not getattr(a, "is_fully_addressable", True):
+        from jax.experimental import multihost_utils
+
+        a = multihost_utils.process_allgather(a, tiled=True)
+    return np.asarray(a)
+
+
 def _finalize_topk(best_scores, best_idx, n_items_t: int,
-                   top_k: Optional[int] = None):
+                   top_k: Optional[int] = None,
+                   n_rows: Optional[int] = None):
     """Shared host epilogue: -1-pad entries that are -inf or tile padding;
-    slice a pow2-widened pallas-merge carry back to the requested top_k."""
+    slice a pow2-widened pallas-merge carry back to the requested top_k,
+    and a row-sharded carry (read back whole from its chips) to the
+    ``n_rows`` primary items that are no padding."""
     if isinstance(best_scores, np.ndarray):     # the host tail's own arrays
         scores, idx = best_scores, np.asarray(best_idx)
     else:
         with span("device_wait", bytes=best_scores.nbytes + best_idx.nbytes):
-            scores = np.asarray(best_scores)
-            idx = np.asarray(best_idx)
+            scores = _fetch(best_scores)
+            idx = _fetch(best_idx)
+    if n_rows is not None and scores.shape[0] > n_rows:
+        scores, idx = scores[:n_rows], idx[:n_rows]
     if top_k is not None and scores.shape[1] > top_k:
         scores, idx = scores[:, :top_k], idx[:, :top_k]
     idx = np.where((scores > -np.inf) & (idx < n_items_t), idx, -1)
@@ -367,6 +396,16 @@ def _mm_in_dtype():
     return jnp.int8 if _matmul_dtype() == "int8" else jnp.bfloat16
 
 
+def _varying(tree, axis_name: Optional[str]):
+    """A scan's initial carry as one that differs from chip to chip: under
+    ``shard_map`` (``axis_name``) a carry built from constants has to say
+    so before a chip's own values are folded into it; as it is without."""
+    if axis_name is None:
+        return tree
+    return jax.tree.map(
+        lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), tree)
+
+
 def _pad128(n: int) -> int:
     """``n`` rounded up to whole 128-wide tiles, at least one."""
     return max(((n + 127) // 128) * 128, 128)
@@ -398,13 +437,21 @@ def _cco_tile_body_resident(
     n_total, best_scores, best_idx, tile_start,
     tile: int, top_k: int, llr_threshold,
     exclude_self: bool, pallas: str, mm: str, topk: str = "lax",
+    axis_name: Optional[str] = None,
 ):
     """One item tile against the RESIDENT densified primary: densify only
     this tile's slice of A (one scatter), one matmul, LLR, top-k merge —
     the primary is never re-densified per tile, unlike the chunked tiled
-    path which pays n_tiles × that cost."""
+    path which pays n_tiles × that cost.
+
+    With ``axis_name`` (under ``shard_map``) ``P`` and the pairs are one
+    chip's range of users, so the product is a partial count tile: it is
+    reduce-scattered over the primary's item rows, the tile's column
+    counts are ``psum``'d, and ``rc``, the scores and the carry are this
+    chip's rows alone.  Counts stay exact: each chip's partial is an
+    integer below 2²⁴ in float32, and so is their sum (``_plan``)."""
     n_rows = P.shape[0]
-    n_items_p = P.shape[1]
+    row_offset = None
     with jax.named_scope("cco.densify_tile"):
         a_local = a_gi - tile_start
         in_tile = a_valid & (a_local >= 0) & (a_local < tile)
@@ -413,24 +460,34 @@ def _cco_tile_body_resident(
     with jax.named_scope("cco.count_matmul"):
         c = _count_matmul(P, A_t, mm).astype(jnp.float32)
         cct = _col_count(A_t).astype(jnp.float32)
+    if axis_name is not None:
+        with jax.named_scope("cco.exchange"):
+            c = jax.lax.psum_scatter(c, axis_name, scatter_dimension=0,
+                                     tiled=True)
+            cct = jax.lax.psum(cct, axis_name)
+        row_offset = jax.lax.axis_index(axis_name) * c.shape[0]
     with jax.named_scope("cco.llr"):
         scores = _llr_mask_scores(c, rc.astype(jnp.float32), cct, n_total,
                                   llr_threshold, pallas)
     return _merge_topk(best_scores, best_idx, scores, tile_start, tile,
-                       top_k, n_items_p, exclude_self, impl=topk)
+                       top_k, c.shape[0], exclude_self, impl=topk,
+                       row_offset=row_offset)
 
 
 def _scan_tiles(step, n_items_p: int, n_tiles: int, tile: int, top_k: int,
-                carry_k: Optional[int] = None):
+                carry_k: Optional[int] = None,
+                axis_name: Optional[str] = None):
     """Shared scan harness for the tiled strategies: run ``step(bs, bi,
     tile_start)`` over every tile start in ONE compiled program.
 
     A Python-level tile loop pays a dispatch per tile and blocks XLA
     from pipelining the scatter of tile t+1 under the matmul of tile t;
     the scan removes both.  ``carry_k`` widens the running-merge carry to
-    the pallas merge's pow2 block (see _carry_width)."""
+    the pallas merge's pow2 block (see _carry_width).  Under ``shard_map``
+    (``axis_name``) the carry is one chip's ``n_items_p`` rows."""
     init = (jnp.full((n_items_p, carry_k or top_k), -jnp.inf, jnp.float32),
             jnp.zeros((n_items_p, carry_k or top_k), jnp.int32))
+    init = _varying(init, axis_name)
     starts = jnp.arange(n_tiles, dtype=jnp.int32) * tile
 
     def body(carry, tile_start):
@@ -438,6 +495,28 @@ def _scan_tiles(step, n_items_p: int, n_tiles: int, tile: int, top_k: int,
 
     (best_scores, best_idx), _ = jax.lax.scan(body, init, starts)
     return best_scores, best_idx
+
+
+def _resident_all_tiles(
+    P, rc, a_gu, a_gi, a_valid, n_total,
+    n_tiles: int, tile: int, top_k: int, llr_threshold,
+    exclude_self: bool, pallas: str, mm: str, topk: str,
+    axis_name: Optional[str] = None,
+):
+    """The scan over the RESIDENT path's item tiles (_scan_tiles), traced
+    by the one-device program and, with an axis name, by the sharded one:
+    ``rc`` has one entry for each row of the carry."""
+
+    def step(bs, bi, tile_start):
+        return _cco_tile_body_resident(
+            P, rc, a_gu, a_gi, a_valid, n_total, bs, bi, tile_start,
+            tile=tile, top_k=top_k, llr_threshold=llr_threshold,
+            exclude_self=exclude_self, pallas=pallas, mm=mm, topk=topk,
+            axis_name=axis_name)
+
+    return _scan_tiles(step, rc.shape[0], n_tiles, tile, top_k,
+                       carry_k=_carry_width(top_k, topk),
+                       axis_name=axis_name)
 
 
 @partial(jax.jit, static_argnames=(
@@ -448,54 +527,133 @@ def _cco_resident_all_tiles(
     exclude_self: bool, pallas: str, mm: str, topk: str = "lax",
 ):
     """All RESIDENT-path item tiles in one compiled program (_scan_tiles)."""
+    return _resident_all_tiles(
+        P, rc, a_gu, a_gi, a_valid, n_total, n_tiles=n_tiles, tile=tile,
+        top_k=top_k, llr_threshold=llr_threshold, exclude_self=exclude_self,
+        pallas=pallas, mm=mm, topk=topk)
 
-    def step(bs, bi, tile_start):
-        return _cco_tile_body_resident(
-            P, rc, a_gu, a_gi, a_valid, n_total, bs, bi, tile_start,
-            tile=tile, top_k=top_k, llr_threshold=llr_threshold,
-            exclude_self=exclude_self, pallas=pallas, mm=mm, topk=topk)
 
-    return _scan_tiles(step, P.shape[1], n_tiles, tile, top_k,
-                       carry_k=_carry_width(top_k, topk))
+def _valid_slots(count, width: int):
+    """bool [width]: the first ``count[0]`` slots of one chip's row of a
+    ``_StagedCOO`` hold a pair."""
+    return jax.lax.iota(jnp.int32, width) < count[0]
+
+
+@partial(jax.jit, static_argnames=("mesh", "n_rows", "n_cols"))
+def _densify_sharded(lu, it, cnt, mesh: Mesh, n_rows: int, n_cols: int):
+    """``_densify_global`` on every chip of ``mesh`` for its own range of
+    users: the primary as ``[dp * n_rows, n_cols]`` sharded by rows, and
+    its items' user counts summed over the chips and scattered like the
+    count tiles (chip d holds the counts of its ``n_cols / dp`` items)."""
+
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("dp"),) * 3,
+             out_specs=(P("dp"), P("dp")))
+    def run(lu, it, cnt):
+        Pm = _densify_global(lu[0], it[0], _valid_slots(cnt, lu.shape[1]),
+                             n_rows, n_cols)
+        return Pm, jax.lax.psum_scatter(_col_count(Pm), "dp", tiled=True)
+
+    return run(lu, it, cnt)
+
+
+@partial(jax.jit, static_argnames=(
+    "mesh", "n_tiles", "tile", "top_k", "exclude_self", "pallas", "mm",
+    "topk"))
+def _cco_sharded_all_tiles(
+    Pm, rc, a_lu, a_it, a_cnt, n_total, mesh: Mesh,
+    n_tiles: int, tile: int, top_k: int, llr_threshold,
+    exclude_self: bool, pallas: str, mm: str, topk: str = "lax",
+):
+    """``_cco_resident_all_tiles`` with the users sharded over ``dp``: one
+    compiled program of the same scan under ``shard_map``, the carry
+    sharded by the primary's item rows."""
+    rows, rep = P("dp"), P()
+
+    # the Pallas interpreter slices a chip's own block by a loop index
+    # that is the same on every chip, which the varying-axes check refuses
+    @partial(jax.shard_map, mesh=mesh, in_specs=(rows,) * 5 + (rep, rep),
+             out_specs=(rows, rows), check_vma=pallas != "interpret")
+    def run(Pm, rc, a_lu, a_it, a_cnt, n_total, llr_threshold):
+        return _resident_all_tiles(
+            Pm, rc, a_lu[0], a_it[0], _valid_slots(a_cnt, a_lu.shape[1]),
+            n_total, n_tiles=n_tiles, tile=tile, top_k=top_k,
+            llr_threshold=llr_threshold, exclude_self=exclude_self,
+            pallas=pallas, mm=mm, topk=topk, axis_name="dp")
+
+    return run(Pm, rc, a_lu, a_it, a_cnt, n_total, llr_threshold)
+
+
+def _pad_items(n_items_p: int, dp: int) -> int:
+    """The primary's item rows as a program over ``dp`` chips holds them:
+    whole 128-row tiles a chip, so that the reduce-scatter's shards are
+    the chips' own rows as they lie (at a multiple of 8 the TPU compiler
+    pads each shard to 128 itself and moves the difference between
+    neighbours, a pad, a permute and a concatenate a tile [AOT, PR 33]);
+    as they are on one device."""
+    return n_items_p if dp == 1 else math.ceil(n_items_p / (dp * 128)) * dp * 128
 
 
 def _cco_resident(
     pu: np.ndarray, pi: np.ndarray, au: np.ndarray, ai: np.ndarray,
     n_users: int, n_items_p: int, n_items_t: int,
     top_k: int, llr_threshold: float, item_tile: int, exclude_self: bool,
+    mesh: Optional[Mesh] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """One event type on the P-resident program, from the pairs as the
-    engine has them: the int32 casts are all the host layout there is."""
-    with span("layout"):
-        pu, pi = np.asarray(pu, np.int32), np.asarray(pi, np.int32)
-        au, ai = np.asarray(au, np.int32), np.asarray(ai, np.int32)
-    n_rows = _pad128(n_users)
-    mm = _matmul_dtype()
-    with span("h2d", bytes=pu.nbytes + pi.nbytes):
-        p_gu, p_gi = jnp.asarray(pu), jnp.asarray(pi)
-        p_valid = jnp.ones(len(pu), bool)
-    with span("dispatch", program="_densify_global"):
-        P = _densify_global(p_gu, p_gi, p_valid, n_rows, n_items_p)
-        rc = _col_count(P)
-    with span("h2d", bytes=au.nbytes + ai.nbytes):
-        a_gu, a_gi = jnp.asarray(au), jnp.asarray(ai)
-        a_valid = jnp.ones(len(au), bool)
-    tile = min(item_tile, max(n_items_t, 1))
-    n_tiles = math.ceil(n_items_t / tile)
-
+    engine has them.  On one device the int32 casts are all the host
+    layout there is; on a mesh the pairs are bucketed into ``dp``
+    contiguous ranges of users (``_stage_chunked``), one a chip."""
     from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
+    tile = min(item_tile, max(n_items_t, 1))
+    n_tiles = math.ceil(n_items_t / tile)
     topk = topk_impl()
-    with span("dispatch", program="_cco_resident_all_tiles",
+    static = dict(n_tiles=n_tiles, tile=tile, top_k=top_k,
+                  exclude_self=exclude_self, pallas=pallas_mode(),
+                  mm=_matmul_dtype(), topk=topk)
+    if mesh is None:
+        with span("layout"):
+            pu, pi = np.asarray(pu, np.int32), np.asarray(pi, np.int32)
+            au, ai = np.asarray(au, np.int32), np.asarray(ai, np.int32)
+        n_rows = _pad128(n_users)
+        with span("h2d", bytes=pu.nbytes + pi.nbytes):
+            p_gu, p_gi = jnp.asarray(pu), jnp.asarray(pi)
+            p_valid = jnp.ones(len(pu), bool)
+        with span("dispatch", program="_densify_global"):
+            Pm = _densify_global(p_gu, p_gi, p_valid, n_rows, n_items_p)
+            rc = _col_count(Pm)
+        with span("h2d", bytes=au.nbytes + ai.nbytes):
+            a_gu, a_gi = jnp.asarray(au), jnp.asarray(ai)
+            a_valid = jnp.ones(len(au), bool)
+        with span("dispatch", program="_cco_resident_all_tiles",
+                  **_topk_attrs(topk, tile, top_k)):
+            best_scores, best_idx = _cco_resident_all_tiles(
+                Pm, rc, a_gu, a_gi, a_valid, float(n_users),
+                llr_threshold=float(llr_threshold), **static)
+        return _finalize_topk(best_scores, best_idx, n_items_t, top_k)
+
+    dp = mesh.shape["dp"]
+    users_chip = math.ceil(max(n_users, 1) / dp)
+    rows = _pad_items(n_items_p, dp)
+    by_user = NamedSharding(mesh, P("dp"))
+    p = _stage_chunked(pu, pi, users_chip, dp, by_user, by_chip=True)
+    with span("dispatch", program="_densify_sharded", dp=dp):
+        Pm, rc = _densify_sharded(p.local_u, p.item, p.count, mesh=mesh,
+                                  n_rows=_pad128(users_chip), n_cols=rows)
+    # the primary against itself: the pairs are staged already
+    a = p if au is pu and ai is pi else _stage_chunked(
+        au, ai, users_chip, dp, by_user, by_chip=True)
+    with span("dispatch", program="_cco_sharded_all_tiles", dp=dp,
+              tiles=n_tiles, rows_per_chip=rows // dp,
+              # what one chip sends in the job's reduce-scatters: all of
+              # every float32 partial count tile but its own rows
+              exchange_mb=n_tiles * (rows - rows // dp) * tile * 4 / 1e6,
               **_topk_attrs(topk, tile, top_k)):
-        best_scores, best_idx = _cco_resident_all_tiles(
-            P, rc, a_gu, a_gi, a_valid, float(n_users),
-            n_tiles=n_tiles, tile=tile, top_k=top_k,
-            llr_threshold=float(llr_threshold),
-            exclude_self=exclude_self, pallas=pallas_mode(), mm=mm,
-            topk=topk,
-        )
-    return _finalize_topk(best_scores, best_idx, n_items_t, top_k)
+        best_scores, best_idx = _cco_sharded_all_tiles(
+            Pm, rc, a.local_u, a.item, a.count, float(n_users),
+            llr_threshold=float(llr_threshold), mesh=mesh, **static)
+    return _finalize_topk(best_scores, best_idx, n_items_t, top_k,
+                          n_rows=n_items_p)
 
 
 # ---------------------------------------------------------------------------
@@ -544,11 +702,7 @@ def _cooccurrence_tile(
         jnp.zeros((n_items_p,), jnp.int32),
         jnp.zeros((tile,), jnp.int32),
     )
-    if axis_name is not None:
-        # under shard_map the carry varies per dp shard
-        init = jax.tree.map(
-            lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), init)
-    out, _ = jax.lax.scan(body, init,
+    out, _ = jax.lax.scan(body, _varying(init, axis_name),
                           (p_lu, p_it, p_cnt, a_lu, a_it, a_cnt))
     return out
 
@@ -676,10 +830,8 @@ def _cco_counts_dense(
         jnp.zeros((n_items_p,), jnp.int32),
         jnp.zeros((it_pad,), jnp.int32),
     )
-    if axis_name is not None:
-        init = jax.tree.map(
-            lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), init)
-    (C, rc, cc), _ = jax.lax.scan(body, init, (p_lu, p_it, p_cnt, a_lu, a_it, a_cnt))
+    (C, rc, cc), _ = jax.lax.scan(body, _varying(init, axis_name),
+                                  (p_lu, p_it, p_cnt, a_lu, a_it, a_cnt))
     if axis_name is not None:
         C, rc, cc = jax.lax.psum((C, rc, cc), axis_name)
     return C, rc, cc
@@ -719,8 +871,13 @@ class _StagedCOO:
 
 def _stage_chunked(
     user: np.ndarray, item: np.ndarray,
-    chunk: int, n_chunks: int, sharding=None,
+    chunk: int, n_chunks: int, sharding=None, by_chip: bool = False,
 ) -> _StagedCOO:
+    """Pairs bucketed into ``n_chunks`` contiguous ranges of ``chunk``
+    users (in-range user index, padded to the fullest range, a count of
+    valid slots a range) and handed to the device.  ``by_chip``: a range
+    is one chip's users (``sharding`` puts row d on chip d), and the
+    ``layout`` span says how evenly the events fell."""
     from predictionio_tpu.native import layout_chunks
 
     user = np.asarray(user, np.int32)
@@ -730,7 +887,7 @@ def _stage_chunked(
     if len(user) and (int(user.min()) < 0 or int(user.max()) >= chunk * n_chunks):
         raise ValueError(
             f"user ids outside [0, {chunk * n_chunks}) in _stage_chunked")
-    with span("layout"):
+    with span("layout") as rec:
         native = (layout_chunks(user, item, chunk, n_chunks)
                   if len(user) else None)
         if native is not None:
@@ -741,6 +898,11 @@ def _stage_chunked(
                 [(user, item)], n_chunks * chunk, 0, user_block=chunk)
             lu, it = b.local_u[:n_chunks], b.item[:n_chunks]
             counts = b.count[:n_chunks]
+        if by_chip:
+            rec["attrs"] = {
+                "dp": n_chunks, "users_per_chip": chunk,
+                "events_max_chip": int(counts.max()),
+                "pad_events": int(lu.size - counts.sum())}
     if sharding is not None:
         from predictionio_tpu.parallel.sharding import stage_global
 
@@ -1277,20 +1439,31 @@ def _plan(n_users: int, n_items_p: int, n_items_t: int,
       picks it there.
     - ``dense`` (PIO_CCO_DENSE): the full I_p×I_t 32-bit count matrix fits
       ``_DENSE_C_BYTES``.
-    - ``resident`` (one device): tiled over items with the densified
-      primary kept in HBM, when the program's plan fits ``_TILED_P_BYTES``
-      and counts stay exact: bf16 contracts the full user space in one f32
-      pass, so n_users must stay below 2²⁴ (int8 accumulates int32 and has
-      no such cap).  The plan is what the compiler holds for
-      ``_cco_resident_all_tiles``: the densified primary as an argument,
-      and per tile the densified slab of the other type, the float32 count
-      tile and the float32 scores made from it.  At 32,768 × 100,000, tile
-      4,096, bf16 that is 6.55 + 0.27 + 2 × 1.64 = 10.10 GB; the TPU
+    - ``resident``: tiled over items with the densified primary kept in
+      HBM, when what ONE chip holds of the program's plan fits
+      ``_TILED_P_BYTES`` and counts stay exact: bf16 contracts a chip's
+      users in one f32 pass and the chips' partial counts are summed in
+      f32, so n_users must stay below 2²⁴ (int8 accumulates int32 and has
+      no such cap).  ``dp`` is the mesh's, 1 without one.  The plan is
+      what the compiler holds for ``_cco_resident_all_tiles``, or on a
+      mesh for ``_cco_sharded_all_tiles`` on each chip: the densified
+      primary of the chip's ``n_users / dp`` users (padded to 128) as an
+      argument, and per tile the densified slab of the other type for
+      those users, the float32 count tile and the float32 scores made
+      from it.  On a mesh the count tile a chip computes is a partial one
+      and is held whole, and what the reduce-scatter leaves it, and the
+      scores, are a ``dp``-th each.  At 32,768 × 100,000, tile 4,096,
+      bf16, one device: 6.55 + 0.27 + 2 × 1.64 = 10.10 GB; the TPU
       compiler plans 6.11 GiB of arguments + 3.20 GiB of temporaries =
       10.0 GB there [AOT, PR 25] and the chip's peak read 10.08 GB (chip
-      run, PR 25).
+      run, PR 25).  At 131,072 × 100,000 over four chips (the item rows
+      padded to 100,352, ``_pad_items``): 6.58 + 0.27 + 1.64 + 2 × 0.41 =
+      9.31 GB a chip; the TPU compiler plans 6.13 GiB of arguments + 1.93
+      GiB of temporaries = 8.66 GB [AOT, PR 33].
     - ``chunked``: tiled over items, the primary re-densified per user
-      block and tile; whatever is left."""
+      block and tile; whatever is left.  On a mesh it is one sharded step
+      a tile, dispatched from a Python loop, the whole count tile
+      ``psum``'d and scored on every chip alike."""
     host: Tuple[str, ...] = ()
     if mesh is None:
         sparse = _switch("PIO_CCO_SPARSE")
@@ -1303,14 +1476,17 @@ def _plan(n_users: int, n_items_p: int, n_items_t: int,
         dense = n_items_p * _pad128(n_items_t) * 4 <= _DENSE_C_BYTES
     if dense:
         return host + ("dense",)
-    if mesh is None:
-        int8 = _matmul_dtype() == "int8"
-        n_rows = _pad128(n_users)
-        tile = min(item_tile, max(n_items_t, 1))
-        plan = (n_rows * n_items_p + n_rows * tile) * (1 if int8 else 2) \
-            + 2 * n_items_p * tile * 4
-        if plan <= _TILED_P_BYTES and (int8 or n_users < (1 << 24)):
-            return host + ("resident",)
+    dp = 1 if mesh is None else mesh.shape["dp"]
+    int8 = _matmul_dtype() == "int8"
+    n_rows = _pad128(math.ceil(n_users / dp))
+    rows = _pad_items(n_items_p, dp)
+    tile = min(item_tile, max(n_items_t, 1))
+    # float32 tiles of `rows`: the partial one (a mesh's alone), then the
+    # chip's rows of the counts and of the scores
+    plan = (n_rows * rows + n_rows * tile) * (1 if int8 else 2) \
+        + (rows * (dp > 1) + 2 * (rows // dp)) * tile * 4
+    if plan <= _TILED_P_BYTES and (int8 or n_users < (1 << 24)):
+        return host + ("resident",)
     return host + ("chunked",)
 
 
@@ -1382,7 +1558,7 @@ def cco_train_indicators(
             elif strategy == "resident":
                 results[name] = _cco_resident(
                     p_user, p_item, au, ai, n_users, n_items_p, n_items_t,
-                    t_k, t_llr, item_tile, excl)
+                    t_k, t_llr, item_tile, excl, mesh=mesh)
             else:
                 with span("layout") as rec:
                     p = block_interactions(p_user, p_item, n_users, n_items_p,

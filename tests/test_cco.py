@@ -137,27 +137,52 @@ def test_cco_exclude_self():
         assert row not in idx[row][idx[row] >= 0]
 
 
-def test_cco_mesh_matches_single():
+# what a mesh takes: the dense strategy at these sizes when nothing is
+# pinned, the chunked tiled strategy where no chip's share of the primary
+# stays resident, the sharded resident program (tests/test_cco_sharded.py
+# holds it to the reference) where it does
+MESH_PROGRAMS = ["auto", "chunked", "resident"]
+
+
+def _take_mesh_program(monkeypatch, program):
+    if program != "auto":
+        _take_program(monkeypatch, program)
+
+
+def _mesh_strategy(n_users, n_ip, n_it, mesh, item_tile=4096):
+    from predictionio_tpu.ops.cco import _plan
+
+    return _plan(n_users, n_ip, n_it, mesh, item_tile)[-1]
+
+
+@pytest.mark.parametrize("program", MESH_PROGRAMS)
+def test_cco_mesh_matches_single(monkeypatch, program):
+    _take_mesh_program(monkeypatch, program)
     n_users, n_ip, n_it = 64, 12, 10
     pu, pi = random_interactions(n_users, n_ip, 300, 6)
     ou, oi = random_interactions(n_users, n_it, 300, 7)
     s1, i1 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
                                 top_k=5, user_block=8)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
+    assert _mesh_strategy(n_users, n_ip, n_it, mesh) == (
+        "dense" if program == "auto" else program)
     s8, i8 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
                                 top_k=5, user_block=8, mesh=mesh)
     assert np.allclose(np.where(np.isfinite(s1), s1, -1), np.where(np.isfinite(s8), s8, -1), atol=1e-3)
     assert (i1 == i8).all()
 
 
+@pytest.mark.parametrize("program", ["chunked", "resident"])
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_tiled_mesh_matches_single(monkeypatch, kernels):
-    """The tiled strategy sharded over dp — what `pio train` takes by
+def test_tiled_mesh_matches_single(monkeypatch, kernels, program):
+    """The tiled strategies sharded over dp — what `pio train` takes by
     default on a several-chip host once the catalog outgrows the dense
-    budget — equals one device; also with the Pallas LLR and top-k
-    kernels traced INSIDE the shard_map step (interpreted here), which
-    is how that step runs on TPUs."""
-    _take_program(monkeypatch, "resident")
+    budget: the resident program with the count tiles reduce-scattered
+    where a chip's share of the primary fits, one sharded step a tile
+    with the whole tile `psum`'d where it does not — equal one device;
+    also with the Pallas LLR and top-k kernels traced INSIDE the
+    shard_map (interpreted here), which is how they run on TPUs."""
+    _take_program(monkeypatch, program)
     if kernels == "pallas":
         monkeypatch.setenv("PIO_PALLAS", "interpret")
     n_users, n_ip, n_it = 64, 12, 10
@@ -166,6 +191,7 @@ def test_tiled_mesh_matches_single(monkeypatch, kernels):
     s1, i1 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
                                 top_k=5, user_block=8, item_tile=4)
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
+    assert _mesh_strategy(n_users, n_ip, n_it, mesh, 4) == program
     s8, i8 = cco_indicators_coo(pu, pi, ou, oi, n_users, n_ip, n_it,
                                 top_k=5, user_block=8, item_tile=4, mesh=mesh)
     np.testing.assert_allclose(s1, s8, rtol=1e-5)
@@ -308,10 +334,14 @@ def test_cco_train_indicators_tiled_fallback(monkeypatch):
         np.testing.assert_allclose(dense[name][0], tiled[name][0], rtol=1e-4)
 
 
-def test_cco_train_indicators_mesh(monkeypatch):
+@pytest.mark.parametrize("program", ["dense", "chunked", "resident"])
+def test_cco_train_indicators_mesh(monkeypatch, program):
     from predictionio_tpu.ops.cco import cco_train_indicators
 
-    monkeypatch.setenv("PIO_CCO_DENSE", "1")
+    if program == "dense":     # as before the tiled cases: one switch
+        monkeypatch.setenv("PIO_CCO_DENSE", "1")
+    else:
+        _take_program(monkeypatch, program)
     n_users, n_ip, n_view = 64, 10, 12
     pu, pi = random_interactions(n_users, n_ip, 250, 81)
     vu, vi = random_interactions(n_users, n_view, 400, 82)
@@ -319,6 +349,7 @@ def test_cco_train_indicators_mesh(monkeypatch):
         pu, pi, [("buy", pu, pi, n_ip), ("view", vu, vi, n_view)],
         n_users, n_ip, top_k=5, exclude_self_for="buy")
     mesh = create_mesh(MeshSpec(dp=8, mp=1))
+    assert _mesh_strategy(n_users, n_ip, n_view, mesh) == program
     sharded = cco_train_indicators(
         pu, pi, [("buy", pu, pi, n_ip), ("view", vu, vi, n_view)],
         n_users, n_ip, top_k=5, exclude_self_for="buy", mesh=mesh)
