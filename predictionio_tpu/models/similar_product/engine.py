@@ -233,7 +233,7 @@ class SPALSAlgorithm(Algorithm):
 class SPCooccurrenceParams(Params):
     max_correlators_per_item: int = 50
     min_llr: float = 0.0
-    user_block: int = 1024
+    user_block: int = 0     # 0: derived from the bytes (ops/cco._block_plan)
     item_tile: int = 4096
     mesh_dp: int = 0
 

@@ -1141,7 +1141,7 @@ class URAlgorithmParams(Params):
     min_llr: float = 0.0
     max_query_events: int = 100
     num: int = 20
-    user_block: int = 1024
+    user_block: int = 0     # 0: derived from the bytes (ops/cco._block_plan)
     item_tile: int = 4096
     mesh_dp: int = 0
     use_llr_weights: bool = False

@@ -411,6 +411,12 @@ def _pad128(n: int) -> int:
     return max(((n + 127) // 128) * 128, 128)
 
 
+def _tiling(n_items_t: int, item_tile: int) -> Tuple[int, int]:
+    """``(tile, n_tiles)`` of an event type's items under ``itemTile``."""
+    tile = min(item_tile, max(n_items_t, 1))
+    return tile, math.ceil(n_items_t / tile)
+
+
 # ---------------------------------------------------------------------------
 # P-resident tiled path (huge catalogs, but the densified primary fits HBM)
 # ---------------------------------------------------------------------------
@@ -661,47 +667,53 @@ def _cco_resident(
 # ---------------------------------------------------------------------------
 
 
-def _cooccurrence_tile(
+def _cooccurrence_group(
     p_lu, p_it, p_cnt,       # primary blocks [n_blocks, E_p], [n_blocks]
     a_lu, a_it, a_cnt,       # other blocks   [n_blocks, E_a], [n_blocks]
+    rc, count_rows,
     block: int,
     n_items_p: int,
-    tile_start,
+    start,
     tile: int,
+    group: int,
     axis_name: Optional[str] = None,
 ):
-    """One item tile's counts AND the LLR marginals, on device:
-    C_tile [I_p, tile] = Σ_b P_bᵀ A_b[:, tile];  rc = Σ_b colsum(P_b);
-    cc_tile = Σ_b colsum(A_b[:, tile]).  Marginals come from the densified
-    (hence dedup'd) matrices — no host unique pass feeds this path.
-    ``p_cnt``/``a_cnt`` give each block's valid slots; validity is an iota
-    comparison on device, as in ``_cco_counts_dense``."""
+    """The counts of ``group`` adjacent item tiles from column ``start``
+    AND the LLR marginals, on device, every user block densified once
+    for all of them:
+    C [I_p, group·tile] = Σ_b P_bᵀ A_b[:, start : start + group·tile];
+    cc = Σ_b colsum(A_b[:, the same columns]); and, where ``count_rows``
+    (a bool, traced or not: the primary's counts are the same numbers in
+    every group, so one group of an event type sums them), ``rc`` +
+    Σ_b colsum(P_b).  Marginals come from the densified (hence dedup'd)
+    matrices — no host unique pass feeds this path.  ``p_cnt``/``a_cnt``
+    give each block's valid slots; validity is an iota comparison on
+    device, as in ``_cco_counts_dense``."""
     in_dtype = _mm_in_dtype()
     mm = _matmul_dtype()
     e_p, e_a = p_lu.shape[1], a_lu.shape[1]
+    width = group * tile
 
     def body(carry, xs):
-        C, rc, cct = carry
+        C, rc, cc = carry
         plu, pit, pcnt, alu, ait, acnt = xs
         with jax.named_scope("cco.densify_block"):
             pvalid = jax.lax.iota(jnp.int32, e_p) < pcnt
             pb = _densify(plu, pit, pvalid, block, n_items_p, in_dtype)
-            a_local = ait - tile_start
-            in_tile = ((jax.lax.iota(jnp.int32, e_a) < acnt)
-                       & (a_local >= 0) & (a_local < tile))
-            ab = _densify(alu, jnp.where(in_tile, a_local, 0), in_tile,
-                          block, tile, in_dtype)
+            a_local = ait - start
+            in_group = ((jax.lax.iota(jnp.int32, e_a) < acnt)
+                        & (a_local >= 0) & (a_local < width))
+            ab = _densify(alu, jnp.where(in_group, a_local, 0), in_group,
+                          block, width, in_dtype)
         with jax.named_scope("cco.count_matmul"):
             C = C + _count_matmul(pb, ab, mm)
-            rc = rc + _col_count(pb)
-            cct = cct + _col_count(ab)
-        return (C, rc, cct), None
+            rc = jax.lax.cond(count_rows, lambda: rc + _col_count(pb),
+                              lambda: rc)
+            cc = cc + _col_count(ab)
+        return (C, rc, cc), None
 
-    init = (
-        jnp.zeros((n_items_p, tile), jnp.int32),
-        jnp.zeros((n_items_p,), jnp.int32),
-        jnp.zeros((tile,), jnp.int32),
-    )
+    init = (jnp.zeros((n_items_p, width), jnp.int32), rc,
+            jnp.zeros((width,), jnp.int32))
     out, _ = jax.lax.scan(body, _varying(init, axis_name),
                           (p_lu, p_it, p_cnt, a_lu, a_it, a_cnt))
     return out
@@ -710,60 +722,86 @@ def _cooccurrence_tile(
 @partial(
     jax.jit,
     static_argnames=(
-        "block", "n_items_p", "tile", "top_k", "axis_name", "pallas",
-        "exclude_self", "topk",
+        "block", "n_items_p", "tile", "group", "top_k", "axis_name",
+        "pallas", "exclude_self", "topk",
     ),
 )
-def _cco_tile_step(
+def _cco_group_step(
     p_lu, p_it, p_cnt, a_lu, a_it, a_cnt,
     n_total,
-    best_scores, best_idx,
-    tile_start,
-    block: int, n_items_p: int, tile: int, top_k: int,
+    best_scores, best_idx, rc, count_rows,
+    start,
+    block: int, n_items_p: int, tile: int, group: int, top_k: int,
     llr_threshold: float,
     axis_name: Optional[str] = None,
     pallas: str = "off",
     exclude_self: bool = False,
     topk: str = "lax",
 ):
-    """Process one item tile: cooccurrence counts → LLR → merge into top-k."""
-    c, rc, cct = _cooccurrence_tile(
-        p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, block, n_items_p, tile_start,
-        tile, axis_name,
+    """Process one group of item tiles: cooccurrence counts against each
+    user block densified once, then tile by tile as a lone tile would be,
+    LLR → merge into top-k.  Returns the carry and the primary's counts
+    (``rc`` as given where ``count_rows`` is false)."""
+    c, rc, cc = _cooccurrence_group(
+        p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, rc, count_rows, block,
+        n_items_p, start, tile, group, axis_name,
     )
     if axis_name is not None:
-        c, rc, cct = jax.lax.psum((c, rc, cct), axis_name)
-    with jax.named_scope("cco.llr"):
-        scores = _llr_mask_scores(
-            c.astype(jnp.float32), rc.astype(jnp.float32),
-            cct.astype(jnp.float32), n_total, llr_threshold, pallas)
-    return _merge_topk(best_scores, best_idx, scores, tile_start, tile,
-                       top_k, n_items_p, exclude_self, impl=topk)
+        c, rc, cc = jax.lax.psum((c, rc, cc), axis_name)
+    rcf = rc.astype(jnp.float32)
+
+    def one_tile(g, best):
+        # one tile at a time: a loop, so that the compiler holds one
+        # float32 tile and its scores, not the group's [AOT, PR 34]
+        with jax.named_scope("cco.llr"):
+            c_t = jax.lax.dynamic_slice(c, (0, g * tile), (n_items_p, tile))
+            cc_t = jax.lax.dynamic_slice(cc, (g * tile,), (tile,))
+            scores = _llr_mask_scores(
+                c_t.astype(jnp.float32), rcf, cc_t.astype(jnp.float32),
+                n_total, llr_threshold, pallas)
+        return _merge_topk(*best, scores, start + g * tile, tile, top_k,
+                           n_items_p, exclude_self, impl=topk)
+
+    best = jax.lax.fori_loop(0, group, one_tile, (best_scores, best_idx))
+    return (*best, rc)
 
 
 @partial(
     jax.jit,
     static_argnames=(
-        "n_tiles", "block", "n_items_p", "tile", "top_k", "pallas",
+        "n_tiles", "group", "block", "n_items_p", "tile", "top_k", "pallas",
         "exclude_self", "topk",
     ),
 )
 def _cco_chunked_all_tiles(
     p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, n_total,
-    n_tiles: int, block: int, n_items_p: int, tile: int, top_k: int,
-    llr_threshold, pallas: str, exclude_self: bool, topk: str = "lax",
+    n_tiles: int, group: int, block: int, n_items_p: int, tile: int,
+    top_k: int, llr_threshold, pallas: str, exclude_self: bool,
+    topk: str = "lax",
 ):
-    """All chunked-path item tiles in one compiled program (_scan_tiles)."""
+    """All chunked-path item tiles in one compiled program: a scan over
+    the whole groups of ``group`` tiles, the first of which sums the
+    primary's counts, and the tiles left over (``n_tiles`` mod ``group``)
+    as one step of their own size, not a group padded with empty tiles."""
 
-    def step(bs, bi, tile_start):
-        return _cco_tile_step(
-            p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, n_total, bs, bi, tile_start,
-            block=block, n_items_p=n_items_p, tile=tile, top_k=top_k,
-            llr_threshold=llr_threshold, pallas=pallas,
-            exclude_self=exclude_self, topk=topk)
+    def step(carry, start, size, count_rows):
+        return _cco_group_step(
+            p_lu, p_it, p_cnt, a_lu, a_it, a_cnt, n_total, *carry,
+            count_rows, start, block=block, n_items_p=n_items_p, tile=tile,
+            group=size, top_k=top_k, llr_threshold=llr_threshold,
+            pallas=pallas, exclude_self=exclude_self, topk=topk)
 
-    return _scan_tiles(step, n_items_p, n_tiles, tile, top_k,
-                       carry_k=_carry_width(top_k, topk))
+    carry_k = _carry_width(top_k, topk)
+    carry = (jnp.full((n_items_p, carry_k), -jnp.inf, jnp.float32),
+             jnp.zeros((n_items_p, carry_k), jnp.int32),
+             jnp.zeros((n_items_p,), jnp.int32))
+    whole, rest = divmod(n_tiles, group)
+    starts = jnp.arange(whole, dtype=jnp.int32) * (group * tile)
+    carry, _ = jax.lax.scan(
+        lambda c, s: (step(c, s, group, s == 0), None), carry, starts)
+    if rest:
+        carry = step(carry, jnp.int32(whole * group * tile), rest, whole == 0)
+    return carry[0], carry[1]
 
 
 # ---------------------------------------------------------------------------
@@ -1461,9 +1499,11 @@ def _plan(n_users: int, n_items_p: int, n_items_t: int,
       9.31 GB a chip; the TPU compiler plans 6.13 GiB of arguments + 1.93
       GiB of temporaries = 8.66 GB [AOT, PR 33].
     - ``chunked``: tiled over items, the primary re-densified per user
-      block and tile; whatever is left.  On a mesh it is one sharded step
-      a tile, dispatched from a Python loop, the whole count tile
-      ``psum``'d and scored on every chip alike."""
+      block and GROUP of tiles; whatever is left.  The block and the
+      group are ``_block_plan``'s, from the bytes this budget leaves.  On
+      a mesh it is one sharded step a tile, dispatched from a Python
+      loop, the whole count tile ``psum``'d and scored on every chip
+      alike."""
     host: Tuple[str, ...] = ()
     if mesh is None:
         sparse = _switch("PIO_CCO_SPARSE")
@@ -1490,6 +1530,72 @@ def _plan(n_users: int, n_items_p: int, n_items_t: int,
     return host + ("chunked",)
 
 
+# The user block the count matmul needs to run at the MXU's rate: at
+# 131,072 × 100,000 a job's count matmuls took 34.7 s at (K 1,024, G 4),
+# 28.0 s at (2,048, 4), 28.3 s at (4,096, 3) and 30.7–31.0 s at (8,192, 3)
+# and (8,192, 2), against 35.65 s at (1,024, 1) and a floor of 26.6 s, or
+# 27.25 s with the last tile's empty columns (chip runs, PR 34; ledger,
+# PR 33).  Past it, bytes serve the program better as tiles counted
+# against one densified block: every tile more in a group is a densify of
+# the whole primary less.
+_BLOCK_ROWS = 2048
+
+
+def _block_plan(n_rows: int, n_items_p: int, tile: int, n_tiles: int,
+                block: int = 0, own_slab: bool = True,
+                f32_tiles: int = 2) -> Tuple[int, int, int]:
+    """``(user block K, tiles a group G, plan bytes)`` of the user-blocked
+    program, by ``_plan``'s accounting against the same ``_TILED_P_BYTES``.
+    Counted for ``_cco_chunked_all_tiles`` are the G carried int32 count
+    tiles (G × I_p × tile × 4), the float32 tile and the scores made from
+    it (``f32_tiles`` × I_p × tile × 4), and the densified block [K, I_p]
+    with the other type's slab [K, G × tile] in the count matmul's input
+    type three times over (the zero fill, the flat scatter's result, and
+    its copy re-laid as a matrix: what ``_basket_plan`` counts, and [AOT,
+    PR 31] bore out).  K and G compete for the same bytes: every densified
+    byte is paid once a block and GROUP, so a job densifies the primary
+    ⌈tiles ÷ G⌉ times an event type, and K is the count matmul's
+    contraction.
+
+    The rule: G is the most tiles that leave a block of ``_BLOCK_ROWS``
+    (or of all the rows, padded to 128, where they are fewer), at least
+    one; K the largest power of two of rows that fits beside them (PR 31:
+    a contraction of 13,312 ran 14% slower than 8,192), at least 128, at
+    most the padded rows and 2²³ (a block's counts accumulate in float32).
+    A ``block`` given is taken as K and only G is derived.  It reads
+    shapes, the input type and the budget, nothing else.
+
+    At 131,072 × 100,000, tile 4,096, bf16: K 2,048, G 4 (11.26 GB; five
+    tiles would leave 733 rows), 64 blocks × 7 groups a type, the primary
+    densified 7 times where a tile a step densified it 25 times; in int8
+    K 4,096, G 4.  The TPU compiler's own plan there is (G + 4) tiles with
+    the densified block inside the four, 13.2 GB [AOT, PR 34]; the chip's
+    peak read (G + 2) tiles, 9.99 GB (chip run, PR 34).
+
+    The basket program is this plan with no slab of its own (the tile's
+    is a slice of the block) and one float32 tile: ``own_slab=False,
+    f32_tiles=1`` at G = 1 gives ``_basket_plan``'s chunk of 8,192 at its
+    cell's shape."""
+    in_bytes = 1 if _matmul_dtype() == "int8" else 2
+    cap = min(_pad128(n_rows), 1 << 23)
+
+    def tiles_bytes(g: int) -> int:
+        return (g + f32_tiles) * n_items_p * tile * 4
+
+    def row_bytes(g: int) -> int:
+        return 3 * (n_items_p + own_slab * g * tile) * in_bytes
+
+    def room(g: int) -> int:
+        """The rows a group of ``g`` leaves."""
+        return (_TILED_P_BYTES - tiles_bytes(g)) // row_bytes(g)
+
+    want = block or min(_BLOCK_ROWS, cap)
+    group = max([g for g in range(1, max(n_tiles, 1) + 1)
+                 if room(g) >= want] or [1])
+    k = block or min(1 << max(room(group), 128).bit_length() - 1, cap)
+    return k, group, tiles_bytes(group) + k * row_bytes(group)
+
+
 def cco_train_indicators(
     p_user: np.ndarray, p_item: np.ndarray,
     others: Sequence[Tuple[str, np.ndarray, np.ndarray, int]],
@@ -1498,7 +1604,7 @@ def cco_train_indicators(
     llr_threshold: float = 0.0,
     mesh: Optional[Mesh] = None,
     exclude_self_for: Optional[str] = None,
-    user_block: int = 1024,
+    user_block: int = 0,
     item_tile: int = 4096,
     per_type: Optional[Dict[str, Tuple[int, float]]] = None,
 ) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
@@ -1513,6 +1619,12 @@ def cco_train_indicators(
     t+1 overlaps device compute of type t.  The tiled strategies (count
     matrix past the HBM budget) upload and densify the primary once per
     event type and wait for each result before the next.
+
+    ``user_block`` is read by the ``chunked`` strategy alone: 0 (the
+    default, ``userBlock`` unset in an ``engine.json``) derives the block
+    and the tiles counted against it from the bytes ``_plan``'s budget
+    leaves (``_block_plan``); a value is taken as the block, and only the
+    group is derived.
 
     ``per_type`` optionally overrides ``(top_k, llr_threshold)`` for named
     event types (reference UR: per-indicator maxCorrelatorsPerItem/minLLR).
@@ -1560,11 +1672,15 @@ def cco_train_indicators(
                     p_user, p_item, au, ai, n_users, n_items_p, n_items_t,
                     t_k, t_llr, item_tile, excl, mesh=mesh)
             else:
+                dp = 1 if mesh is None else mesh.shape["dp"]
+                block = _block_plan(
+                    math.ceil(n_users / dp), n_items_p,
+                    *_tiling(n_items_t, item_tile), block=user_block)[0]
                 with span("layout") as rec:
                     p = block_interactions(p_user, p_item, n_users, n_items_p,
-                                           user_block=user_block)
+                                           user_block=block)
                     a = block_interactions(au, ai, n_users, n_items_t,
-                                           user_block=user_block)
+                                           user_block=block)
                     slots = p.local_u.size + a.local_u.size
                     rec["attrs"] = {
                         "user_blocks": p.n_blocks, "slots": slots,
@@ -1585,7 +1701,7 @@ def cco_indicators_coo(
     n_users: int, n_items_p: int, n_items_t: int,
     top_k: int = 50,
     llr_threshold: float = 0.0,
-    user_block: int = 1024,
+    user_block: int = 0,
     item_tile: int = 4096,
     mesh: Optional[Mesh] = None,
     exclude_self: bool = False,
@@ -1611,10 +1727,13 @@ def _cco_chunked(
     mesh: Optional[Mesh] = None,
     exclude_self: bool = False,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """The chunked tiled strategy over two blocked layouts: an item-tile
-    loop that never materializes the full count matrix and merges a
-    running top-k, the primary re-densified per user block and tile,
-    marginals accumulated in the same scan.  One compiled program on one
+    """The chunked tiled strategy over two blocked layouts: a loop over
+    groups of item tiles that never materializes the full count matrix and
+    merges a running top-k, the primary re-densified per user block and
+    group, marginals accumulated in the same scan.  The layouts' block is
+    the program's; the group is what ``_block_plan`` derives beside it
+    (``cco_train_indicators`` lays the pairs out in the block the same
+    plan gives where ``userBlock`` is unset).  One compiled program on one
     device; on a mesh one sharded step a tile, counts ``psum``'d over
     ``dp``."""
     if n_total_users <= 0:
@@ -1622,33 +1741,31 @@ def _cco_chunked(
     if primary.n_blocks != other.n_blocks or primary.user_block != other.user_block:
         raise ValueError("primary/other must be blocked with the same user layout")
     n_items_p, n_items_t = primary.n_items, other.n_items
-    tile = min(item_tile, max(n_items_t, 1))
-    n_tiles = math.ceil(n_items_t / tile)
+    tile, n_tiles = _tiling(n_items_t, item_tile)
 
     topk = topk_impl()
-    carry_k = _carry_width(top_k, topk)
-    best_scores = jnp.full((n_items_p, carry_k), -jnp.inf, jnp.float32)
-    best_idx = jnp.zeros((n_items_p, carry_k), jnp.int32)
 
     from predictionio_tpu.ops.pallas_kernels import pallas_mode
 
-    pallas = pallas_mode()
-
+    static = dict(block=primary.user_block, n_items_p=n_items_p, tile=tile,
+                  top_k=top_k, llr_threshold=float(llr_threshold),
+                  pallas=pallas_mode(), exclude_self=exclude_self, topk=topk)
     host_args = (primary.local_u, primary.item, primary.count,
                  other.local_u, other.item, other.count)
     if mesh is None:
+        _, group, plan_bytes = _block_plan(
+            primary.n_users, n_items_p, tile, n_tiles,
+            block=primary.user_block)
         with span("h2d", bytes=sum(a.nbytes for a in host_args)):
             args = tuple(jnp.asarray(a) for a in host_args)
         with span("dispatch", program="_cco_chunked_all_tiles",
-                  tiles=n_tiles, block_steps=n_tiles * primary.n_blocks,
+                  tiles=n_tiles, user_block=primary.user_block,
+                  tile_group=group, plan_bytes=plan_bytes,
+                  block_steps=math.ceil(n_tiles / group) * primary.n_blocks,
                   **_topk_attrs(topk, tile, top_k)):
             best_scores, best_idx = _cco_chunked_all_tiles(
-                *args, float(n_total_users),
-                n_tiles=n_tiles, block=primary.user_block,
-                n_items_p=n_items_p,
-                tile=tile, top_k=top_k, llr_threshold=float(llr_threshold),
-                pallas=pallas, exclude_self=exclude_self, topk=topk,
-            )
+                *args, float(n_total_users), n_tiles=n_tiles, group=group,
+                **static)
     else:
         dp = mesh.shape["dp"]
         nb = primary.n_blocks
@@ -1668,22 +1785,22 @@ def _cco_chunked(
             args = tuple(stage_global(pad(np.asarray(a)), shard)
                          for a in host_args)
 
+        # groups of one tile, each summing the primary's counts anew
         @partial(
             jax.shard_map, mesh=mesh,
             in_specs=(spec,) * 6 + (rep,) * 3,
             out_specs=(rep, rep),
         )
         def tile_step_sharded(plu, pit, pcnt, alu, ait, acnt, bs, bi, ts):
-            return _cco_tile_step(
+            return _cco_group_step(
                 plu, pit, pcnt, alu, ait, acnt, float(n_total_users),
-                bs, bi, ts,
-                block=primary.user_block, n_items_p=n_items_p,
-                tile=tile, top_k=top_k, llr_threshold=llr_threshold,
-                axis_name="dp", pallas=pallas, exclude_self=exclude_self,
-                topk=topk,
-            )
+                bs, bi, jnp.zeros((n_items_p,), jnp.int32), True, ts,
+                group=1, axis_name="dp", **static)[:2]
 
-        with span("dispatch", program="_cco_tile_step"):
+        carry_k = _carry_width(top_k, topk)
+        best_scores = jnp.full((n_items_p, carry_k), -jnp.inf, jnp.float32)
+        best_idx = jnp.zeros((n_items_p, carry_k), jnp.int32)
+        with span("dispatch", program="_cco_group_step"):
             for t in range(n_tiles):
                 best_scores, best_idx = tile_step_sharded(
                     *args, best_scores, best_idx, jnp.int32(t * tile),

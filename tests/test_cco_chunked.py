@@ -84,9 +84,142 @@ def test_blocks_carry_a_count_and_no_mask_array():
         assert not b.item[~b.mask].any() and not b.local_u[~b.mask].any()
 
 
+# -- the step's shape: the user block and the tiles counted against it -------
+
+U131K = (131072, 100000, 4096, 25)        # users, items, tile, tiles
+GB = 10**9
+
+
+def _plan_bytes(k, g, items, tile, in_bytes):
+    """The accounting the derivation is held to, restated: G carried int32
+    count tiles, the float32 tile and its scores, and the densified block
+    with the group's slab three times over."""
+    return (g + 2) * items * tile * 4 + 3 * k * (items + g * tile) * in_bytes
+
+
+@pytest.mark.parametrize("mm,shape,budget,block,want", [
+    # the blocked cell: four tiles against a block of 2,048 (11.26 GB);
+    # five would leave 733 rows, and 4,096 rows leave three tiles
+    ("bf16", U131K, 12 * GB, 0, (2048, 4)),
+    ("int8", U131K, 12 * GB, 0, (4096, 4)),
+    # an engine.json's userBlock is the block, and only the group is derived
+    ("bf16", U131K, 12 * GB, 1024, (1024, 4)),
+    ("bf16", U131K, 12 * GB, 4096, (4096, 3)),
+    ("bf16", U131K, 12 * GB, 8192, (8192, 2)),
+    ("int8", U131K, 12 * GB, 8192, (8192, 3)),
+    # the resident cell's shape, were it ever sent here
+    ("bf16", (32768, 100000, 4096, 25), 12 * GB, 0, (2048, 4)),
+    ("int8", (32768, 100000, 4096, 25), 12 * GB, 0, (4096, 4)),
+    # no room for two tiles: one, and the largest power of two that fits
+    ("bf16", U131K, 7 * GB, 0, (2048, 1)),
+    ("bf16", U131K, 5 * GB, 0, (128, 1)),
+    # not even one: a group of one tile and one 128-row block all the same
+    ("bf16", U131K, 1 * GB, 0, (128, 1)),
+    # the suite's sizes: no block past the padded users, no group past the
+    # tiles
+    ("bf16", (899, 700, 256, 3), 12 * GB, 0, (1024, 3)),
+    ("bf16", (70, 14, 8, 3), 12 * GB, 0, (128, 3)),
+    ("bf16", (300, 90, 32, 5), 12 * GB, 64, (64, 5)),
+])
+def test_the_steps_shape_comes_from_the_bytes_the_plan_leaves(
+        monkeypatch, mm, shape, budget, block, want):
+    """`_block_plan` reads shapes, the input type and `_TILED_P_BYTES`: the
+    most tiles a group can hold beside a block of `_BLOCK_ROWS` (or of the
+    block given, or of every row), then the deepest power-of-two block
+    beside them."""
+    from predictionio_tpu.ops import cco
+
+    monkeypatch.setenv("PIO_CCO_MM_DTYPE", mm)
+    monkeypatch.setattr(cco, "_TILED_P_BYTES", budget)
+    users, items, tile, tiles = shape
+    k, g, plan_bytes = cco._block_plan(users, items, tile, tiles, block=block)
+    assert (k, g) == want
+    assert 1 <= g <= tiles and k <= max(block, cco._pad128(users))
+    in_bytes = 1 if mm == "int8" else 2
+    assert plan_bytes == _plan_bytes(k, g, items, tile, in_bytes)
+    # only the smallest step there is may pass the budget
+    assert plan_bytes <= budget or (k, g) == (block or 128, 1)
+    if not block and k < cco._pad128(users):
+        assert k & (k - 1) == 0         # the contraction is a power of two
+        # neither a deeper block nor, at this depth, one more tile fits
+        assert _plan_bytes(2 * k, g, items, tile, in_bytes) > budget
+    if g < tiles and plan_bytes <= budget:
+        assert _plan_bytes(block or min(k, cco._BLOCK_ROWS), g + 1, items,
+                           tile, in_bytes) > budget
+
+
+def test_the_basket_plan_is_the_same_derivation_without_a_slab(monkeypatch):
+    """`_basket_plan` returns what it returned, and the chunk it derives is
+    `_block_plan`'s block for a step with no slab of its own and one
+    float32 tile, at the basket cell's shape."""
+    from predictionio_tpu.ops import cco
+
+    monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
+    assert cco._basket_plan(65536, 100000, 4096) == (4096, 25, 8192, 8)
+    assert cco._block_plan(65536, 25 * 4096, 4096, 1, own_slab=False,
+                           f32_tiles=1)[:2] == (8192, 1)
+
+
+GROUPED = dict(n_users=1100, n_ip=90, n_it=150, tile=32, top_k=7)   # 5 tiles
+
+
+@pytest.mark.parametrize("block,group,exclude_self", [
+    (128, 2, False),    # 2 whole groups and a last one of 1; a last block of 76
+    (128, 3, False),    # 1 whole group and a last one of 2
+    (128, 5, False),    # every tile against one densify of each block
+    (64, 2, True),      # 3 tiles, the diagonal masked in a group's second too
+    (0, 2, False),      # the block derived: the power of two the bytes leave
+])
+def test_grouped_program_equals_the_ungrouped_one_to_the_bit(
+        monkeypatch, block, group, exclude_self):
+    """Several item tiles counted against one densified block give the
+    counts one tile at a time gives, so scores and indices are equal to
+    the bit: a ragged last group, a ragged last user block, `exclude_self`
+    and an explicit `userBlock`, the budget alone deciding the group."""
+    from predictionio_tpu.obs.spans import SpanCollector
+    from predictionio_tpu.ops import cco
+
+    monkeypatch.setenv("PIO_CCO_SPARSE", "0")
+    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
+    monkeypatch.setattr(cco, "_BLOCK_ROWS", 256)    # a chip's 4,096, in small
+    n_users, n_ip, n_it, tile, top_k = GROUPED.values()
+    rng = np.random.default_rng(340 + group)
+    pu, pi = rng.integers(0, n_users, 2500), rng.integers(0, n_ip, 2500)
+    if exclude_self:
+        n_it, (au, ai) = n_ip, (pu, pi)
+    else:
+        au, ai = rng.integers(0, n_users, 4000), rng.integers(0, n_it, 4000)
+    n_tiles = -(-n_it // tile)
+
+    def run(budget, user_block):
+        monkeypatch.setattr(cco, "_TILED_P_BYTES", budget)
+        with SpanCollector().activate() as collector:
+            out = cco.cco_indicators_coo(
+                pu, pi, au, ai, n_users, n_ip, n_it, top_k=top_k,
+                user_block=user_block, item_tile=tile,
+                exclude_self=exclude_self)
+        (attrs,) = [s["attrs"] for s in collector.spans()
+                    if s["name"] == "dispatch"]
+        return out, attrs
+
+    k = block or 256
+    (s1, i1), one = run(0, k)
+    assert (one["user_block"], one["tile_group"]) == (k, 1)
+    assert one["block_steps"] == n_tiles * -(-n_users // k)
+    (sg, ig), grouped = run(_plan_bytes(k, group, n_ip, tile, 2), block)
+    assert grouped["program"] == "_cco_chunked_all_tiles"
+    assert (grouped["user_block"], grouped["tile_group"]) == (k, group)
+    assert grouped["plan_bytes"] == _plan_bytes(k, group, n_ip, tile, 2)
+    assert grouped["tiles"] == n_tiles
+    assert grouped["block_steps"] == -(-n_tiles // group) * -(-n_users // k)
+    assert np.array_equal(s1, sg) and np.array_equal(i1, ig)
+    assert (i1 >= 0).sum() > n_ip       # and there is something to compare
+
+
 # -- the engine, trained through the chunked program, against the reference ---
 
-SHAPE = dict(n_users=899, n_items=700, n_buy=5000, n_view=9000,
+SHAPE = dict(n_users=1923, n_items=700, n_buy=5000, n_view=9000,
              zipf_buy=1.3, zipf_view=1.2)
 BLOCK, TILE, TOP_K = 128, 256, 10
 
@@ -104,11 +237,12 @@ def _variant(app):
 @pytest.mark.parametrize("seed", [3, 4000000007])
 def test_engine_through_the_chunked_program_agrees_with_the_reference(
         mem_storage, monkeypatch, seed):
-    """899 users in blocks of 128 (the last holds 3), 700 items in tiles of
-    256 (the last holds 188), buy and view: `Engine.train` from the engine
-    variant, the rule sending both event types to
-    `_cco_chunked_all_tiles`, and every row of both persisted tables held
-    against `benchmark/reference/cco.py` by the configuration's limits."""
+    """1,923 users in blocks of 128 (the last holds 3), 700 items in tiles
+    of 256 (the last holds 188) counted two to a group (the last group
+    holds one), buy and view: `Engine.train` from the engine variant, the
+    rule sending both event types to `_cco_chunked_all_tiles`, and every
+    row of both persisted tables held against `benchmark/reference/cco.py`
+    by the configuration's limits."""
     from predictionio_tpu.obs.spans import SpanCollector
     from predictionio_tpu.ops import cco
     from predictionio_tpu.storage import App
@@ -116,13 +250,16 @@ def test_engine_through_the_chunked_program_agrees_with_the_reference(
 
     monkeypatch.setenv("PIO_CCO_SPARSE", "0")
     monkeypatch.setenv("PIO_PALLAS", "interpret")
-    # a chip a hundred-thousandth the size: the rule, not a switch, decides
-    monkeypatch.setattr(cco, "_TILED_P_BYTES", cco._TILED_P_BYTES // 100_000)
+    # a chip three-thousandth the size: the rule, not a switch, decides
+    monkeypatch.setattr(cco, "_TILED_P_BYTES", cco._TILED_P_BYTES // 3_000)
     monkeypatch.setattr(cco, "_DENSE_C_BYTES", cco._DENSE_C_BYTES // 100_000)
     users, items = SHAPE["n_users"], SHAPE["n_items"]
     assert cco._plan(users, items, items, None, TILE) == ("chunked",)
     # a shop that still fits
     assert cco._plan(64, 60, 60, None, 32) == ("resident",)
+    # two tiles beside the engine's block of 128 fit this chip, three do not
+    plan = cco._block_plan(users, items, TILE, 3, block=BLOCK)
+    assert plan[:2] == (BLOCK, 2) and plan[2] <= cco._TILED_P_BYTES
 
     data = _bench_module("data", "commerce").generate(SHAPE, seed)
     app_id = mem_storage.apps.insert(App(0, "chunked"))
@@ -141,8 +278,11 @@ def test_engine_through_the_chunked_program_agrees_with_the_reference(
     assert [d["program"] for d in dispatched] == ["_cco_chunked_all_tiles"] * 2
     n_blocks, n_tiles = -(-users // BLOCK), -(-items // TILE)
     for d in dispatched:
-        assert d["tiles"] == n_tiles and d["topk"] == "pallas"
-        assert d["block_steps"] == n_tiles * n_blocks == 24
+        assert d["tiles"] == n_tiles == 3 and d["topk"] == "pallas"
+        assert (d["user_block"], d["tile_group"]) == (BLOCK, 2)
+        assert d["plan_bytes"] == plan[2]
+        # densify + count steps: groups x blocks, a whole group and one of 1
+        assert d["block_steps"] == 2 * n_blocks == 32
     laid = [s["attrs"] for s in spans if s["name"] == "layout"
             and "user_blocks" in s.get("attrs", {})]
     assert len(laid) == 2

@@ -43,3 +43,37 @@ def test_tile_topk_compiles_for_a_v5e(one_chip, rows, width, b):
     assert "tpu_custom_call" in compiled.as_text()
     assert [o.shape for o in jax.eval_shape(
         lambda s: pk._tile_topk_padded(s, b, True), scores)] == [(rows, b)] * 2
+
+
+def test_blocked_cco_program_fits_a_v5e(one_chip, monkeypatch):
+    """The user-blocked program at ur-ecom-100k-u131k's shape, under the
+    step `_block_plan` derives for it: the TPU compiler's own memory plan
+    stays inside the 14 GB the cell is held to.  (It plans more than the
+    derivation counts and than the chip then holds: PERF.md section 4.
+    A Python loop over a group's tiles in place of the `fori_loop` made
+    it 18 GB.)"""
+    from predictionio_tpu.ops import cco, pallas_kernels as pk
+
+    monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    users, items, tile, tiles = 131072, 100000, 4096, 25
+    block, group, plan_bytes = cco._block_plan(users, items, tile, tiles)
+    assert plan_bytes <= cco._TILED_P_BYTES
+    n_blocks = users // block
+
+    def slots(events):      # a block's share of the events, and some
+        return jax.ShapeDtypeStruct((n_blocks, events // n_blocks // 8 * 9),
+                                    jnp.int32, sharding=one_chip)
+
+    count = jax.ShapeDtypeStruct((n_blocks,), jnp.int32, sharding=one_chip)
+    buy, view = slots(1_600_000), slots(3_200_000)
+    compiled = cco._cco_chunked_all_tiles.lower(
+        buy, buy, count, view, view, count, float(users), n_tiles=tiles,
+        group=group, block=block, n_items_p=items, tile=tile, top_k=50,
+        llr_threshold=0.0, pallas="compiled", exclude_self=False,
+        topk="pallas").compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes)
+    assert held < 14e9, held
+    assert "tpu_custom_call" in compiled.as_text()
