@@ -47,6 +47,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs.spans import span
+from predictionio_tpu.utils.device import noted, stage
 
 
 @dataclasses.dataclass
@@ -158,7 +159,7 @@ def _packed_rows(yw: jnp.ndarray, y: jnp.ndarray, rhs_w: jnp.ndarray) -> jnp.nda
     the second matmul's output, where W lane slices concatenated cost a pass
     over [E, 128] each (my chip runs, PR 27: 2.9 against ~32 ms a half-step)."""
     sel_l, sel_r = _pack_selectors(y.shape[-1])
-    with jax.named_scope("als.rhs"):
+    with stage("als.rhs"):
         left = jnp.concatenate([yw, rhs_w[:, None]], axis=1)
     hi = jax.lax.Precision.HIGHEST
     return jnp.dot(left, sel_l, precision=hi) * jnp.dot(y, sel_r, precision=hi)
@@ -176,8 +177,9 @@ def _unpack_rows(red: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def _row_ridge(local_idx: jnp.ndarray, mask: jnp.ndarray, rows: int, reg) -> jnp.ndarray:
     """λ·n_e ridge (MLlib's ALS-WR weighting) + ε guard for empty rows.  It
     depends on the layout alone: computed once, before the sweeps."""
-    n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
-    return reg * jnp.maximum(n_e, 1.0) + 1e-6
+    with stage("als.normal_eq"):
+        n_e = jax.ops.segment_sum(mask, local_idx, num_segments=rows)
+        return reg * jnp.maximum(n_e, 1.0) + 1e-6
 
 
 def _solve_rows(z, local_idx, lam, k: int, gram=None) -> jnp.ndarray:
@@ -187,13 +189,13 @@ def _solve_rows(z, local_idx, lam, k: int, gram=None) -> jnp.ndarray:
     ``indices_are_sorted``; a blockwise one-hot matmul over rows sorted by
     segment gained 5% of the program for a device sort that compiles for
     15–27 s, and was not kept (my chip runs, PR 27)."""
-    with jax.named_scope("als.normal_eq"):
+    with stage("als.normal_eq"):
         A, b = _unpack_rows(
             jax.ops.segment_sum(z, local_idx, num_segments=lam.shape[0]), k)
         if gram is not None:
             A = A + gram
         A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
-    with jax.named_scope("als.solve"):
+    with stage("als.solve"):
         cho = jax.scipy.linalg.cho_factor(A)
         return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
 
@@ -207,9 +209,9 @@ def _half_step(
     lam: jnp.ndarray,          # [rows] ridge of each row (_row_ridge)
 ) -> jnp.ndarray:
     """Solve per-row normal equations (YtY + λ n_e I) x = Ytr on one shard."""
-    with jax.named_scope("als.gather"):
+    with stage("als.gather"):
         y = other_full[other_flat] * mask[:, None]            # [E, K]
-    with jax.named_scope("als.normal_eq"):
+    with stage("als.normal_eq"):
         z = _packed_rows(y, y, rating)
     return _solve_rows(z, local_idx, lam, y.shape[-1])
 
@@ -231,9 +233,9 @@ def _half_step_implicit(
     is the precomputed ``gram`` (one [N,K]×[K,N] MXU matmul per sweep),
     and only the observed events contribute the (c−1)-weighted correction.
     """
-    with jax.named_scope("als.gather"):
+    with stage("als.gather"):
         y = other_full[other_flat] * mask[:, None]            # [E, K]
-    with jax.named_scope("als.normal_eq"):
+    with stage("als.normal_eq"):
         c1 = alpha * rating * mask                            # c − 1, 0 on padding
         z = _packed_rows(c1[:, None] * y, y, 1.0 + c1)
     return _solve_rows(z, local_idx, lam, y.shape[-1], gram)
@@ -250,7 +252,8 @@ def _sweeps(x0, y0, iters, reg, alpha, u_side, i_side, implicit: bool, gather_fu
 
     def half(other_full, side, lam):
         if implicit:
-            gram = other_full.T @ other_full
+            with stage("als.normal_eq"):
+                gram = other_full.T @ other_full
             return _half_step_implicit(other_full, gram, *side, lam, alpha)
         return _half_step(other_full, *side, lam)
 
@@ -266,8 +269,10 @@ def _sweeps(x0, y0, iters, reg, alpha, u_side, i_side, implicit: bool, gather_fu
 def _as_one_shard(local, other_flat, rating, mask, rows: int):
     """A [dp, E] layout as ONE shard of dp·rows rows: shard s's local row r
     is row s·rows + r, its flat index in the factor blocks."""
-    offset = jnp.arange(local.shape[0], dtype=local.dtype)[:, None] * rows
-    return tuple(a.reshape(-1) for a in (local + offset, other_flat, rating, mask))
+    with stage("als.gather"):
+        offset = jnp.arange(local.shape[0], dtype=local.dtype)[:, None] * rows
+        return tuple(a.reshape(-1)
+                     for a in (local + offset, other_flat, rating, mask))
 
 
 @functools.partial(jax.jit, static_argnames=("implicit",))
@@ -317,7 +322,8 @@ def _als_sharded_fn(mesh: Mesh, implicit: bool):
 
 
 def _als_run_sharded(mesh, implicit, x0, y0, iters, reg, alpha, *args):
-    return _als_sharded_fn(mesh, implicit)(x0, y0, iters, reg, alpha, *args)
+    return noted(_als_sharded_fn(mesh, implicit), x0, y0, iters, reg, alpha,
+                 *args)
 
 
 def als_train(
@@ -361,6 +367,11 @@ def als_train(
 
 
 def _als_init(data: ALSData, k: int, seed: int):
+    # A dozen eager operations, not one jitted program: under one jit XLA
+    # folds the two constant factors of the normal draw into one, and the
+    # factors start a last bit away from where they started before (so the
+    # trained model moves, within its limits: chip run, PR 35).  They run in
+    # microseconds and are the unstaged remainder of a trace.
     key = jax.random.PRNGKey(seed)
     y0 = jax.random.normal(key, (data.dp, data.item_rows, k), jnp.float32) * 0.1
     # zero the padding rows (shard s, local r holds item r*dp + s): real rows
@@ -393,7 +404,8 @@ def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=Non
                  events=int(data.u_mask.shape[1]))
     if mesh is None:
         with span("dispatch", program="_als_run_single", **shape):
-            return _als_run_single(
+            return noted(
+                _als_run_single,
                 x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
                 jnp.float32(alpha),
                 *args, implicit=implicit,

@@ -50,7 +50,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import os as _os
-from functools import partial
+from functools import lru_cache, partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -59,6 +59,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs.spans import span
+from predictionio_tpu.utils.device import noted, stage
 
 
 # ---------------------------------------------------------------------------
@@ -265,6 +266,9 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
     chip's share of the primary's items (the sharded resident program);
     only ``exclude_self`` reads it.
     """
+    # outside any stage: XLA fuses the mask into what produced the scores,
+    # and a fusion that no stage names is the stage of most of what it
+    # does (the scores: utils.device.parse_stage_map's second rule)
     tile_idx = tile_start + jnp.arange(tile, dtype=jnp.int32)[None, :]
     if exclude_self:
         row_ids = jnp.arange(n_items_p, dtype=jnp.int32)[:, None]
@@ -276,15 +280,15 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
         from predictionio_tpu.ops.topk import merge_desc
 
         b = best_scores.shape[1]
-        with jax.named_scope("cco.topk_merge"):
+        with stage("cco.topk_merge"):
             ts, ti = tile_topk_desc(scores, b)
             return merge_desc(best_scores, best_idx, ts, tile_start + ti)
-    with jax.named_scope("cco.topk_merge"):
+    with stage("cco.topk_merge"):
         all_scores = jnp.concatenate([best_scores, scores], axis=1)
         all_idx = jnp.concatenate(
             [best_idx, jnp.broadcast_to(tile_idx, scores.shape)], axis=1)
         new_scores, pos = jax.lax.top_k(all_scores, top_k)
-    with jax.named_scope("cco.topk_gather"):
+    with stage("cco.topk_gather"):
         return new_scores, jnp.take_along_axis(all_idx, pos, axis=1)
 
 
@@ -429,13 +433,27 @@ def _tiling(n_items_t: int, item_tile: int) -> Tuple[int, int]:
 _TILED_P_BYTES = 12 * 10**9
 
 
-@partial(jax.jit, static_argnames=("n_rows", "n_cols"))
-def _densify_global(gu, gi, valid, n_rows: int, n_cols: int):
-    """One scatter-max of global COO into a resident 0/1 matrix."""
+def _densify_coo(gu, gi, valid, n_rows: int, n_cols: int):
+    """One scatter-max of global COO into a 0/1 matrix [n_rows, n_cols]."""
     dtype = _mm_in_dtype()
     return jnp.zeros((n_rows, n_cols), dtype).at[
         jnp.where(valid, gu, 0), jnp.where(valid, gi, 0)
     ].max(valid.astype(dtype))
+
+
+@partial(jax.jit, static_argnames=("n_rows", "n_cols"))
+def _densify_global(gu, gi, valid, n_rows: int, n_cols: int):
+    """The primary's one-off densify into a resident 0/1 matrix."""
+    with stage("cco.densify_primary"):
+        return _densify_coo(gu, gi, valid, n_rows, n_cols)
+
+
+@jax.jit
+def _primary_counts(Pm):
+    """The resident primary's items' user counts (``_col_count``), one
+    program where two eager operations ran."""
+    with stage("cco.densify_primary"):
+        return _col_count(Pm)
 
 
 def _cco_tile_body_resident(
@@ -458,21 +476,21 @@ def _cco_tile_body_resident(
     integer below 2²⁴ in float32, and so is their sum (``_plan``)."""
     n_rows = P.shape[0]
     row_offset = None
-    with jax.named_scope("cco.densify_tile"):
+    with stage("cco.densify_tile"):
         a_local = a_gi - tile_start
         in_tile = a_valid & (a_local >= 0) & (a_local < tile)
-        A_t = _densify_global(a_gu, jnp.where(in_tile, a_local, 0), in_tile,
-                              n_rows, tile)
-    with jax.named_scope("cco.count_matmul"):
+        A_t = _densify_coo(a_gu, jnp.where(in_tile, a_local, 0), in_tile,
+                           n_rows, tile)
+    with stage("cco.count_matmul"):
         c = _count_matmul(P, A_t, mm).astype(jnp.float32)
         cct = _col_count(A_t).astype(jnp.float32)
     if axis_name is not None:
-        with jax.named_scope("cco.exchange"):
+        with stage("cco.exchange"):
             c = jax.lax.psum_scatter(c, axis_name, scatter_dimension=0,
                                      tiled=True)
             cct = jax.lax.psum(cct, axis_name)
         row_offset = jax.lax.axis_index(axis_name) * c.shape[0]
-    with jax.named_scope("cco.llr"):
+    with stage("cco.llr"):
         scores = _llr_mask_scores(c, rc.astype(jnp.float32), cct, n_total,
                                   llr_threshold, pallas)
     return _merge_topk(best_scores, best_idx, scores, tile_start, tile,
@@ -557,7 +575,10 @@ def _densify_sharded(lu, it, cnt, mesh: Mesh, n_rows: int, n_cols: int):
     def run(lu, it, cnt):
         Pm = _densify_global(lu[0], it[0], _valid_slots(cnt, lu.shape[1]),
                              n_rows, n_cols)
-        return Pm, jax.lax.psum_scatter(_col_count(Pm), "dp", tiled=True)
+        with stage("cco.densify_primary"):
+            counts = _col_count(Pm)
+        with stage("cco.exchange"):
+            return Pm, jax.lax.psum_scatter(counts, "dp", tiled=True)
 
     return run(lu, it, cnt)
 
@@ -626,14 +647,16 @@ def _cco_resident(
             p_gu, p_gi = jnp.asarray(pu), jnp.asarray(pi)
             p_valid = jnp.ones(len(pu), bool)
         with span("dispatch", program="_densify_global"):
-            Pm = _densify_global(p_gu, p_gi, p_valid, n_rows, n_items_p)
-            rc = _col_count(Pm)
+            Pm = noted(_densify_global, p_gu, p_gi, p_valid, n_rows,
+                       n_items_p)
+            rc = noted(_primary_counts, Pm)
         with span("h2d", bytes=au.nbytes + ai.nbytes):
             a_gu, a_gi = jnp.asarray(au), jnp.asarray(ai)
             a_valid = jnp.ones(len(au), bool)
         with span("dispatch", program="_cco_resident_all_tiles",
                   **_topk_attrs(topk, tile, top_k)):
-            best_scores, best_idx = _cco_resident_all_tiles(
+            best_scores, best_idx = noted(
+                _cco_resident_all_tiles,
                 Pm, rc, a_gu, a_gi, a_valid, float(n_users),
                 llr_threshold=float(llr_threshold), **static)
         return _finalize_topk(best_scores, best_idx, n_items_t, top_k)
@@ -644,8 +667,8 @@ def _cco_resident(
     by_user = NamedSharding(mesh, P("dp"))
     p = _stage_chunked(pu, pi, users_chip, dp, by_user, by_chip=True)
     with span("dispatch", program="_densify_sharded", dp=dp):
-        Pm, rc = _densify_sharded(p.local_u, p.item, p.count, mesh=mesh,
-                                  n_rows=_pad128(users_chip), n_cols=rows)
+        Pm, rc = noted(_densify_sharded, p.local_u, p.item, p.count,
+                       mesh=mesh, n_rows=_pad128(users_chip), n_cols=rows)
     # the primary against itself: the pairs are staged already
     a = p if au is pu and ai is pi else _stage_chunked(
         au, ai, users_chip, dp, by_user, by_chip=True)
@@ -655,7 +678,8 @@ def _cco_resident(
               # every float32 partial count tile but its own rows
               exchange_mb=n_tiles * (rows - rows // dp) * tile * 4 / 1e6,
               **_topk_attrs(topk, tile, top_k)):
-        best_scores, best_idx = _cco_sharded_all_tiles(
+        best_scores, best_idx = noted(
+            _cco_sharded_all_tiles,
             Pm, rc, a.local_u, a.item, a.count, float(n_users),
             llr_threshold=float(llr_threshold), mesh=mesh, **static)
     return _finalize_topk(best_scores, best_idx, n_items_t, top_k,
@@ -697,7 +721,7 @@ def _cooccurrence_group(
     def body(carry, xs):
         C, rc, cc = carry
         plu, pit, pcnt, alu, ait, acnt = xs
-        with jax.named_scope("cco.densify_block"):
+        with stage("cco.densify_block"):
             pvalid = jax.lax.iota(jnp.int32, e_p) < pcnt
             pb = _densify(plu, pit, pvalid, block, n_items_p, in_dtype)
             a_local = ait - start
@@ -705,7 +729,7 @@ def _cooccurrence_group(
                         & (a_local >= 0) & (a_local < width))
             ab = _densify(alu, jnp.where(in_group, a_local, 0), in_group,
                           block, width, in_dtype)
-        with jax.named_scope("cco.count_matmul"):
+        with stage("cco.count_matmul"):
             C = C + _count_matmul(pb, ab, mm)
             rc = jax.lax.cond(count_rows, lambda: rc + _col_count(pb),
                               lambda: rc)
@@ -747,13 +771,14 @@ def _cco_group_step(
         n_items_p, start, tile, group, axis_name,
     )
     if axis_name is not None:
-        c, rc, cc = jax.lax.psum((c, rc, cc), axis_name)
+        with stage("cco.exchange"):
+            c, rc, cc = jax.lax.psum((c, rc, cc), axis_name)
     rcf = rc.astype(jnp.float32)
 
     def one_tile(g, best):
         # one tile at a time: a loop, so that the compiler holds one
         # float32 tile and its scores, not the group's [AOT, PR 34]
-        with jax.named_scope("cco.llr"):
+        with stage("cco.llr"):
             c_t = jax.lax.dynamic_slice(c, (0, g * tile), (n_items_p, tile))
             cc_t = jax.lax.dynamic_slice(cc, (g * tile,), (tile,))
             scores = _llr_mask_scores(
@@ -851,16 +876,18 @@ def _cco_counts_dense(
     def body(carry, xs):
         C, rc, cc = carry
         plu, pit, pcnt, alu, ait, acnt = xs
-        pvalid = jax.lax.iota(jnp.int32, e_p) < pcnt
-        Pm = _densify(plu, pit, pvalid, chunk, n_items_p, in_dtype)
-        if self_pair:
-            Am = Pm
-        else:
-            avalid = jax.lax.iota(jnp.int32, e_a) < acnt
-            Am = _densify(alu, ait, avalid, chunk, it_pad, in_dtype)
-        C = C + _count_matmul(Pm, Am, mm)
-        rc = rc + _col_count(Pm)
-        cc = cc + _col_count(Am)
+        with stage("cco.densify_block"):
+            pvalid = jax.lax.iota(jnp.int32, e_p) < pcnt
+            Pm = _densify(plu, pit, pvalid, chunk, n_items_p, in_dtype)
+            if self_pair:
+                Am = Pm
+            else:
+                avalid = jax.lax.iota(jnp.int32, e_a) < acnt
+                Am = _densify(alu, ait, avalid, chunk, it_pad, in_dtype)
+        with stage("cco.count_matmul"):
+            C = C + _count_matmul(Pm, Am, mm)
+            rc = rc + _col_count(Pm)
+            cc = cc + _col_count(Am)
         return (C, rc, cc), None
 
     init = (
@@ -871,8 +898,31 @@ def _cco_counts_dense(
     (C, rc, cc), _ = jax.lax.scan(body, _varying(init, axis_name),
                                   (p_lu, p_it, p_cnt, a_lu, a_it, a_cnt))
     if axis_name is not None:
-        C, rc, cc = jax.lax.psum((C, rc, cc), axis_name)
+        with stage("cco.exchange"):
+            C, rc, cc = jax.lax.psum((C, rc, cc), axis_name)
     return C, rc, cc
+
+
+@lru_cache(maxsize=16)
+def _counts_dense_sharded_fn(mesh: Mesh, chunk: int, n_items_p: int,
+                             it_pad: int, self_pair: bool, mm: str):
+    """``_cco_counts_dense`` with the user chunks sharded over ``dp``, built
+    once for a (mesh, shape) and jitted: a wrapper rebuilt a dispatch
+    would re-trace the sharded program every call, and one that closed
+    over its runner would keep the runner's staged arrays alive in
+    ``utils.device``'s table of noted programs."""
+    spec, rep = P("dp"), P()
+
+    @jax.jit
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,) * 6,
+             out_specs=(rep, rep, rep))
+    def counts_sharded(plu, pit, pcnt, alu, ait, acnt):
+        return _cco_counts_dense(
+            plu, pit, pcnt, alu, ait, acnt,
+            chunk=chunk, n_items_p=n_items_p, it_pad=it_pad,
+            axis_name="dp", self_pair=self_pair, mm=mm)
+
+    return counts_sharded
 
 
 @partial(jax.jit, static_argnames=("top_k", "exclude_self", "pallas"))
@@ -884,16 +934,18 @@ def _llr_topk_dense(
     every backend.  A row here is the whole target catalogue; the tiled
     merge's ``tile_topk_desc`` has been measured at the tile's width
     alone."""
-    scores = _llr_mask_scores(
-        C.astype(jnp.float32), rc.astype(jnp.float32), cc.astype(jnp.float32),
-        n_total, llr_threshold, pallas)
-    if exclude_self:
-        n_p, n_t = scores.shape
-        eye = jnp.arange(n_p, dtype=jnp.int32)[:, None] == jnp.arange(
-            n_t, dtype=jnp.int32)[None, :]
-        scores = jnp.where(eye, -jnp.inf, scores)
-    best_scores, best_idx = jax.lax.top_k(scores, top_k)
-    return best_scores, best_idx.astype(jnp.int32)
+    with stage("cco.llr"):
+        scores = _llr_mask_scores(
+            C.astype(jnp.float32), rc.astype(jnp.float32),
+            cc.astype(jnp.float32), n_total, llr_threshold, pallas)
+    with stage("cco.topk_merge"):
+        if exclude_self:
+            n_p, n_t = scores.shape
+            eye = jnp.arange(n_p, dtype=jnp.int32)[:, None] == jnp.arange(
+                n_t, dtype=jnp.int32)[None, :]
+            scores = jnp.where(eye, -jnp.inf, scores)
+        best_scores, best_idx = jax.lax.top_k(scores, top_k)
+        return best_scores, best_idx.astype(jnp.int32)
 
 
 @dataclasses.dataclass
@@ -1345,7 +1397,8 @@ class _SparseHostRunner:
             C_d, rc_d, cc_d = (jnp.asarray(C), jnp.asarray(self.p.col_counts),
                                jnp.asarray(a.col_counts))
         with span("dispatch", program="_llr_topk_dense"):
-            s, i = _llr_topk_dense(
+            s, i = noted(
+                _llr_topk_dense,
                 C_d, rc_d, cc_d,
                 float(self.n_users), float(llr_threshold),
                 top_k=min(top_k, C.shape[1]),
@@ -1374,39 +1427,23 @@ class _DenseRunner:
         self.n_chunks = math.ceil(self.n_chunks / dp) * dp
         self.sharding = (
             NamedSharding(mesh, P("dp")) if mesh is not None else None)
-        self._sharded_counts: Dict[tuple, object] = {}
         self.p = _stage_chunked(p_user, p_item,
                                 self.chunk, self.n_chunks, self.sharding)
 
     def _counts(self, a: _StagedCOO, it_pad: int, self_pair: bool):
         mm = _matmul_dtype()
         if self.mesh is None:
-            return _cco_counts_dense(
+            return noted(
+                _cco_counts_dense,
                 self.p.local_u, self.p.item, self.p.count,
                 a.local_u, a.item, a.count,
                 chunk=self.chunk, n_items_p=self.n_items_p, it_pad=it_pad,
                 self_pair=self_pair, mm=mm,
             )
-        # one shard_map wrapper per (it_pad, self_pair, mm): rebuilding the
-        # wrapper per dispatch would re-trace the sharded program every call
-        key = (it_pad, self_pair, mm)
-        counts_sharded = self._sharded_counts.get(key)
-        if counts_sharded is None:
-            spec, rep = P("dp"), P()
-
-            @partial(jax.shard_map, mesh=self.mesh, in_specs=(spec,) * 6,
-                     out_specs=(rep, rep, rep))
-            def counts_sharded(plu, pit, pcnt, alu, ait, acnt):
-                return _cco_counts_dense(
-                    plu, pit, pcnt, alu, ait, acnt,
-                    chunk=self.chunk, n_items_p=self.n_items_p, it_pad=it_pad,
-                    axis_name="dp", self_pair=self_pair, mm=mm,
-                )
-
-            self._sharded_counts[key] = counts_sharded
-
-        return counts_sharded(self.p.local_u, self.p.item, self.p.count,
-                              a.local_u, a.item, a.count)
+        counts_sharded = _counts_dense_sharded_fn(
+            self.mesh, self.chunk, self.n_items_p, it_pad, self_pair, mm)
+        return noted(counts_sharded, self.p.local_u, self.p.item,
+                     self.p.count, a.local_u, a.item, a.count)
 
     def dispatch(self, a_user, a_item, n_items_t: int, top_k: int,
                  llr_threshold: float, exclude_self: bool,
@@ -1425,7 +1462,8 @@ class _DenseRunner:
             C, rc, cc = self._counts(a, it_pad, self_pair)
         k = min(top_k, it_pad)
         with span("dispatch", program="_llr_topk_dense"):
-            s, i = _llr_topk_dense(
+            s, i = noted(
+                _llr_topk_dense,
                 C, rc, cc, float(self.n_total_users), float(llr_threshold),
                 top_k=k, exclude_self=bool(exclude_self),
                 pallas=pallas_mode(),
@@ -1717,6 +1755,27 @@ def cco_indicators_coo(
         user_block=user_block, item_tile=item_tile)["a"]
 
 
+@lru_cache(maxsize=8)
+def _group_step_sharded_fn(mesh: Mesh, n_total: float, static: tuple):
+    """The chunked strategy's step on a mesh, built once for a (mesh,
+    population, static arguments) and jitted, so that a job neither
+    re-traces it nor hands ``stage_maps`` a new function a call: groups
+    of one tile, each summing the primary's counts anew."""
+    spec, rep = P("dp"), P()
+    static = dict(static)
+
+    @jax.jit
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,) * 6 + (rep,) * 3,
+             out_specs=(rep, rep))
+    def tile_step_sharded(plu, pit, pcnt, alu, ait, acnt, bs, bi, ts):
+        return _cco_group_step(
+            plu, pit, pcnt, alu, ait, acnt, n_total,
+            bs, bi, jnp.zeros((static["n_items_p"],), jnp.int32), True, ts,
+            group=1, axis_name="dp", **static)[:2]
+
+    return tile_step_sharded
+
+
 def _cco_chunked(
     primary: BlockedInteractions,
     other: BlockedInteractions,
@@ -1763,7 +1822,8 @@ def _cco_chunked(
                   tile_group=group, plan_bytes=plan_bytes,
                   block_steps=math.ceil(n_tiles / group) * primary.n_blocks,
                   **_topk_attrs(topk, tile, top_k)):
-            best_scores, best_idx = _cco_chunked_all_tiles(
+            best_scores, best_idx = noted(
+                _cco_chunked_all_tiles,
                 *args, float(n_total_users), n_tiles=n_tiles, group=group,
                 **static)
     else:
@@ -1778,31 +1838,20 @@ def _cco_chunked(
 
         from predictionio_tpu.parallel.sharding import stage_global
 
-        spec = P("dp")
-        rep = P()
-        shard = NamedSharding(mesh, spec)
+        shard = NamedSharding(mesh, P("dp"))
         with span("h2d", bytes=sum(a.nbytes for a in host_args)):
             args = tuple(stage_global(pad(np.asarray(a)), shard)
                          for a in host_args)
 
-        # groups of one tile, each summing the primary's counts anew
-        @partial(
-            jax.shard_map, mesh=mesh,
-            in_specs=(spec,) * 6 + (rep,) * 3,
-            out_specs=(rep, rep),
-        )
-        def tile_step_sharded(plu, pit, pcnt, alu, ait, acnt, bs, bi, ts):
-            return _cco_group_step(
-                plu, pit, pcnt, alu, ait, acnt, float(n_total_users),
-                bs, bi, jnp.zeros((n_items_p,), jnp.int32), True, ts,
-                group=1, axis_name="dp", **static)[:2]
-
+        tile_step_sharded = _group_step_sharded_fn(
+            mesh, float(n_total_users), tuple(sorted(static.items())))
         carry_k = _carry_width(top_k, topk)
         best_scores = jnp.full((n_items_p, carry_k), -jnp.inf, jnp.float32)
         best_idx = jnp.zeros((n_items_p, carry_k), jnp.int32)
         with span("dispatch", program="_cco_group_step"):
             for t in range(n_tiles):
-                best_scores, best_idx = tile_step_sharded(
+                best_scores, best_idx = noted(
+                    tile_step_sharded,
                     *args, best_scores, best_idx, jnp.int32(t * tile),
                 )
 
@@ -1901,16 +1950,16 @@ def _basket_rules_tiled(
     def tile_step(bs, bi, tile_start):
         def body(c_acc, xs):
             blu, bit, bcnt = xs
-            with jax.named_scope("basket.densify"):
+            with stage("basket.densify"):
                 valid = jax.lax.iota(jnp.int32, slots) < bcnt
                 B = _densify(blu, bit, valid, chunk, width, in_dtype)
                 Bt = jax.lax.dynamic_slice(B, (0, tile_start), (chunk, tile))
-            with jax.named_scope("basket.count_matmul"):
+            with stage("basket.count_matmul"):
                 return c_acc + _count_matmul(B, Bt, mm), None
 
         c, _ = jax.lax.scan(body, jnp.zeros((width, tile), jnp.int32),
                             (lu, it, cnt))
-        with jax.named_scope("basket.score"):
+        with stage("basket.score"):
             ci_col = jax.lax.dynamic_slice(ci, (tile_start,), (tile,))
             scores = _basket_scores(
                 c.astype(jnp.float32), ci[:, None], ci_col[None, :],
@@ -1978,7 +2027,8 @@ def basket_rules(
     with span("dispatch", program="_basket_rules_tiled",
               tiles=n_tiles, chunks=n_chunks, steps=n_tiles * n_chunks,
               **_topk_attrs(topk, tile, k)):
-        best_scores, best_idx = _basket_rules_tiled(
+        best_scores, best_idx = noted(
+            _basket_rules_tiled,
             lu, it, cnt, jnp.float32(max(n_kept, 1)), ci_dev,
             chunk=chunk, n_tiles=n_tiles, tile=tile, top_k=k,
             min_support=jnp.float32(min_support),

@@ -10,7 +10,9 @@ the request's trace when one is active, and the profiler's trace).
 from __future__ import annotations
 
 import contextlib
+import json
 import logging
+import os
 import time
 from typing import Iterator, Optional
 
@@ -22,8 +24,17 @@ def profile_to(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
     """Capture a jax.profiler trace into log_dir (view with xprof/tensorboard).
 
     ``host_tracer_level``: 0 = host tracing off, 1 = critical events,
-    2 = info, 3 = verbose."""
+    2 = info, 3 = verbose.
+
+    When the trace stops, ``<log_dir>/stages.json`` is written beside it:
+    ``utils.device.stage_maps()`` of the programs this process dispatched,
+    which says of each operation the trace shows by the compiler's name
+    (``fusion.83``) which stage of which program it is
+    (``cco.count_matmul`` of ``jit__cco_chunked_all_tiles``).  Reading the
+    map compiles each program once more (docs/operations.md)."""
     import jax
+
+    from predictionio_tpu.utils.device import stage_maps
 
     options = jax.profiler.ProfileOptions()
     options.host_tracer_level = host_tracer_level
@@ -32,6 +43,8 @@ def profile_to(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
+        with open(os.path.join(log_dir, "stages.json"), "w") as f:
+            json.dump(stage_maps(), f, indent=1, sort_keys=True)
 
 
 @contextlib.contextmanager
