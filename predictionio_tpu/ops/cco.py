@@ -1589,11 +1589,10 @@ def _block_plan(n_rows: int, n_items_p: int, tile: int, n_tiles: int,
     it (``f32_tiles`` × I_p × tile × 4), and the densified block [K, I_p]
     with the other type's slab [K, G × tile] in the count matmul's input
     type three times over (the zero fill, the flat scatter's result, and
-    its copy re-laid as a matrix: what ``_basket_plan`` counts, and [AOT,
-    PR 31] bore out).  K and G compete for the same bytes: every densified
-    byte is paid once a block and GROUP, so a job densifies the primary
-    ⌈tiles ÷ G⌉ times an event type, and K is the count matmul's
-    contraction.
+    its copy re-laid as a matrix [AOT, PR 31]).  K and G compete for the
+    same bytes: every densified byte is paid once a block and GROUP, so a
+    job densifies the primary ⌈tiles ÷ G⌉ times an event type, and K is
+    the count matmul's contraction.
 
     The rule: G is the most tiles that leave a block of ``_BLOCK_ROWS``
     (or of all the rows, padded to 128, where they are fewer), at least
@@ -1610,10 +1609,14 @@ def _block_plan(n_rows: int, n_items_p: int, tile: int, n_tiles: int,
     the densified block inside the four, 13.2 GB [AOT, PR 34]; the chip's
     peak read (G + 2) tiles, 9.99 GB (chip run, PR 34).
 
-    The basket program is this plan with no slab of its own (the tile's
-    is a slice of the block) and one float32 tile: ``own_slab=False,
-    f32_tiles=1`` at G = 1 gives ``_basket_plan``'s chunk of 8,192 at its
-    cell's shape."""
+    The basket program is this plan with baskets for rows, no slab of its
+    own (the group's is a slice of the chunk) and one float32 tile (the
+    convert fuses into the scores): ``own_slab=False, f32_tiles=1``.  At
+    65,536 × 102,400, tile 4,096, bf16: K 2,048, G 5 (11.32 GB; six tiles
+    would leave 414 rows), a chunk densified 5 times a job, not 25.  The
+    TPU compiler holds the group and one float32 tile there, the chunk's
+    copies in the tile's place: 10.07 GB, as the chip then read; the plan
+    it reports reads a tile more, 11.76 GB [AOT, chip run, PR 36]."""
     in_bytes = 1 if _matmul_dtype() == "int8" else 2
     cap = min(_pad128(n_rows), 1 << 23)
 
@@ -1885,32 +1888,6 @@ def session_baskets(user: np.ndarray, item: np.ndarray, time_us: np.ndarray,
     return basket, np.asarray(item, np.int32)[order], int(basket[-1]) + 1
 
 
-def _basket_plan(n_baskets: int, n_items: int,
-                 item_tile: int) -> Tuple[int, int, int, int]:
-    """``(tile, n_tiles, chunk, n_chunks)`` of the basket program, by
-    ``_plan``'s accounting against the same ``_TILED_P_BYTES``: what the
-    compiler holds for ``_basket_rules_tiled`` is, per tile, the carried
-    int32 count tile and the float32 scores made from it (2 × I × tile ×
-    4), and the densified chunk of baskets [chunk, I] in the count
-    matmul's input type three times over (the zero fill, the flat
-    scatter's result, and its copy re-laid as a matrix).  The tile is UR's
-    (``item_tile`` against the catalogue); the chunk is the largest power
-    of two of baskets that leaves, since the chunk is the count matmul's
-    contraction (13,312 ran the matmul 14% slower than 8,192 on a v5e:
-    chip run, PR 31), or all the baskets where they are fewer.  At 65,536
-    × 100,000, tile 4,096, bf16: 8 chunks of 8,192 × 25 tiles, 3 × 1.68 +
-    2 × 1.68 = 8.39 GB, which is the TPU compiler's own plan [AOT, PR 31]
-    (the chip's peak read 5.05 GB: chip run, PR 31)."""
-    tile = min(item_tile, max(n_items, 1))
-    n_tiles = math.ceil(max(n_items, 1) / tile)
-    width = n_tiles * tile
-    per_basket = 3 * width * (1 if _matmul_dtype() == "int8" else 2)
-    room = _TILED_P_BYTES - 2 * width * tile * 4
-    chunk = 1 << max(room // per_basket, 256).bit_length() - 1
-    chunk = min(chunk, max(math.ceil(n_baskets / 256) * 256, 256))
-    return tile, n_tiles, chunk, max(math.ceil(n_baskets / chunk), 1)
-
-
 def _basket_scores(c, ci_row, ci_col, n, min_support, min_confidence,
                    min_lift):
     """Per-cell rule scoring, one fused elementwise pass: lift =
@@ -1929,47 +1906,66 @@ def _basket_scores(c, ci_row, ci_col, n, min_support, min_confidence,
 
 
 @partial(jax.jit, static_argnames=(
-    "chunk", "n_tiles", "tile", "top_k", "topk", "mm"))
+    "chunk", "n_tiles", "group", "tile", "top_k", "topk", "mm"))
 def _basket_rules_tiled(
     lu, it, cnt, n_baskets, ci,
-    chunk: int, n_tiles: int, tile: int, top_k: int,
+    chunk: int, n_tiles: int, group: int, tile: int, top_k: int,
     min_support, min_confidence, min_lift, topk: str, mm: str,
 ):
-    """Every item tile of the basket rules in one compiled program
-    (_scan_tiles): per tile, C_tile [I, tile] accumulates over the basket
-    chunks on the MXU — each chunk densified from its own slots of the
-    chunk-grouped log (``lu``/``it``/``cnt``: block_interactions' layout),
-    the tile's slab a slice of it — then scores and merges into the
-    running top-k (_merge_topk).  I is the catalogue padded to whole
-    tiles; ``ci`` [I] float32 is the exact per-item basket count from the
-    host."""
+    """Every item tile of the basket rules in one compiled program: a scan
+    over the whole groups of ``group`` adjacent tiles, and the tiles left
+    over as one step of their own size (as ``_cco_chunked_all_tiles``).
+    A group's counts C [I, group·tile] accumulate over the basket chunks
+    on the MXU in one product — each chunk densified ONCE a group from its
+    own slots of the chunk-grouped log (``lu``/``it``/``cnt``:
+    block_interactions' layout), the group's slab a slice of it — then its
+    tiles go one at a time, in tile order, through the scores and the
+    running top-k (_merge_topk) as a lone tile does.  I is the catalogue
+    padded to whole tiles; ``ci`` [I] float32 is the exact per-item basket
+    count from the host."""
     width = n_tiles * tile
     slots = lu.shape[1]
     in_dtype = jnp.int8 if mm == "int8" else jnp.bfloat16
 
-    def tile_step(bs, bi, tile_start):
+    def group_step(best, start, size: int):
         def body(c_acc, xs):
             blu, bit, bcnt = xs
             with stage("basket.densify"):
                 valid = jax.lax.iota(jnp.int32, slots) < bcnt
                 B = _densify(blu, bit, valid, chunk, width, in_dtype)
-                Bt = jax.lax.dynamic_slice(B, (0, tile_start), (chunk, tile))
+                # the slab as an array of its own: fused into the matmul's
+                # operand, the slice ran it 15% slower (chip run, PR 36)
+                Bg = jax.lax.optimization_barrier(jax.lax.dynamic_slice(
+                    B, (0, start), (chunk, size * tile)))
             with stage("basket.count_matmul"):
-                return c_acc + _count_matmul(B, Bt, mm), None
+                return c_acc + _count_matmul(B, Bg, mm), None
 
-        c, _ = jax.lax.scan(body, jnp.zeros((width, tile), jnp.int32),
+        c, _ = jax.lax.scan(body, jnp.zeros((width, size * tile), jnp.int32),
                             (lu, it, cnt))
-        with stage("basket.score"):
-            ci_col = jax.lax.dynamic_slice(ci, (tile_start,), (tile,))
-            scores = _basket_scores(
-                c.astype(jnp.float32), ci[:, None], ci_col[None, :],
-                n_baskets, min_support, min_confidence, min_lift)
-        # exclude_self masks the diagonal inside the merge
-        return _merge_topk(bs, bi, scores, tile_start, tile, top_k, width,
-                           exclude_self=True, impl=topk)
 
-    return _scan_tiles(tile_step, width, n_tiles, tile, top_k,
-                       carry_k=_carry_width(top_k, topk))
+        def one_tile(g, best):
+            # one tile at a time: a loop, so that the compiler holds one
+            # float32 tile, not the group's [AOT, PR 34]
+            tile_start = start + g * tile
+            with stage("basket.score"):
+                c_t = jax.lax.dynamic_slice(c, (0, g * tile), (width, tile))
+                ci_col = jax.lax.dynamic_slice(ci, (tile_start,), (tile,))
+                scores = _basket_scores(
+                    c_t.astype(jnp.float32), ci[:, None], ci_col[None, :],
+                    n_baskets, min_support, min_confidence, min_lift)
+            # exclude_self masks the diagonal inside the merge
+            return _merge_topk(*best, scores, tile_start, tile, top_k, width,
+                               exclude_self=True, impl=topk)
+
+        return jax.lax.fori_loop(0, size, one_tile, best)
+
+    whole, rest = divmod(n_tiles, group)
+    best = _scan_tiles(          # the whole groups: steps group·tile wide
+        lambda bs, bi, start: group_step((bs, bi), start, group),
+        width, whole, group * tile, top_k, carry_k=_carry_width(top_k, topk))
+    if rest:
+        best = group_step(best, jnp.int32(whole * group * tile), rest)
+    return best
 
 
 def basket_rules(
@@ -1992,9 +1988,11 @@ def basket_rules(
     confidence / (c_j / N); a rule passes at support ≥ min_support,
     confidence ≥ min_confidence and lift ≥ min_lift, and each item keeps
     its ``top_k`` best by lift.  One device program at every size
-    (_basket_rules_tiled, tile and chunk from _basket_plan); confidence
-    is derived from the kept lifts (conf = lift·c_j / N), so no
-    confidence matrix exists anywhere.
+    (_basket_rules_tiled): the tile is UR's (``item_tile`` against the
+    catalogue), the step's shape — a chunk of K baskets and the G tiles
+    counted against one densify of it — is ``_block_plan``'s, from the
+    bytes ``_TILED_P_BYTES`` leaves.  Confidence is derived from the kept
+    lifts (conf = lift·c_j / N), so no confidence matrix exists anywhere.
     """
     if n_baskets >= (1 << 31):
         raise ValueError(
@@ -2012,8 +2010,11 @@ def basket_rules(
         gb = (np.cumsum(kept) - 1).astype(np.int32)[gb[pair_kept]]
         gi = gi[pair_kept]
         ci = np.bincount(gi, minlength=n_items)
-        tile, n_tiles, chunk, n_chunks = _basket_plan(n_kept, n_items,
-                                                      item_tile)
+        tile, n_tiles = _tiling(max(n_items, 1), item_tile)
+        chunk, group, plan_bytes = _block_plan(
+            n_kept, n_tiles * tile, tile, n_tiles, own_slab=False,
+            f32_tiles=1)
+        n_chunks = max(math.ceil(n_kept / chunk), 1)
         blocks = block_interactions(gb, gi, n_chunks * chunk, n_items,
                                     user_block=chunk)
         ci_pad = np.zeros(n_tiles * tile, np.float32)
@@ -2025,12 +2026,14 @@ def basket_rules(
         lu, it, cnt, ci_dev = (jnp.asarray(a) for a in host_args)
     topk = topk_impl()
     with span("dispatch", program="_basket_rules_tiled",
-              tiles=n_tiles, chunks=n_chunks, steps=n_tiles * n_chunks,
+              tiles=n_tiles, chunks=n_chunks, chunk=chunk, tile_group=group,
+              plan_bytes=plan_bytes,
+              steps=math.ceil(n_tiles / group) * n_chunks,
               **_topk_attrs(topk, tile, k)):
         best_scores, best_idx = noted(
             _basket_rules_tiled,
             lu, it, cnt, jnp.float32(max(n_kept, 1)), ci_dev,
-            chunk=chunk, n_tiles=n_tiles, tile=tile, top_k=k,
+            chunk=chunk, n_tiles=n_tiles, group=group, tile=tile, top_k=k,
             min_support=jnp.float32(min_support),
             min_confidence=jnp.float32(min_confidence),
             min_lift=jnp.float32(min_lift), topk=topk, mm=_matmul_dtype())

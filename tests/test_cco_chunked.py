@@ -148,16 +148,40 @@ def test_the_steps_shape_comes_from_the_bytes_the_plan_leaves(
                            tile, in_bytes) > budget
 
 
-def test_the_basket_plan_is_the_same_derivation_without_a_slab(monkeypatch):
-    """`_basket_plan` returns what it returned, and the chunk it derives is
-    `_block_plan`'s block for a step with no slab of its own and one
-    float32 tile, at the basket cell's shape."""
+CP = (65536, 25 * 4096, 4096, 25)         # baskets, padded items, tile, tiles
+
+
+@pytest.mark.parametrize("mm,shape,budget,f32_tiles,want", [
+    # the basket cell: five tiles against a chunk of 2,048 (11.32 GB; six
+    # would leave 414 rows), each chunk densified 5 times a job, not 25
+    ("bf16", CP, 12 * GB, 1, (2048, 5)),
+    # a step that held a float32 tile more would count one tile fewer
+    ("bf16", CP, 12 * GB, 2, (2048, 4)),
+    ("int8", CP, 12 * GB, 1, (4096, 5)),
+    # one tile a step is the program as it was: its chunk of 8,192
+    ("bf16", CP[:3] + (1,), 12 * GB, 1, (8192, 1)),
+    # a chip half the size: one tile, and the deepest chunk beside it
+    ("bf16", CP, 6 * GB, 1, (4096, 1)),
+    # a shop of few baskets is one chunk of all of them, padded to 128
+    ("bf16", (900,) + CP[1:], 12 * GB, 1, (1024, 5)),
+    ("bf16", (5, 3, 3, 1), 12 * GB, 1, (128, 1)),
+])
+def test_the_basket_plan_is_the_same_derivation_without_a_slab(
+        monkeypatch, mm, shape, budget, f32_tiles, want):
+    """The basket program's step is `_block_plan`'s with no slab of its own
+    (the group's is a slice of the chunk) and one float32 tile: G carried
+    int32 tiles, the float32 tile, the densified chunk three times."""
     from predictionio_tpu.ops import cco
 
-    monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
-    assert cco._basket_plan(65536, 100000, 4096) == (4096, 25, 8192, 8)
-    assert cco._block_plan(65536, 25 * 4096, 4096, 1, own_slab=False,
-                           f32_tiles=1)[:2] == (8192, 1)
+    monkeypatch.setenv("PIO_CCO_MM_DTYPE", mm)
+    monkeypatch.setattr(cco, "_TILED_P_BYTES", budget)
+    rows, width, tile, tiles = shape
+    k, g, plan_bytes = cco._block_plan(rows, width, tile, tiles,
+                                       own_slab=False, f32_tiles=f32_tiles)
+    assert (k, g) == want
+    in_bytes = 1 if mm == "int8" else 2
+    assert plan_bytes == ((g + f32_tiles) * width * tile * 4
+                          + 3 * k * width * in_bytes) <= budget
 
 
 GROUPED = dict(n_users=1100, n_ip=90, n_it=150, tile=32, top_k=7)   # 5 tiles

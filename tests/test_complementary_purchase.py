@@ -127,36 +127,104 @@ def test_model_roundtrip(cp_app):
             == engine.predictor(ep, restored)(q).to_json())
 
 
-def _small_plan(monkeypatch, chunk: int):
-    """Basket chunks of ``chunk`` rows: the plan's budget shrunk to what two
-    count tiles and three such chunks take (see _basket_plan)."""
-    real = cco_ops._basket_plan
+def _basket_shape(n_baskets: int, n_items: int, item_tile: int):
+    """``(tile, tiles, chunk, chunks, group)`` as `basket_rules` derives
+    them: UR's tiling, and `_block_plan` for a step with no slab of its
+    own and one float32 tile."""
+    tile, n_tiles = cco_ops._tiling(n_items, item_tile)
+    chunk, group, _ = cco_ops._block_plan(
+        n_baskets, n_tiles * tile, tile, n_tiles, own_slab=False, f32_tiles=1)
+    return tile, n_tiles, chunk, max(-(-n_baskets // chunk), 1), group
 
-    def plan(n_baskets, n_items, item_tile):
-        tile, n_tiles, _, _ = real(n_baskets, n_items, item_tile)
-        width = n_tiles * tile
+
+def _step_bytes(chunk: int, group: int, width: int, tile: int) -> int:
+    """What `_block_plan` reckons for a basket step in bf16: the carried
+    group, one float32 tile, the densified chunk three times."""
+    return (group + 1) * width * tile * 4 + chunk * 3 * width * 2
+
+
+def _small_plan(monkeypatch, chunk: int, group: int = 1):
+    """Basket chunks of ``chunk`` rows and ``group`` tiles a step: the block
+    the matmul needs shrunk to the chunk, and the plan's budget to exactly
+    what such a step takes (see _block_plan)."""
+    real = cco_ops._block_plan
+
+    def plan(n_rows, width, tile, n_tiles, **flags):
         monkeypatch.setattr(cco_ops, "_TILED_P_BYTES",
-                            2 * width * tile * 4 + chunk * 3 * width * 2)
-        return real(n_baskets, n_items, item_tile)
+                            _step_bytes(chunk, group, width, tile))
+        return real(n_rows, width, tile, n_tiles, **flags)
 
-    monkeypatch.setattr(cco_ops, "_basket_plan", plan)
+    monkeypatch.setattr(cco_ops, "_BLOCK_ROWS", chunk)
+    monkeypatch.setattr(cco_ops, "_block_plan", plan)
 
 
 def test_the_plan_of_the_basket_program_at_the_cells_size(monkeypatch):
-    """65,536 baskets x 100,000 items: 25 tiles of 4,096 and 8 chunks of
-    8,192, planned at 8.39 GB of the 12 GB the rule has; a shop of few
-    baskets is one chunk, a chip half the size halves the chunk."""
+    """65,536 baskets x 100,000 items: 25 tiles of 4,096 counted five to a
+    group against 32 chunks of 2,048, planned at 11.32 GB of the 12 GB the
+    rule has; a shop of few baskets is one chunk, a chip half the size
+    counts one tile a step against a chunk of 4,096, or two in int8."""
     monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
-    assert cco_ops._basket_plan(65_536, 100_000, 4096) == (4096, 25, 8192, 8)
-    width = 25 * 4096
-    assert 3 * 8192 * width * 2 + 2 * width * 4096 * 4 == pytest.approx(
-        8.39e9, rel=1e-3)
-    assert cco_ops._basket_plan(900, 100_000, 4096) == (4096, 25, 1024, 1)
-    assert cco_ops._basket_plan(5, 3, 4096) == (3, 1, 256, 1)
+    assert _basket_shape(65_536, 100_000, 4096) == (4096, 25, 2048, 32, 5)
+    assert _step_bytes(2048, 5, 25 * 4096, 4096) == pytest.approx(
+        11.32e9, rel=1e-3)
+    assert _basket_shape(900, 100_000, 4096) == (4096, 25, 1024, 1, 5)
+    assert _basket_shape(5, 3, 4096) == (3, 1, 128, 1, 1)
     monkeypatch.setattr(cco_ops, "_TILED_P_BYTES", cco_ops._TILED_P_BYTES // 2)
-    assert cco_ops._basket_plan(65_536, 100_000, 4096) == (4096, 25, 4096, 16)
+    assert _basket_shape(65_536, 100_000, 4096) == (4096, 25, 4096, 16, 1)
     monkeypatch.setenv("PIO_CCO_MM_DTYPE", "int8")
-    assert cco_ops._basket_plan(65_536, 100_000, 4096) == (4096, 25, 8192, 8)
+    assert _basket_shape(65_536, 100_000, 4096) == (4096, 25, 2048, 32, 2)
+
+
+@pytest.mark.parametrize("n_baskets,n_items,tile,chunk,group,min_size", [
+    (300, 150, 32, 128, 2, 1),   # 5 tiles: two groups of 2 and a last one
+                                 # of 1; a last tile of 22; a last chunk of 44
+    (300, 150, 32, 128, 3, 1),   # one whole group and a last one of 2
+    (300, 150, 32, 128, 5, 1),   # every tile against one densify of a chunk
+    (300, 128, 32, 256, 2, 1),   # whole tiles, two whole groups, 2 chunks
+    (100, 150, 32, 128, 4, 1),   # one chunk only
+    (400, 90, 32, 128, 2, 2),    # baskets of one item dropped: N is fewer
+])
+def test_grouped_basket_program_equals_one_tile_a_step_to_the_bit(
+        monkeypatch, n_baskets, n_items, tile, chunk, group, min_size):
+    """Several item tiles counted against one densified chunk give the
+    counts one tile a step gives, and the tiles merge in the same order:
+    lifts, ids and confidences are equal to the bit, the budget alone
+    deciding the group."""
+    from predictionio_tpu.obs.spans import SpanCollector
+
+    rng = np.random.default_rng(360 + group)
+    gb = rng.integers(0, n_baskets, 6 * n_baskets).astype(np.int32)
+    gi = (rng.zipf(1.3, 6 * n_baskets) % n_items).astype(np.int32)
+    if min_size > 1:            # a fifth of the baskets hold one item
+        lone = gb % 5 == 0
+        gi[lone] = gb[lone] % n_items
+
+    def run(g):
+        monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
+        _small_plan(monkeypatch, chunk, g)
+        with SpanCollector().activate() as collector:
+            out = basket_rules(gb, gi, n_baskets, n_items, top_k=6,
+                               min_support=0.004, min_confidence=0.05,
+                               min_basket_size=min_size, item_tile=tile)
+        monkeypatch.undo()
+        attrs = {s["name"]: s["attrs"] for s in collector.spans()}
+        return out, attrs["dispatch"], attrs["layout"]
+
+    one, at_one, _ = run(1)
+    grouped, at_group, laid = run(group)
+    n_tiles = -(-n_items // tile)
+    n_chunks = -(-laid["baskets"] // chunk)
+    assert (laid["baskets_dropped"] > n_baskets // 10) == (min_size > 1)
+    assert (at_one["chunk"], at_one["tile_group"]) == (chunk, 1)
+    assert at_one["steps"] == n_tiles * n_chunks
+    assert (at_group["chunk"], at_group["tile_group"]) == (chunk, group)
+    assert at_group["plan_bytes"] == _step_bytes(chunk, group, n_tiles * tile,
+                                                 tile)
+    assert (at_group["tiles"], at_group["chunks"]) == (n_tiles, n_chunks)
+    assert at_group["steps"] == -(-n_tiles // group) * n_chunks
+    for a, b in zip(one, grouped):
+        assert np.array_equal(a, b)
+    assert (one[1] >= 0).sum() > n_items    # and there is something to compare
 
 
 def test_basket_rules_chunked_exact(monkeypatch):
@@ -167,7 +235,7 @@ def test_basket_rules_chunked_exact(monkeypatch):
     b = np.concatenate([np.arange(n_baskets),      # none empty: N is 1000
                         rng.integers(0, n_baskets, 7000)]).astype(np.int32)
     i = rng.integers(0, n_items, 8000).astype(np.int32)
-    assert cco_ops._basket_plan(n_baskets, n_items, 4096)[2:] == (256, 4)
+    assert _basket_shape(n_baskets, n_items, 4096)[2:] == (256, 4, 1)
     lift, idx, conf = basket_rules(b, i, n_baskets, n_items, top_k=n_items)
     # dense numpy reference
     B = np.zeros((n_baskets, n_items))
@@ -224,7 +292,7 @@ def test_basket_rules_tiled_matches_dense(monkeypatch):
     dense = basket_rules(gb, gi, n_baskets, n_items, top_k=6,
                          min_support=0.004, min_confidence=0.1)
     _small_plan(monkeypatch, 256)
-    assert cco_ops._basket_plan(n_baskets, n_items, 32) == (32, 3, 256, 2)
+    assert _basket_shape(n_baskets, n_items, 32) == (32, 3, 256, 2, 1)
     tiled = basket_rules(gb, gi, n_baskets, n_items, top_k=6,
                          min_support=0.004, min_confidence=0.1,
                          item_tile=32)
@@ -405,7 +473,8 @@ def test_engine_train_on_the_blocked_program_against_the_reference(
         mem_storage, monkeypatch, seed):
     """cp-ecom-100k's engine.json at a small size: 900 kept baskets in
     chunks of 256 (the last holds 132), 700 items in tiles of 256 (the
-    last holds 188), 220 one-item visits dropped.  `Engine.train` from the
+    last holds 188) counted two to a group (the last group holds one),
+    220 one-item visits dropped.  `Engine.train` from the
     engine variant, the Pallas tournament interpreted, every row of the
     persisted table held against the plain reference by the
     configuration's limits."""
@@ -413,10 +482,10 @@ def test_engine_train_on_the_blocked_program_against_the_reference(
     from predictionio_tpu.workflow import create_workflow
 
     monkeypatch.setenv("PIO_PALLAS", "interpret")
-    _small_plan(monkeypatch, 256)
+    _small_plan(monkeypatch, 256, group=2)
     data = _bench_module("data", "shop_visits").generate(SHOP, seed)
-    assert cco_ops._basket_plan(data["n_baskets"], data["n_items"], 256) == (
-        256, 3, 256, 4)
+    assert _basket_shape(data["n_baskets"], data["n_items"], 256) == (
+        256, 3, 256, 4, 2)
     app_id = mem_storage.apps.insert(App(0, "blocked"))
     wire = _bench_module("drivers", "train_jobs").wire_events
     for r in mem_storage.l_events.insert_json_batch(
@@ -432,8 +501,11 @@ def test_engine_train_on_the_blocked_program_against_the_reference(
     spans = collector.spans()
     n_events = len(data["blocks"][0]["users"])
     dispatched = [s["attrs"] for s in spans if s["name"] == "dispatch"]
+    # densify + count steps: groups x chunks, a whole group and one of 1
     assert dispatched == [{"program": "_basket_rules_tiled", "topk": "pallas",
-                           "tiles": 3, "chunks": 4, "steps": 12,
+                           "tiles": 3, "chunks": 4, "steps": 2 * 4,
+                           "chunk": 256, "tile_group": 2,
+                           "plan_bytes": cco_ops._TILED_P_BYTES,
                            "topk_block": 8, "topk_slab_stages": 10.0,
                            "topk_lane_stages": 0.0}]
     formed, laid = [s["attrs"] for s in spans if s["name"] == "layout"]

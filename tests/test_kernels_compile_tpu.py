@@ -45,6 +45,14 @@ def test_tile_topk_compiles_for_a_v5e(one_chip, rows, width, b):
         lambda s: pk._tile_topk_padded(s, b, True), scores)] == [(rows, b)] * 2
 
 
+def _held_bytes(compiled) -> int:
+    """The TPU compiler's memory plan of one program: arguments,
+    temporaries and outputs."""
+    memory = compiled.memory_analysis()
+    return (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes)
+
+
 def test_blocked_cco_program_fits_a_v5e(one_chip, monkeypatch):
     """The user-blocked program at ur-ecom-100k-u131k's shape, under the
     step `_block_plan` derives for it: the TPU compiler's own memory plan
@@ -72,8 +80,38 @@ def test_blocked_cco_program_fits_a_v5e(one_chip, monkeypatch):
         group=group, block=block, n_items_p=items, tile=tile, top_k=50,
         llr_threshold=0.0, pallas="compiled", exclude_self=False,
         topk="pallas").compile()
-    memory = compiled.memory_analysis()
-    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            + memory.output_size_in_bytes)
-    assert held < 14e9, held
+    assert _held_bytes(compiled) < 14e9
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_basket_program_fits_a_v5e(one_chip, monkeypatch):
+    """The basket program at cp-ecom-100k's shape, under the step
+    `_block_plan` derives for it (a chunk of 2,048, five tiles a group):
+    the TPU compiler's memory plan stays inside 14 GB.  Its buffers are the
+    carried group and ONE float32 tile, the densified chunk's copies in
+    the tile's place while the chunks are counted (10.07 GB); the plan it
+    reports reads a tile more (11.76 GB) [AOT, PR 36]."""
+    from predictionio_tpu.ops import cco, pallas_kernels as pk
+
+    monkeypatch.delenv("PIO_CCO_MM_DTYPE", raising=False)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    baskets, tile, tiles = 65536, 4096, 25      # 100,000 items in 25 tiles
+    width = tiles * tile
+    chunk, group, plan_bytes = cco._block_plan(
+        baskets, width, tile, tiles, own_slab=False, f32_tiles=1)
+    assert (chunk, group) == (2048, 5) and plan_bytes <= cco._TILED_P_BYTES
+    n_chunks = baskets // chunk
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # a chunk's share of the 394,000 pairs of the baskets kept, and some
+    log = of((n_chunks, 394_000 // n_chunks // 8 * 9), jnp.int32)
+    scalar = of((), jnp.float32)
+    compiled = cco._basket_rules_tiled.lower(
+        log, log, of((n_chunks,), jnp.int32), scalar, of((width,), jnp.float32),
+        chunk=chunk, n_tiles=tiles, group=group, tile=tile, top_k=5,
+        min_support=scalar, min_confidence=scalar, min_lift=scalar,
+        topk="pallas", mm="bf16").compile()
+    assert _held_bytes(compiled) < 14e9
     assert "tpu_custom_call" in compiled.as_text()
