@@ -187,21 +187,69 @@ def dedup_pairs(user: np.ndarray, item: np.ndarray, n_items: int):
 
 
 def _llr_mask_scores(c, row_counts, col_counts, n_total, llr_threshold,
-                     pallas: str):
+                     pallas: str, col_start=None, width: Optional[int] = None,
+                     diagonal=None):
     """Shared LLR scoring + masking used by EVERY strategy (dense, chunked
     tiled, P-resident tiled): G² over the 2×2 table, -inf where there is no
-    cooccurrence or the score misses the significance threshold."""
+    cooccurrence or the score misses the significance threshold.  The
+    counts may be of any number type.  ``width``: score that many columns
+    of ``c`` from ``col_start``, a multiple of ``width`` (a tile read in
+    its group).  ``diagonal``: -inf too where row − column == diagonal,
+    the item against itself (``_self_pair_diagonal``); this is the one
+    place the UR programs mask self-pairs, so every tile is masked once."""
     if pallas != "off":
         from predictionio_tpu.ops.pallas_kernels import llr_masked_scores
 
-        return llr_masked_scores(c, row_counts, col_counts, n_total, llr_threshold)
+        return llr_masked_scores(c, row_counts, col_counts, n_total,
+                                 llr_threshold, col_start=col_start,
+                                 width=width, diagonal=diagonal)
+    if width is not None and width != c.shape[1]:
+        c = jax.lax.dynamic_slice_in_dim(c, col_start, width, 1)
+        col_counts = jax.lax.dynamic_slice_in_dim(col_counts, col_start, width)
+    c = c.astype(jnp.float32)
     k11 = c
-    k12 = row_counts[:, None] - c
-    k21 = col_counts[None, :] - c
+    k12 = row_counts.astype(jnp.float32)[:, None] - c
+    k21 = col_counts.astype(jnp.float32)[None, :] - c
     k22 = n_total - k11 - k12 - k21
     scores = llr_score(k11, k12, k21, k22)
     scores = jnp.where(c > 0, scores, -jnp.inf)
-    return jnp.where(scores >= llr_threshold, scores, -jnp.inf)
+    scores = jnp.where(scores >= llr_threshold, scores, -jnp.inf)
+    return _mask_self_pairs(scores, diagonal)
+
+
+def _self_pair_diagonal(exclude_self: bool, tile_start, row_offset=None):
+    """Where a tile's self-pairs lie: row − column of the cell that pairs
+    an item with itself (the tile's first column ``tile_start`` less the
+    primary item of row 0, ``row_offset``, where the rows are one chip's
+    share), or None where nothing is masked."""
+    if not exclude_self:
+        return None
+    return tile_start if row_offset is None else tile_start - row_offset
+
+
+def _mask_self_pairs(scores, diagonal):
+    """-inf where row − column == ``diagonal`` (None: as they are): the
+    XLA form of the LLR kernel's mask, fused by XLA into the scores."""
+    if diagonal is None:
+        return scores
+    rows = jnp.arange(scores.shape[0], dtype=jnp.int32)[:, None]
+    cols = diagonal + jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+    return jnp.where(rows == cols, -jnp.inf, scores)
+
+
+def _llr_attrs(pallas: str, rows: int, width: int,
+               exclude_self: bool) -> dict:
+    """What a tiled UR program's ``dispatch`` span says of its LLR: the
+    kernel's block (``llr_block``), the rows of its partial last block
+    (``llr_edge_rows``, 0 where the blocks divide the rows) and where the
+    self-pairs are masked (``llr_mask``: in the kernel, by XLA, none)."""
+    if pallas == "off":
+        return {"llr_mask": "xla" if exclude_self else "none"}
+    from predictionio_tpu.ops.pallas_kernels import llr_blocks
+
+    tile_r, tile_c = llr_blocks(rows, width)
+    return {"llr_block": f"{tile_r}x{tile_c}", "llr_edge_rows": rows % tile_r,
+            "llr_mask": "kernel" if exclude_self else "none"}
 
 
 def topk_impl() -> str:
@@ -245,10 +293,10 @@ def _topk_attrs(impl: str, tile: int, top_k: int) -> dict:
 
 
 def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
-                top_k: int, n_items_p: int, exclude_self: bool,
-                impl: str = "lax", row_offset=None):
-    """Shared running top-k merge for the tiled strategies; masks self-pairs
-    BEFORE the merge so every row still gets a full top_k correlators.
+                top_k: int, impl: str = "lax"):
+    """Shared running top-k merge for the tiled strategies.  The scores
+    come with their self-pairs masked already (by the scoring step, so
+    every row still gets a full top_k correlators).
 
     impl='lax': top_k over concat(carry, tile), then a gather of the
     kept indices — on a TPU XLA's full variadic row sort, 326 + 89 ms a
@@ -261,20 +309,8 @@ def _merge_topk(best_scores, best_idx, scores, tile_start, tile: int,
     PR 32; 153 ms before it), ~2 ms for the merge (chip run, PR 25).  The
     carry is then [I, block_width(top_k)], sorted desc; _finalize_topk
     slices back to top_k.
-
-    ``row_offset``: the primary item of row 0 where the rows are one
-    chip's share of the primary's items (the sharded resident program);
-    only ``exclude_self`` reads it.
     """
-    # outside any stage: XLA fuses the mask into what produced the scores,
-    # and a fusion that no stage names is the stage of most of what it
-    # does (the scores: utils.device.parse_stage_map's second rule)
     tile_idx = tile_start + jnp.arange(tile, dtype=jnp.int32)[None, :]
-    if exclude_self:
-        row_ids = jnp.arange(n_items_p, dtype=jnp.int32)[:, None]
-        if row_offset is not None:
-            row_ids = row_ids + row_offset
-        scores = jnp.where(tile_idx == row_ids, -jnp.inf, scores)
     if impl == "pallas":
         from predictionio_tpu.ops.pallas_kernels import tile_topk_desc
         from predictionio_tpu.ops.topk import merge_desc
@@ -491,11 +527,11 @@ def _cco_tile_body_resident(
             cct = jax.lax.psum(cct, axis_name)
         row_offset = jax.lax.axis_index(axis_name) * c.shape[0]
     with stage("cco.llr"):
-        scores = _llr_mask_scores(c, rc.astype(jnp.float32), cct, n_total,
-                                  llr_threshold, pallas)
+        scores = _llr_mask_scores(
+            c, rc, cct, n_total, llr_threshold, pallas,
+            diagonal=_self_pair_diagonal(exclude_self, tile_start, row_offset))
     return _merge_topk(best_scores, best_idx, scores, tile_start, tile,
-                       top_k, c.shape[0], exclude_self, impl=topk,
-                       row_offset=row_offset)
+                       top_k, impl=topk)
 
 
 def _scan_tiles(step, n_items_p: int, n_tiles: int, tile: int, top_k: int,
@@ -654,7 +690,9 @@ def _cco_resident(
             a_gu, a_gi = jnp.asarray(au), jnp.asarray(ai)
             a_valid = jnp.ones(len(au), bool)
         with span("dispatch", program="_cco_resident_all_tiles",
-                  **_topk_attrs(topk, tile, top_k)):
+                  **_topk_attrs(topk, tile, top_k),
+                  **_llr_attrs(static["pallas"], n_items_p, tile,
+                               exclude_self)):
             best_scores, best_idx = noted(
                 _cco_resident_all_tiles,
                 Pm, rc, a_gu, a_gi, a_valid, float(n_users),
@@ -677,7 +715,8 @@ def _cco_resident(
               # what one chip sends in the job's reduce-scatters: all of
               # every float32 partial count tile but its own rows
               exchange_mb=n_tiles * (rows - rows // dp) * tile * 4 / 1e6,
-              **_topk_attrs(topk, tile, top_k)):
+              **_topk_attrs(topk, tile, top_k),
+              **_llr_attrs(static["pallas"], rows // dp, tile, exclude_self)):
         best_scores, best_idx = noted(
             _cco_sharded_all_tiles,
             Pm, rc, a.local_u, a.item, a.count, float(n_users),
@@ -773,19 +812,18 @@ def _cco_group_step(
     if axis_name is not None:
         with stage("cco.exchange"):
             c, rc, cc = jax.lax.psum((c, rc, cc), axis_name)
-    rcf = rc.astype(jnp.float32)
 
     def one_tile(g, best):
         # one tile at a time: a loop, so that the compiler holds one
-        # float32 tile and its scores, not the group's [AOT, PR 34]
+        # tile's scores, not the group's; the kernel reads the tile
+        # inside the carried int32 group
+        tile_start = start + g * tile
         with stage("cco.llr"):
-            c_t = jax.lax.dynamic_slice(c, (0, g * tile), (n_items_p, tile))
-            cc_t = jax.lax.dynamic_slice(cc, (g * tile,), (tile,))
             scores = _llr_mask_scores(
-                c_t.astype(jnp.float32), rcf, cc_t.astype(jnp.float32),
-                n_total, llr_threshold, pallas)
-        return _merge_topk(*best, scores, start + g * tile, tile, top_k,
-                           n_items_p, exclude_self, impl=topk)
+                c, rc, cc, n_total, llr_threshold, pallas,
+                col_start=g * tile, width=tile,
+                diagonal=_self_pair_diagonal(exclude_self, tile_start))
+        return _merge_topk(*best, scores, tile_start, tile, top_k, impl=topk)
 
     best = jax.lax.fori_loop(0, group, one_tile, (best_scores, best_idx))
     return (*best, rc)
@@ -936,14 +974,9 @@ def _llr_topk_dense(
     alone."""
     with stage("cco.llr"):
         scores = _llr_mask_scores(
-            C.astype(jnp.float32), rc.astype(jnp.float32),
-            cc.astype(jnp.float32), n_total, llr_threshold, pallas)
+            C, rc, cc, n_total, llr_threshold, pallas,
+            diagonal=_self_pair_diagonal(exclude_self, 0))
     with stage("cco.topk_merge"):
-        if exclude_self:
-            n_p, n_t = scores.shape
-            eye = jnp.arange(n_p, dtype=jnp.int32)[:, None] == jnp.arange(
-                n_t, dtype=jnp.int32)[None, :]
-            scores = jnp.where(eye, -jnp.inf, scores)
         best_scores, best_idx = jax.lax.top_k(scores, top_k)
         return best_scores, best_idx.astype(jnp.int32)
 
@@ -1824,7 +1857,9 @@ def _cco_chunked(
                   tiles=n_tiles, user_block=primary.user_block,
                   tile_group=group, plan_bytes=plan_bytes,
                   block_steps=math.ceil(n_tiles / group) * primary.n_blocks,
-                  **_topk_attrs(topk, tile, top_k)):
+                  **_topk_attrs(topk, tile, top_k),
+                  **_llr_attrs(static["pallas"], n_items_p, tile,
+                               exclude_self)):
             best_scores, best_idx = noted(
                 _cco_chunked_all_tiles,
                 *args, float(n_total_users), n_tiles=n_tiles, group=group,
@@ -1851,7 +1886,9 @@ def _cco_chunked(
         carry_k = _carry_width(top_k, topk)
         best_scores = jnp.full((n_items_p, carry_k), -jnp.inf, jnp.float32)
         best_idx = jnp.zeros((n_items_p, carry_k), jnp.int32)
-        with span("dispatch", program="_cco_group_step"):
+        with span("dispatch", program="_cco_group_step",
+                  **_llr_attrs(static["pallas"], n_items_p, tile,
+                               exclude_self)):
             for t in range(n_tiles):
                 best_scores, best_idx = noted(
                     tile_step_sharded,
@@ -1950,12 +1987,12 @@ def _basket_rules_tiled(
             with stage("basket.score"):
                 c_t = jax.lax.dynamic_slice(c, (0, g * tile), (width, tile))
                 ci_col = jax.lax.dynamic_slice(ci, (tile_start,), (tile,))
-                scores = _basket_scores(
+                scores = _mask_self_pairs(_basket_scores(
                     c_t.astype(jnp.float32), ci[:, None], ci_col[None, :],
-                    n_baskets, min_support, min_confidence, min_lift)
-            # exclude_self masks the diagonal inside the merge
-            return _merge_topk(*best, scores, tile_start, tile, top_k, width,
-                               exclude_self=True, impl=topk)
+                    n_baskets, min_support, min_confidence, min_lift),
+                    tile_start)
+            return _merge_topk(*best, scores, tile_start, tile, top_k,
+                               impl=topk)
 
         return jax.lax.fori_loop(0, size, one_tile, best)
 

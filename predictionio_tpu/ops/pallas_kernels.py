@@ -12,8 +12,9 @@ beyond what XLA does automatically:
   tile*, so the [B, I] score matrix is written to HBM exactly once instead
   of the mask/bias reading it back (3 HBM round-trips → 1).
 - ``llr_masked_scores`` — the CCO tile post-pass: Dunning G² over the
-  2×2 contingency table + cooccurrence mask + significance threshold,
-  fused into one VPU pass over each count tile.
+  2×2 contingency table + cooccurrence mask + significance threshold +
+  self-pair mask, fused into one VPU pass over each count tile where it
+  lies (a tile of a wider group included), scores at their own shape.
 
 - ``tile_topk_desc`` — exact per-row top-k of a score tile as an in-VMEM
   bitonic tournament across whole vregs (the tiled-CCO merge's per-tile
@@ -189,12 +190,13 @@ def recommend_batch_fused(
 # ---------------------------------------------------------------------------
 
 
-def _llr_kernel(c_ref, row_ref, col_ref, scalars_ref, out_ref):
+def _llr_kernel(block_ref, diag_ref, c_ref, row_ref, col_ref, scalars_ref,
+                out_ref, *, mask: bool):
     from predictionio_tpu.ops.cco import llr_score
 
-    c = c_ref[:]
-    row = row_ref[:]               # [TB, 1] primary-item user counts
-    col = col_ref[:]               # [1, TI] other-item user counts
+    c = c_ref[:].astype(jnp.float32)     # the counts' own dtype, in VMEM
+    row = row_ref[:].astype(jnp.float32)   # [TB, 1] primary-item user counts
+    col = col_ref[:].astype(jnp.float32)   # [1, TI] other-item user counts
     n_total = scalars_ref[0, 0]
     threshold = scalars_ref[0, 1]
     k11 = c
@@ -204,55 +206,109 @@ def _llr_kernel(c_ref, row_ref, col_ref, scalars_ref, out_ref):
     g2 = llr_score(k11, k12, k21, k22)   # determinant-form G², VPU-only
     keep = (c > 0) & (g2 >= threshold)
     out_ref[:] = jnp.where(keep, g2, NEG_INF)
+    if mask:
+        # cell (a, b) of this block is an item against itself where
+        # a - b == d; only the blocks the diagonal crosses pay the compare,
+        # on the scores already stored (a value held across two branches
+        # ran the kernel 1.3% slower on a TPU v5e)
+        tile_r, tile_c = out_ref.shape
+        d = diag_ref[0] - (pl.program_id(0) * tile_r
+                           - pl.program_id(1) * tile_c)
+
+        @pl.when((d > -tile_c) & (d < tile_r))
+        def _():
+            a = jax.lax.broadcasted_iota(jnp.int32, (tile_r, tile_c), 0)
+            b = jax.lax.broadcasted_iota(jnp.int32, (tile_r, tile_c), 1)
+            out_ref[:] = jnp.where(a - b == d, NEG_INF, out_ref[:])
 
 
-@functools.partial(jax.jit, static_argnames=("tile_r", "tile_c", "interpret"))
-def _llr_padded(c, row, col, scalars, tile_r: int, tile_c: int, interpret: bool):
-    rp, cp = c.shape
-    grid = (rp // tile_r, cp // tile_c)
+@functools.partial(jax.jit, static_argnames=(
+    "width", "tile_r", "tile_c", "mask", "interpret"))
+def _llr_padded(block, diag, c, row, col, scalars, width: int, tile_r: int,
+                tile_c: int, mask: bool, interpret: bool):
+    """The LLR kernel: scores [R, width] of ``width`` columns of ``c``
+    [R, W] from column block ``block[0]`` (``col`` [1, W] alike; ``row``
+    is [R, 1]); with ``mask``, -inf too where row − column == ``diag[0]``.
+    ``block`` and ``diag`` are prefetched scalars: they reach the index
+    maps and the kernel before the grid runs.  "Padded" is the grid's:
+    its last row block (and column block) runs past the array, where a
+    TPU reads unspecified values and drops the writes; the pass is
+    elementwise, so nothing leaks, and no copy is made to fit the
+    blocks."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    r = c.shape[0]
+    cells = r * width
     return pl.pallas_call(
-        _llr_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_r, tile_c), lambda i, j: (i, j)),
-            pl.BlockSpec((tile_r, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile_c), lambda i, j: (0, j)),
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j: (i, j)),
-        out_shape=_out_struct((rp, cp), jnp.float32, c, row, col, scalars),
+        functools.partial(_llr_kernel, mask=mask),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(r, tile_r), pl.cdiv(width, tile_c)),
+            in_specs=[
+                pl.BlockSpec((tile_r, tile_c),
+                             lambda i, j, blk, _: (i, j + blk[0])),
+                pl.BlockSpec((tile_r, 1), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec((1, tile_c), lambda i, j, blk, _: (0, j + blk[0])),
+                pl.BlockSpec((1, 2), lambda i, j, *_: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j, *_: (i, j)),
+        ),
+        out_shape=_out_struct((r, width), jnp.float32, block, diag, c, row,
+                              col, scalars),
         cost_estimate=pl.CostEstimate(
-            flops=30 * rp * cp,
-            bytes_accessed=4 * 2 * rp * cp,
-            transcendentals=9 * rp * cp,   # the xlogx logs
+            flops=30 * cells,
+            bytes_accessed=(c.dtype.itemsize + 4) * cells,
+            transcendentals=9 * cells,   # the xlogx logs
         ),
         interpret=interpret,
-    )(c, row, col, scalars)
+    )(block, diag, c, row, col, scalars)
+
+
+def llr_blocks(rows: int, width: int) -> Tuple[int, int]:
+    """``(tile_r, tile_c)`` of the LLR kernel over ``rows`` × ``width``
+    scores: 256 rows, and the widest of 512, 256, 128 columns that divides
+    ``width`` (a tile read in its group starts on a whole block), else
+    ``width`` in whole 128-wide columns, at most 512."""
+    tile_r = min(256, _round_up(rows, 8))
+    tile_c = next((t for t in (512, 256, 128) if width % t == 0),
+                  min(512, _round_up(width, 128)))
+    return tile_r, tile_c
 
 
 def llr_masked_scores(
-    counts: jnp.ndarray,       # [R, C] cooccurrence counts
+    counts: jnp.ndarray,       # [R, W] cooccurrence counts, any number type
     row_counts: jnp.ndarray,   # [R] users per primary item
-    col_counts: jnp.ndarray,   # [C] users per other item
+    col_counts: jnp.ndarray,   # [W] users per other item
     n_total: float,
     threshold: float = 0.0,
-    tile_r: int = 256,
-    tile_c: int = 512,
+    col_start=None,            # with width: read [R, width] from this
+    width: Optional[int] = None,   # column, a multiple of width (traced)
+    diagonal=None,             # -inf where row − column == diagonal (traced)
 ) -> jnp.ndarray:
-    """Fused G² scores with zero-cooccurrence + threshold masking (-inf)."""
-    r, c = counts.shape
-    tile_r = min(tile_r, _round_up(r, 8))
-    tile_c = min(tile_c, _round_up(c, 128))
-    rp, cp = _round_up(r, tile_r), _round_up(c, tile_c)
-    cm = jnp.zeros((rp, cp), jnp.float32).at[:r, :c].set(counts)
-    rowm = jnp.zeros((rp, 1), jnp.float32).at[:r, 0].set(row_counts)
-    colm = jnp.zeros((1, cp), jnp.float32).at[0, :c].set(col_counts)
+    """Fused G² scores with zero-cooccurrence + threshold masking (-inf),
+    [R, width] (``width`` W unless a window is asked for), and with
+    ``diagonal`` the self-pairs masked too, in the same pass.  The kernel
+    reads ``counts`` where it lies and converts it to float32 in VMEM:
+    neither a padded copy nor a sliced or converted tile is made."""
+    r, w = counts.shape
+    window = width is not None and width != w
+    width = w if width is None else width
+    tile_r, tile_c = llr_blocks(r, width)
+    if window and width % tile_c:     # a window no 128-wide block divides
+        counts = jax.lax.dynamic_slice_in_dim(counts, col_start, width, 1)
+        col_counts = jax.lax.dynamic_slice_in_dim(col_counts, col_start, width)
+        window = False
+    block = (jax.lax.div(jnp.asarray(col_start, jnp.int32), jnp.int32(tile_c))
+             if window else jnp.int32(0))
+    diag = jnp.asarray(0 if diagonal is None else diagonal, jnp.int32)
     # n_total / threshold may be traced scalars (called inside a jitted step)
     scalars = jnp.stack(
         [jnp.asarray(n_total, jnp.float32), jnp.asarray(threshold, jnp.float32)]
     ).reshape(1, 2)
-    out = _llr_padded(cm, rowm, colm, scalars, tile_r, tile_c, _interpret())
-    return out[:r, :c]
+    return _llr_padded(block.reshape(1), diag.reshape(1), counts,
+                       row_counts.reshape(r, 1),
+                       col_counts.reshape(1, -1), scalars, width, tile_r,
+                       tile_c, diagonal is not None, _interpret())
 
 
 # ---------------------------------------------------------------------------

@@ -307,6 +307,10 @@ def test_engine_through_the_chunked_program_agrees_with_the_reference(
         assert d["plan_bytes"] == plan[2]
         # densify + count steps: groups x blocks, a whole group and one of 1
         assert d["block_steps"] == 2 * n_blocks == 32
+        # the LLR kernel reads each tile in its group: 700 rows = two
+        # blocks of 256 and one of 188
+        assert d["llr_block"] == "256x256" and d["llr_edge_rows"] == 188
+    assert [d["llr_mask"] for d in dispatched] == ["kernel", "none"]
     laid = [s["attrs"] for s in spans if s["name"] == "layout"
             and "user_blocks" in s.get("attrs", {})]
     assert len(laid) == 2

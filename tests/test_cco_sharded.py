@@ -262,6 +262,9 @@ def test_engine_with_mesh_dp_4_takes_the_sharded_program(
         assert d["rows_per_chip"] == rows // 4 and d["topk"] == "pallas"
         # three quarters of each float32 partial tile leave the chip
         assert d["exchange_mb"] == tiles * (rows * 3 // 4) * TILE * 4 / 1e6
+        # a chip's 256 rows are whole blocks of the LLR kernel
+        assert d["llr_block"] == "256x256" and d["llr_edge_rows"] == 0
+    assert [d["llr_mask"] for d in dispatched[1::2]] == ["kernel", "none"]
     laid = [s["attrs"] for s in spans if s["name"] == "layout"
             and "dp" in s.get("attrs", {})]
     # buy against itself stages its pairs once; view stages the primary

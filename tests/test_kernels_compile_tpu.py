@@ -1,5 +1,5 @@
-"""The selection kernel compiled for a described TPU v5e at the shapes
-the benchmark's cells run, with no chip: Mosaic refuses here what it
+"""The selection and LLR kernels compiled for a described TPU v5e at the
+shapes the benchmark's cells run, with no chip: Mosaic refuses here what it
 would refuse there (a layout it cannot turn, too much VMEM), which the
 interpreter never sees.  Nothing runs, so nothing here is a speed.
 
@@ -43,6 +43,45 @@ def test_tile_topk_compiles_for_a_v5e(one_chip, rows, width, b):
     assert "tpu_custom_call" in compiled.as_text()
     assert [o.shape for o in jax.eval_shape(
         lambda s: pk._tile_topk_padded(s, b, True), scores)] == [(rows, b)] * 2
+
+
+@pytest.mark.parametrize("rows,width,group,dtype", [
+    (100_000, 4096, 1, jnp.float32),   # ur-ecom-100k: a resident tile
+    (100_000, 4096, 4, jnp.int32),     # u131k: a tile of the carried group
+    (25_088, 4096, 1, jnp.float32),    # the four-chip cell: a chip's rows
+    # fewer rows than a block; a window no 128-wide block divides
+    (37, 200, 3, jnp.int32),
+])
+def test_llr_compiles_for_a_v5e(one_chip, monkeypatch, rows, width, group,
+                                dtype):
+    """The LLR kernel at the cells' shapes, masked: the program is the
+    custom call on the counts as they lie, the tile read in its group, and
+    the scores at their own shape; no pad, slice or conversion of the
+    tile around it."""
+    import re
+
+    from predictionio_tpu.ops import cco, pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def of(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def llr(c, row, col, start, diagonal):
+        return cco._llr_mask_scores(c, row, col, 1e6, 0.0, "compiled",
+                                    col_start=start, width=width,
+                                    diagonal=diagonal)
+
+    scalar = of((), jnp.int32)
+    compiled = jax.jit(llr).lower(
+        of((rows, group * width), dtype), of((rows,), dtype),
+        of((group * width,), dtype), scalar, scalar).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%_llr_padded[.0-9]* = f32\[{rows},{width}\]", text)
+    aligned = width % 128 == 0
+    tile_shaped = rf"\[{rows},{width}\]\S* (pad|slice|dynamic-slice|convert)\("
+    assert (re.search(tile_shaped, text) is None) == aligned
+    assert "f32[100096" not in text
 
 
 def _held_bytes(compiled) -> int:

@@ -270,6 +270,43 @@ def test_tiled_dispatch_spans_say_which_network_ran(monkeypatch, program):
     assert on["topk_lane_stages"] == plan.lane_stages == 0.0
 
 
+@pytest.mark.parametrize("exclude_self", [True, False])
+@pytest.mark.parametrize("program", ["_cco_resident_all_tiles",
+                                     "_cco_chunked_all_tiles"])
+def test_tiled_dispatch_spans_say_which_llr_ran(monkeypatch, program,
+                                                exclude_self):
+    """A journal says which LLR form a job ran: under the kernel the
+    block, the rows of its partial last block and whether the kernel
+    masks the self-pairs; under the XLA twin only where the mask is."""
+    from predictionio_tpu.obs.spans import SpanCollector
+    from predictionio_tpu.ops import cco as cco_ops
+
+    rng = np.random.default_rng(6)
+    n_users, n_items, tile = 60, 300, 64
+    u = rng.integers(0, n_users, 900)
+    it = rng.integers(0, n_items, 900)
+    monkeypatch.setenv("PIO_CCO_SPARSE", "0")
+    monkeypatch.setenv("PIO_CCO_DENSE", "0")
+    if program == "_cco_chunked_all_tiles":
+        monkeypatch.setattr(cco_ops, "_TILED_P_BYTES", 0)
+
+    def attrs(pallas):
+        monkeypatch.setenv("PIO_PALLAS", pallas)
+        with SpanCollector().activate() as spans:
+            cco_ops.cco_indicators_coo(
+                u, it, u, it, n_users, n_items, n_items, top_k=5,
+                exclude_self=exclude_self, user_block=32, item_tile=tile)
+        return _dispatch_attrs(spans)[program]
+
+    off = attrs("off")
+    assert off["llr_mask"] == ("xla" if exclude_self else "none")
+    assert "llr_block" not in off and "llr_edge_rows" not in off
+    on = attrs("interpret")
+    # 300 rows: a block of 256 and one of 44; a 64-wide tile in one block
+    assert on["llr_block"] == "256x128" and on["llr_edge_rows"] == 44
+    assert on["llr_mask"] == ("kernel" if exclude_self else "none")
+
+
 def test_topk_impl_follows_pallas_mode(monkeypatch):
     """No knob of its own: the merge's selection is the Pallas tournament
     exactly where Pallas kernels run."""
