@@ -49,6 +49,7 @@ from predictionio_tpu.controller import (
     Preparator,
 )
 from predictionio_tpu.models.recommendation.engine import ItemScore, PredictedResult
+from predictionio_tpu.obs.spans import span
 from predictionio_tpu.ops import als as als_ops
 from predictionio_tpu.parallel.mesh import MeshSpec, create_mesh
 from predictionio_tpu.models.common import (
@@ -249,14 +250,16 @@ class ECommAlgorithm(Algorithm):
             if name in td.event_names:
                 w[td.event_names.index(name)] = float(weight)
         strength = w[np.maximum(td.event_codes, 0)]
-        cell = td.user_idx.astype(np.int64) * n_items + td.item_idx
-        uniq, inv = np.unique(cell, return_inverse=True)
-        r = np.zeros(len(uniq), np.float32)
-        np.add.at(r, inv, strength)
-        users = (uniq // n_items).astype(np.int32)
-        items = (uniq % n_items).astype(np.int32)
-        popular = np.zeros(n_items, np.float32)
-        np.add.at(popular, items, r)
+        with span("layout", events=len(td.user_idx)) as rec:
+            cell = td.user_idx.astype(np.int64) * n_items + td.item_idx
+            uniq, inv = np.unique(cell, return_inverse=True)
+            r = np.bincount(inv, weights=strength,
+                            minlength=len(uniq)).astype(np.float32)
+            users = (uniq // n_items).astype(np.int32)
+            items = (uniq % n_items).astype(np.int32)
+            popular = np.bincount(items, weights=r,
+                                  minlength=n_items).astype(np.float32)
+            rec["attrs"]["cells"] = len(uniq)
         dp = self.params.mesh_dp or len(jax.devices())
         mesh = create_mesh(MeshSpec(dp=dp, mp=1)) if dp > 1 else None
         data = als_ops.prepare_als_data(users, items, r, n_users, n_items, dp=dp)

@@ -47,7 +47,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs.spans import span
-from predictionio_tpu.utils.device import noted, stage
+from predictionio_tpu.utils.device import PLAN_BYTES, noted, stage
 
 
 @dataclasses.dataclass
@@ -182,22 +182,80 @@ def _row_ridge(local_idx: jnp.ndarray, mask: jnp.ndarray, rows: int, reg) -> jnp
         return reg * jnp.maximum(n_e, 1.0) + 1e-6
 
 
-def _solve_rows(z, local_idx, lam, k: int, gram=None) -> jnp.ndarray:
+def _solve_rows(z, local_idx, lam, k: int, gram, block: int) -> jnp.ndarray:
     """The half-step's one segment reduction, over the packed rows, then the
     K×K solves.  XLA:TPU's scatter-add of [E, W] rows costs 6.8 ns a rating
     on a v5e, 8.7 where runs of ratings share a row, and more again with
     ``indices_are_sorted``; a blockwise one-hot matmul over rows sorted by
     segment gained 5% of the program for a device sort that compiles for
-    15–27 s, and was not kept (my chip runs, PR 27)."""
+    15–27 s, and was not kept (both measured on a v5e).
+
+    The systems are solved ``block`` rows at a time (``_solve_plan``'s,
+    dividing ``lam``'s rows) in a ``lax.map``: where one block holds every
+    row, XLA drops the one-trip loop and the program is the unblocked one."""
     with stage("als.normal_eq"):
-        A, b = _unpack_rows(
-            jax.ops.segment_sum(z, local_idx, num_segments=lam.shape[0]), k)
+        red = jax.ops.segment_sum(z, local_idx, num_segments=lam.shape[0])
+    n = lam.shape[0] // block
+    return jax.lax.map(
+        lambda a: _solve_block(*a, k, gram),
+        (red.reshape(n, block, -1), lam.reshape(n, block))).reshape(-1, k)
+
+
+def _solve_block(red, lam, k: int, gram=None) -> jnp.ndarray:
+    """Rows' systems from their summed packed rows [rows, W], solved:
+    unpack, the Gram and the ridge added, then the batched Cholesky."""
+    with stage("als.normal_eq"):
+        A, b = _unpack_rows(red, k)
         if gram is not None:
             A = A + gram
         A = A + lam[:, None, None] * jnp.eye(k, dtype=A.dtype)
     with stage("als.solve"):
         cho = jax.scipy.linalg.cho_factor(A)
         return jax.scipy.linalg.cho_solve(cho, b[..., None])[..., 0]  # [rows, K]
+
+
+# the bytes a program's plan may take (a name of this module's, which the
+# tests shrink)
+_PLAN_BYTES = PLAN_BYTES
+
+
+def _tiled_bytes(rows: int, cols: int) -> int:
+    """A float32 [rows, cols] in the TPU's (8, 128) tiles of its two minor
+    dimensions: a K×K system at rank 10 takes [16, 128], 8 KB."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+
+
+def _solve_plan(rows_u: int, rows_i: int, k: int) -> Tuple[int, int]:
+    """``(user block, item block)``: the rows whose systems one step of a
+    side's solve holds, all of the side's where they fit in one.
+
+    Counted as the TPU compiler lays the solve out for a v5e: a row of a
+    block holds its K×K system and that system's Cholesky factor, padded
+    ``_tiled_bytes(K, K)`` each (16 KB a row at rank 10, where the floats
+    are 800 B); beside the block stay, for every row of either side, its
+    summed packed row, that sum's copy laid out in blocks and its factor
+    row, lane-padded.  (The event slots' packed rows are dead by then: the
+    solve reuses their bytes.)  The block is the most rows that fit what
+    ``_PLAN_BYTES`` leaves; a side with more rows is cut into the fewest
+    blocks that fit, evenly, each rounded up to whole 8-row tiles.  At 1.4M
+    users and 235k items, rank 10: the users in 3 blocks of 469,200 and the
+    items in one, where one block of all the users asked the compiler for
+    21.64 GB.  It reads shapes alone."""
+    held = 3 * _tiled_bytes(rows_u + rows_i, _packed_width(k))
+    most = max((_PLAN_BYTES - held) // (2 * _tiled_bytes(k, k)) // 8 * 8, 8)
+
+    def block(rows: int) -> int:
+        if rows <= most:
+            return rows
+        per_block = -(-rows // -(-rows // most))      # ⌈rows ÷ blocks⌉
+        return -(-per_block // 8) * 8
+
+    return block(rows_u), block(rows_i)
+
+
+def _blocked_rows(rows: int, block: int) -> int:
+    """The rows a side's systems are reduced into: whole blocks."""
+    return -(-rows // block) * block
 
 
 def _half_step(
@@ -207,13 +265,14 @@ def _half_step(
     rating: jnp.ndarray,       # [E]
     mask: jnp.ndarray,         # [E]
     lam: jnp.ndarray,          # [rows] ridge of each row (_row_ridge)
+    block: int,                # rows a solve block (_solve_plan)
 ) -> jnp.ndarray:
     """Solve per-row normal equations (YtY + λ n_e I) x = Ytr on one shard."""
     with stage("als.gather"):
         y = other_full[other_flat] * mask[:, None]            # [E, K]
     with stage("als.normal_eq"):
         z = _packed_rows(y, y, rating)
-    return _solve_rows(z, local_idx, lam, y.shape[-1])
+    return _solve_rows(z, local_idx, lam, y.shape[-1], None, block)
 
 
 def _half_step_implicit(
@@ -225,42 +284,75 @@ def _half_step_implicit(
     mask: jnp.ndarray,         # [E]
     lam: jnp.ndarray,          # [rows]
     alpha: jnp.ndarray,
+    block: int,                # rows a solve block (_solve_plan)
 ) -> jnp.ndarray:
     """Implicit-feedback half-step (Hu/Koren/Volinsky; MLlib trainImplicit).
 
     Preference p = 1 for every observed event, confidence c = 1 + α·r.
     Per-row system: (YᵀY + Yᵀ(C−I)Y + λ·n_e·I) x = Yᵀ C p — the dense YᵀY
-    is the precomputed ``gram`` (one [N,K]×[K,N] MXU matmul per sweep),
-    and only the observed events contribute the (c−1)-weighted correction.
+    is the precomputed ``gram`` (``_gram``, once a half-step), and only the
+    observed events contribute the (c−1)-weighted correction.
     """
     with stage("als.gather"):
         y = other_full[other_flat] * mask[:, None]            # [E, K]
     with stage("als.normal_eq"):
         c1 = alpha * rating * mask                            # c − 1, 0 on padding
         z = _packed_rows(c1[:, None] * y, y, 1.0 + c1)
-    return _solve_rows(z, local_idx, lam, y.shape[-1], gram)
+    return _solve_rows(z, local_idx, lam, y.shape[-1], gram, block)
 
 
-def _sweeps(x0, y0, iters, reg, alpha, u_side, i_side, implicit: bool, gather_full):
+# rows of one partial Gram (``_gram``)
+_GRAM_ROWS = 2048
+
+
+def _gram(other_full: jnp.ndarray) -> jnp.ndarray:
+    """YᵀY [K, K] of the implicit half-step, at HIGHEST, summed in two
+    levels: the Gram of every ``_GRAM_ROWS`` rows, then their sum.
+
+    At default precision a TPU multiplies in one bfloat16 pass (~1e-3 off
+    float32's Gram).  At HIGHEST, one contraction over a whole table still
+    loses float32: on a v5e its largest error was 4.1e-5 of the largest
+    entry over 1.4M rows (1.9e-5 over 235k; numpy's float32 1.6e-6), and
+    every row's system carries it (the cell's reference reads it: PERF.md
+    section 2).  Summed in two levels it was 1.3e-7.  The barrier keeps XLA
+    from folding the sum of the partial Grams back into one contraction."""
+    n, k = other_full.shape
+    with stage("als.normal_eq"):
+        o = jnp.pad(other_full, ((0, -n % _GRAM_ROWS), (0, 0)))
+        o = o.reshape(-1, _GRAM_ROWS, k)
+        part = jnp.einsum("cnk,cnl->ckl", o, o,
+                          precision=jax.lax.Precision.HIGHEST)
+        return jax.lax.optimization_barrier(part).sum(0)
+
+
+def _sweeps(x0, y0, iters, reg, alpha, u_side, i_side, implicit: bool,
+            gather_full, blocks):
     """``iters`` ALS sweeps over the factor rows this program solves (``x0``
     [rows_u, K], ``y0`` [rows_i, K]) and their events (``u_side``,
     ``i_side``: local row, opposite flat index, rating, mask, each [E]).
-    ``gather_full`` turns its rows of one side into that side's whole table."""
+    ``gather_full`` turns its rows of one side into that side's whole table.
+    ``blocks`` are the rows of a solve block of each side (``_solve_plan``):
+    a side's systems are reduced into ⌈rows ÷ block⌉ · block rows, and the
+    rows past its own are dropped (they have no events: b = 0, solved to
+    0)."""
     # the counts depend on the layout alone: once, not in each half-step
-    lam_u = _row_ridge(u_side[0], u_side[3], x0.shape[0], reg)
-    lam_i = _row_ridge(i_side[0], i_side[3], y0.shape[0], reg)
+    lam_u = _row_ridge(u_side[0], u_side[3],
+                       _blocked_rows(x0.shape[0], blocks[0]), reg)
+    lam_i = _row_ridge(i_side[0], i_side[3],
+                       _blocked_rows(y0.shape[0], blocks[1]), reg)
 
-    def half(other_full, side, lam):
+    def half(other_full, side, lam, block, rows):
         if implicit:
-            with stage("als.normal_eq"):
-                gram = other_full.T @ other_full
-            return _half_step_implicit(other_full, gram, *side, lam, alpha)
-        return _half_step(other_full, *side, lam)
+            out = _half_step_implicit(other_full, _gram(other_full), *side,
+                                      lam, alpha, block)
+        else:
+            out = _half_step(other_full, *side, lam, block)
+        return out[:rows]
 
     def sweep(_, carry):
         x, y = carry
-        x = half(gather_full(y), u_side, lam_u)
-        y = half(gather_full(x), i_side, lam_i)
+        x = half(gather_full(y), u_side, lam_u, blocks[0], x0.shape[0])
+        y = half(gather_full(x), i_side, lam_i, blocks[1], y0.shape[0])
         return (x, y)
 
     return jax.lax.fori_loop(0, iters, sweep, (x0, y0))
@@ -275,11 +367,11 @@ def _as_one_shard(local, other_flat, rating, mask, rows: int):
                      for a in (local + offset, other_flat, rating, mask))
 
 
-@functools.partial(jax.jit, static_argnames=("implicit",))
+@functools.partial(jax.jit, static_argnames=("implicit", "blocks"))
 def _als_run_single(
     x0, y0, iters, reg, alpha,
     uu, ui, ur, um, ii, iu, ir, im,
-    *, implicit: bool = False,
+    *, implicit: bool = False, blocks: Tuple[int, int],
 ):
     """Single-program ALS sweeps: every shard's rows and events on one device.
 
@@ -293,12 +385,12 @@ def _als_run_single(
         x0.reshape(-1, k), y0.reshape(-1, k), iters, reg, alpha,
         _as_one_shard(uu, ui, ur, um, x0.shape[1]),
         _as_one_shard(ii, iu, ir, im, y0.shape[1]),
-        implicit, gather_full=lambda f: f)
+        implicit, gather_full=lambda f: f, blocks=blocks)
     return x.reshape(x0.shape), y.reshape(y0.shape)
 
 
 @functools.lru_cache(maxsize=8)
-def _als_sharded_fn(mesh: Mesh, implicit: bool):
+def _als_sharded_fn(mesh: Mesh, implicit: bool, blocks: Tuple[int, int]):
     """Build (and cache per mesh/mode) the shard_map'd ALS runner."""
 
     def per_shard(x0_, y0_, iters, reg, alpha, *sides):
@@ -310,7 +402,8 @@ def _als_sharded_fn(mesh: Mesh, implicit: bool):
         mine = [a[0] for a in sides]
         x, y = _sweeps(
             x0_[0], y0_[0], iters, reg, alpha, mine[:4], mine[4:], implicit,
-            gather_full=lambda f: jax.lax.all_gather(f, "dp", tiled=True))
+            gather_full=lambda f: jax.lax.all_gather(f, "dp", tiled=True),
+            blocks=blocks)
         return x[None], y[None]
 
     spec, rep = P("dp"), P()
@@ -321,9 +414,10 @@ def _als_sharded_fn(mesh: Mesh, implicit: bool):
     ))
 
 
-def _als_run_sharded(mesh, implicit, x0, y0, iters, reg, alpha, *args):
-    return noted(_als_sharded_fn(mesh, implicit), x0, y0, iters, reg, alpha,
-                 *args)
+def _als_run_sharded(mesh, implicit, blocks, x0, y0, iters, reg, alpha,
+                     *args):
+    return noted(_als_sharded_fn(mesh, implicit, blocks), x0, y0, iters, reg,
+                 alpha, *args)
 
 
 def als_train(
@@ -400,15 +494,22 @@ def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=Non
         args = _als_device_args(data)
     # which program shape ran: the packed row's width and the event slots a
     # shard of each layout (the reduction always engages; this is its witness)
-    shape = dict(packed_width=_packed_width(x0.shape[-1]),
-                 events=int(data.u_mask.shape[1]))
+    # and the solve's blocks: the rows of the largest, and how many a call
+    # solves (each side's a half-step, every sweep)
+    k, sides = x0.shape[-1], (data.user_rows, data.item_rows)
+    blocks = _solve_plan(*sides, k)
+    shape = dict(packed_width=_packed_width(k),
+                 events=int(data.u_mask.shape[1]), implicit=implicit,
+                 solve_block=max(blocks),
+                 solve_blocks=n_sweeps * sum(
+                     -(-r // b) for b, r in zip(blocks, sides)))
     if mesh is None:
         with span("dispatch", program="_als_run_single", **shape):
             return noted(
                 _als_run_single,
                 x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
                 jnp.float32(alpha),
-                *args, implicit=implicit,
+                *args, implicit=implicit, blocks=blocks,
             )
     if mesh.shape.get("dp", 1) != data.dp:
         raise ValueError(
@@ -421,7 +522,8 @@ def _als_sweeps(data: ALSData, x0, y0, n_sweeps: int, reg: float, mesh, args=Non
         y0 = stage_global(np.asarray(y0), sharding)
     with span("dispatch", program="_als_run_sharded", **shape):
         return _als_run_sharded(
-            mesh, implicit, x0, y0, jnp.int32(n_sweeps), jnp.float32(reg),
+            mesh, implicit, blocks, x0, y0, jnp.int32(n_sweeps),
+            jnp.float32(reg),
             jnp.float32(alpha), *args,
         )
 

@@ -59,7 +59,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs.spans import span
-from predictionio_tpu.utils.device import noted, stage
+from predictionio_tpu.utils.device import PLAN_BYTES, noted, stage
 
 
 # ---------------------------------------------------------------------------
@@ -462,11 +462,10 @@ def _tiling(n_items_t: int, item_tile: int) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 # Budget for the P-resident program's plan (see _plan for what the plan
-# counts): three quarters of a 16 GB v5e.  The quarter left over is for
-# what the plan does not count: the COO arrays and the top-k carry of the
-# event type in flight, the results of the one before it, and the
-# allocator's own slack.
-_TILED_P_BYTES = 12 * 10**9
+# counts).  The rest of the chip is for what the plan does not count: the
+# COO arrays and the top-k carry of the event type in flight, the results
+# of the one before it, and the allocator's own slack.
+_TILED_P_BYTES = PLAN_BYTES
 
 
 def _densify_coo(gu, gi, valid, n_rows: int, n_cols: int):

@@ -215,10 +215,10 @@ def test_half_step_matches_float64_row_solves(k, implicit):
     if implicit:
         got = als_ops._half_step_implicit(
             jnp.asarray(factors), jnp.asarray(factors.T @ factors), local,
-            other, rating, mask, lam, jnp.float32(alpha))
+            other, rating, mask, lam, jnp.float32(alpha), rows)
     else:
         got = als_ops._half_step(jnp.asarray(factors), local, other, rating,
-                                 mask, lam)
+                                 mask, lam, rows)
     want = _half_step_float64(rows, local, other, rating, mask, factors, reg,
                               alpha if implicit else None)
     got = np.asarray(got, np.float64)
@@ -260,7 +260,9 @@ def test_program_has_no_event_by_rank_squared_array_and_counts_once(implicit):
     data = prepare_als_data(u, i, r, 40, 30, dp=1)
     e = data.u_mask.shape[1]
     x0, y0 = als_ops._als_init(data, k, 0)
-    program = functools.partial(als_ops._als_run_single, implicit=implicit)
+    program = functools.partial(
+        als_ops._als_run_single, implicit=implicit,
+        blocks=als_ops._solve_plan(data.user_rows, data.item_rows, k))
     jaxpr = jax.make_jaxpr(program)(
         x0, y0, jnp.int32(3), jnp.float32(0.1), jnp.float32(1.0),
         *als_ops._als_device_args(data)).jaxpr
@@ -306,6 +308,10 @@ def test_dispatch_span_says_which_program_shape_ran(dp, tmp_path):
         "_als_run_sharded" if dp > 1 else "_als_run_single")
     assert dispatch["attrs"]["packed_width"] == als_ops._packed_width(6) == 27
     assert dispatch["attrs"]["events"] == data.u_mask.shape[1]
+    # one block a side, each sweep
+    assert dispatch["attrs"]["solve_blocks"] == 2 * 2
+    assert dispatch["attrs"]["solve_block"] == max(data.user_rows,
+                                                   data.item_rows)
 
 
 @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
@@ -321,3 +327,67 @@ def test_one_device_runs_a_sharded_layout_as_the_mesh_does(implicit):
     x8, y8 = als_train(data, mesh=create_mesh(MeshSpec(dp=8, mp=1)), **kw)
     assert np.abs(x1 - x8).max() < 1e-4 * np.abs(x8).max()
     assert np.abs(y1 - y8).max() < 1e-4 * np.abs(y8).max()
+
+
+@pytest.mark.parametrize("rows", [1, 2048, 5000])
+def test_gram_sums_partial_grams_to_the_float64_gram(rows):
+    """`_gram` pads the table to whole partials of `_GRAM_ROWS` rows: the
+    padding adds nothing, and the sum is YᵀY to float32's rounding."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als as als_ops
+
+    y = np.random.default_rng(rows).normal(size=(rows, 10)).astype(np.float32)
+    want = y.astype(np.float64).T @ y.astype(np.float64)
+    got = np.asarray(als_ops._gram(jnp.asarray(y)), np.float64)
+    assert got.shape == (10, 10)
+    assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+
+
+def test_solve_plan_blocks_a_side_only_where_its_systems_do_not_fit():
+    """The plan at the cells' shapes: MovieLens-1M's 9,746 rows solve in
+    one block each side (a one-trip `lax.map`); 1.4M visitors do
+    not, and are cut into three even blocks of whole 8-row tiles beside
+    one block of 235k items.  A padded K×K system at rank 10 is [16, 128]
+    floats."""
+    from predictionio_tpu.ops import als as als_ops
+
+    assert als_ops._tiled_bytes(10, 10) == 8192
+    assert als_ops._solve_plan(6040, 3706, 10) == (6040, 3706)
+    block, item_block = als_ops._solve_plan(1_407_580, 235_061, 10)
+    assert item_block == 235_061 and block % 8 == 0
+    assert als_ops._blocked_rows(1_407_580, block) // block == 3
+    assert 0 <= als_ops._blocked_rows(1_407_580, block) - 1_407_580 < 3 * 8
+
+
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+def test_blocked_solve_equals_the_one_block_solve(implicit, monkeypatch, tmp_path):
+    """The budget shrunk until the users take three blocks and the items
+    two: the same factors as one block a side, to float32's rounding, and
+    the dispatch span counts the blocks the program solved."""
+    from predictionio_tpu.obs import spans as obs_spans
+    from predictionio_tpu.ops import als as als_ops
+
+    u, i, r, _, _ = synthetic_ratings(n_users=40, n_items=30)
+    if implicit:
+        r = np.abs(r)
+    data = prepare_als_data(u, i, r, 40, 30, dp=1)
+    kw = dict(k=6, reg=0.05, iterations=4, implicit=implicit, alpha=1.5)
+    assert als_ops._solve_plan(40, 30, 6) == (40, 30)
+    x1, y1 = als_train(data, **kw)
+    # what the plan holds beside a block at this shape, and 16 rows' systems
+    held = 3 * als_ops._tiled_bytes(40 + 30, als_ops._packed_width(6))
+    monkeypatch.setattr(als_ops, "_PLAN_BYTES",
+                        held + 16 * 2 * als_ops._tiled_bytes(6, 6))
+    blocks = als_ops._solve_plan(40, 30, 6)
+    assert blocks == (16, 16)
+    with obs_spans.SpanJournal(tmp_path / "als.jsonl").activate():
+        xb, yb = als_train(data, **kw)
+    (dispatch,) = [s for s in obs_spans.recent_runs()[-1]
+                   if s["name"] == "dispatch"
+                   and s["attrs"]["program"] == "_als_run_single"]
+    assert dispatch["attrs"]["solve_block"] == 16
+    assert dispatch["attrs"]["solve_blocks"] == 4 * (3 + 2)
+    assert dispatch["attrs"]["implicit"] is implicit
+    assert np.abs(xb - x1).max() <= 1e-5 * np.abs(x1).max()
+    assert np.abs(yb - y1).max() <= 1e-5 * np.abs(y1).max()

@@ -214,3 +214,28 @@ def test_ecomm_serve_batch_matches_serial(ecomm_app):
         s_i = [(r.item, round(r.score, 4)) for r in s.item_scores]
         b_i = [(r.item, round(r.score, 4)) for r in b.item_scores]
         assert s_i == b_i, (q, s_i, b_i)
+
+
+def test_cell_fold_sums_weighted_events_under_a_layout_span(ecomm_app, tmp_path):
+    """The engine's cell fold: each (user, item) cell's strength is the sum
+    of its events' weights, the popularity of an item the sum over its
+    cells, as a float32 `np.add.at` over the events gives them; the fold
+    runs under a `layout` span that counts events and cells."""
+    from predictionio_tpu.models.ecommerce.engine import (
+        ECommAlgorithm, ECommDataSource)
+    from predictionio_tpu.obs import spans as obs_spans
+
+    ep = make_ep(event_weights={"buy": 4.5})
+    td = ECommDataSource(ep.data_source_params).read_training()
+    with obs_spans.SpanJournal(tmp_path / "fold.jsonl").activate():
+        model = ECommAlgorithm(ep.algorithm_params_list[0][1]).train(td)
+    w = np.where(np.asarray(td.event_names)[td.event_codes] == "buy", 4.5,
+                 1.0).astype(np.float32)
+    popular = np.zeros(len(td.item_dict), np.float32)
+    np.add.at(popular, td.item_idx, w)
+    assert np.array_equal(model.popular, popular)
+    cells = np.unique(td.user_idx.astype(np.int64) * len(td.item_dict)
+                      + td.item_idx)
+    (fold,) = [s for s in obs_spans.recent_runs()[-1]
+               if s["name"] == "layout" and "cells" in s.get("attrs", {})]
+    assert fold["attrs"] == {"events": len(td.user_idx), "cells": len(cells)}

@@ -154,3 +154,39 @@ def test_basket_program_fits_a_v5e(one_chip, monkeypatch):
         topk="pallas", mm="bf16").compile()
     assert _held_bytes(compiled) < 14e9
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_implicit_als_program_fits_a_v5e(one_chip):
+    """`_als_run_single(implicit=True)` at ecom-retailrocket's shape (1.4M
+    visitors, 235k items, 2M cells, rank 10), under the solve blocks
+    `_solve_plan` derives: the TPU compiler's plan stays inside the 15.75
+    GB a v5e gives a program, where one block of every visitor's systems
+    asked the compiler for 21.64 GB.  And the implicit Gram is multiplied
+    at HIGHEST, not in one bfloat16 pass, in partial Grams that XLA has not
+    folded back into one contraction over the whole table."""
+    import re
+
+    from predictionio_tpu.ops import als
+
+    users, items, cells, k = 1_407_580, 235_061, 2_000_000, 10
+    blocks = als._solve_plan(users, items, k)
+    assert blocks[0] < users and blocks[1] == items
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    side = [of((1, cells), jnp.int32), of((1, cells), jnp.int32),
+            of((1, cells), jnp.float32), of((1, cells), jnp.float32)]
+    scalar = of((), jnp.float32)
+    compiled = als._als_run_single.lower(
+        of((1, users, k), jnp.float32), of((1, items, k), jnp.float32),
+        of((), jnp.int32), scalar, scalar, *side, *side, implicit=True,
+        blocks=blocks).compile()
+    assert _held_bytes(compiled) < 15.75e9
+    # the partial Grams of every _GRAM_ROWS rows, one contraction a half-step
+    partials = -(-users // als._GRAM_ROWS), -(-items // als._GRAM_ROWS)
+    grams = [ln for ln in compiled.as_text().splitlines()
+             if re.search(r"= f32\[(%d|%d),10,10\]\S* (convolution|dot)\("
+                          % partials, ln)]
+    assert len(grams) == 2
+    assert all("operand_precision={highest,highest}" in ln for ln in grams)
